@@ -2,9 +2,12 @@
 config stays in bench.py, whose single-JSON-line driver contract this
 file must not disturb).
 
-Each config runs in its own subprocess with a hard timeout (bench.py's
-outage-robustness pattern), emits one QUALITY-GATED JSON line, and the
-collection is written to BENCH_SUITE.json:
+Each config runs in a child of its own with a hard timeout — one process
+holds the chip at a time, and this parent never imports jax — emits one
+QUALITY-GATED JSON line stamped with the device it ran on, and the
+collection is written to BENCH_SUITE.json.  Without a TPU nothing is
+measured and nothing is written: a config whose child fails produces no
+record, and the run exits non-zero.
 
   * goss_regression       — L2 + boosting=goss (examples/regression;
                             no published reference number, gate = heldout
@@ -20,10 +23,6 @@ collection is written to BENCH_SUITE.json:
                             generator, not the published number; the
                             published time is still the vs_baseline
                             denominator.
-  * feature_parallel      — tree_learner=feature on the 8-virtual-device
-                            CPU mesh (the ICI path compiled and executed;
-                            one real chip means no measured multi-chip
-                            scaling claim) with a serial-parity gate.
   * spill_ab              — the same regression config trained twice:
                             data_in_hbm=resident vs forced host-spill
                             (out-of-core row-block streaming,
@@ -49,26 +48,16 @@ import time
 RESULT_TAG = "SUITE_RESULT_JSON:"
 REPO = os.path.dirname(os.path.abspath(__file__))
 
-# (config, platform, rows, warmup, measure, timeout_s); CPU fallback
-# tiers run tiny and are stamped {"fallback": true} like bench.py's
-TIERS = {
-    "goss_regression": [("tpu", 2_000_000, 2, 4, 2400),
-                        ("cpu", 10_000, 1, 2, 900)],
-    "multiclass_cat": [("tpu", 1_000_000, 2, 4, 2400),
-                       ("cpu", 10_000, 1, 2, 900)],
+# config -> (rows, warmup, measure, timeout_s)
+SIZES = {
+    "goss_regression": (2_000_000, 2, 4, 2400),
+    "multiclass_cat": (1_000_000, 2, 4, 2400),
     # 4200s: the cold lambdarank compile at 2.27M rows blew the usual
-    # 2700s budget (r5 on-chip log, 2026-08-01)
-    "lambdarank_msltr": [("tpu", 2_270_000, 2, 4, 4200),
-                         ("cpu", 20_000, 1, 2, 900)],
-    # the mesh is 8 VIRTUAL CPU devices sharing one host core, so this
-    # config is a correctness/liveness gate (serial parity), not a
-    # timing claim — tiers stay tiny and the record says virtual_mesh
-    "feature_parallel": [("cpu-mesh", 20_000, 1, 2, 1800),
-                         ("cpu-mesh", 5_000, 1, 2, 900)],
-    # two children per tier (resident + forced spill), so the per-child
-    # timeout stays the usual single-run budget
-    "spill_ab": [("tpu", 1_000_000, 2, 4, 2400),
-                 ("cpu", 10_000, 1, 2, 900)],
+    # 2700s budget (retired installation, 2026-08-01)
+    "lambdarank_msltr": (2_270_000, 2, 4, 4200),
+    # two children (resident + forced spill), so the per-child timeout
+    # stays the usual single-run budget
+    "spill_ab": (1_000_000, 2, 4, 2400),
 }
 
 # published reference wall-clocks for vs_baseline (500 iters, CPU,
@@ -77,7 +66,6 @@ REF_500_ITERS_S = {
     "goss_regression": None,
     "multiclass_cat": None,
     "lambdarank_msltr": 215.320,
-    "feature_parallel": None,
     "spill_ab": None,
 }
 REF_ROWS = {"lambdarank_msltr": 2_270_296}
@@ -172,14 +160,12 @@ def _impl_label(bst, requested: str) -> str:
     return label
 
 
-def run_child(config: str, platform: str, n_rows: int, warmup: int,
-              measure: int) -> None:
+def run_child(config: str, n_rows: int, warmup: int, measure: int) -> None:
     import jax
-    if platform.startswith("cpu"):
-        jax.config.update("jax_platforms", "cpu")
     sys.path.insert(0, REPO)
-    from lightgbm_tpu.utils import enable_jax_compilation_cache
-    enable_jax_compilation_cache(REPO)
+    from lightgbm_tpu.utils import enable_jax_compilation_cache, require_tpu
+    device = require_tpu("bench_suite.py")
+    enable_jax_compilation_cache()
     import numpy as np
 
     import lightgbm_tpu as lgb
@@ -187,7 +173,6 @@ def run_child(config: str, platform: str, n_rows: int, warmup: int,
     rng = np.random.RandomState(7)
     gen = {"goss_regression": _gen_goss, "multiclass_cat": _gen_multiclass,
            "lambdarank_msltr": _gen_rank,
-           "feature_parallel": _gen_goss,
            "spill_ab": _gen_goss}[config]
     X, y, extra = gen(rng, n_rows)
     params = {"learning_rate": 0.1, "num_leaves": 255, "max_bin": 63,
@@ -195,15 +180,15 @@ def run_child(config: str, platform: str, n_rows: int, warmup: int,
               "objective": "regression",
               # same A/B hooks as bench.py: LIGHTGBM_TPU_IMPL pins the
               # grower, LIGHTGBM_TPU_BOOST_CHUNK pins the chunk size
-              # (0 = auto; GOSS/mesh configs self-clamp to 1)
+              # (0 = auto; the GOSS config self-clamps to 1)
               "tpu_tree_impl": os.environ.get("LIGHTGBM_TPU_IMPL",
                                               "auto"),
               "tpu_boost_chunk": int(os.environ.get(
                   "LIGHTGBM_TPU_BOOST_CHUNK", "0"))}
     params.update(extra.get("params", {}))
-    # fused-K ladder hook (tools/onchip_r7.py): pins the frontier batch
-    # width, same knob perf_probe.py exposes, so the K∈{4,8,16} A/B
-    # cells measure the width they name
+    # fused-K ladder hook: pins the frontier batch width, same knob
+    # perf_probe.py exposes, so K∈{4,8,16} A/B cells measure the width
+    # they name
     fk = int(os.environ.get("LIGHTGBM_TPU_FRONTIER_K", "0") or 0)
     if fk > 0:
         params["tpu_frontier_width"] = fk
@@ -216,8 +201,6 @@ def run_child(config: str, platform: str, n_rows: int, warmup: int,
         params["boosting"] = "goss"
     if config == "multiclass_cat":
         params["num_leaves"] = 31
-    if config == "feature_parallel":
-        params.update({"tree_learner": "feature", "num_leaves": 63})
 
     ds = lgb.Dataset(X, y, group=extra.get("group"),
                      categorical_feature=extra.get("categorical_feature",
@@ -263,22 +246,10 @@ def run_child(config: str, platform: str, n_rows: int, warmup: int,
     pred = bst.predict(X[:200_000])
     quality: dict = {}
     ok = True
-    if config in ("goss_regression", "feature_parallel", "spill_ab"):
+    if config in ("goss_regression", "spill_ab"):
         l2 = float(np.mean((pred - y[:len(pred)]) ** 2))
         quality["l2"] = round(l2, 5)
         ok = l2 < 0.5 * float(np.var(y))
-        if config == "feature_parallel":
-            # parity gate vs the serial learner at the same budget
-            ps = dict(params)
-            ps.pop("tree_learner")
-            bs = lgb.Booster(ps, lgb.Dataset(X, y))
-            for _ in range(max(25, warmup + measure)):
-                bs.update()
-            pred_s = bs.predict(X[:200_000])
-            dev = float(np.abs(pred - pred_s).max())
-            quality["max_dev_vs_serial"] = round(dev, 6)
-            scale = float(np.abs(pred_s).max()) + 1e-9
-            ok = ok and dev < 5e-3 * max(scale, 1.0)
     elif config == "multiclass_cat":
 
         p = np.asarray(pred).reshape(-1, 5)
@@ -308,6 +279,7 @@ def run_child(config: str, platform: str, n_rows: int, warmup: int,
         bst.model_to_string().encode()).hexdigest()
     print(RESULT_TAG + json.dumps({
         "config": config, "rows": n_rows, "backend": backend,
+        "device": device,
         "per_iter": round(per_iter, 5), "setup_s": round(t_setup, 2),
         "warmup_s": round(t_warm, 2), "quality": quality,
         "quality_ok": bool(ok),
@@ -318,34 +290,22 @@ def run_child(config: str, platform: str, n_rows: int, warmup: int,
     }))
 
 
-def _cpu_env():
-    sys.path.insert(0, REPO)
-    from lightgbm_tpu.utils import cpu_subprocess_env
-    env = cpu_subprocess_env()
-    flags = env.get("XLA_FLAGS", "")
-    if "host_platform_device_count" not in flags:
-        env["XLA_FLAGS"] = (flags +
-                            " --xla_force_host_platform_device_count=8"
-                            ).strip()
-    return env
-
-
-def _run_child_record(config: str, platform: str, rows: int, warmup: int,
+def _run_child_record(config: str, rows: int, warmup: int,
                       measure: int, timeout_s: float,
                       env: dict) -> dict | None:
     cmd = [sys.executable, os.path.abspath(__file__), "--child",
-           config, platform, str(rows), str(warmup), str(measure)]
+           config, str(rows), str(warmup), str(measure)]
     try:
         proc = subprocess.run(cmd, env=env, timeout=timeout_s,
                               capture_output=True, cwd=REPO)
     except subprocess.TimeoutExpired:
-        sys.stderr.write(f"suite: {config}/{platform}/{rows} timed "
+        sys.stderr.write(f"suite: {config}/{rows} timed "
                          f"out ({timeout_s}s)\n")
         return None
     sys.stderr.write(proc.stderr.decode(errors="replace")[-2000:])
     if proc.returncode != 0:
         sys.stderr.write(
-            f"suite: {config}/{platform}/{rows} rc={proc.returncode}\n")
+            f"suite: {config}/{rows} rc={proc.returncode}\n")
         return None
     for line in proc.stdout.decode(errors="replace").splitlines():
         if line.startswith(RESULT_TAG):
@@ -358,7 +318,7 @@ def _peak_hbm(rec: dict) -> int | None:
             or {}).get("peak_bytes_in_use")
 
 
-def _run_spill_ab(probe_ok: bool) -> dict | None:
+def _run_spill_ab() -> dict | None:
     """Resident-vs-forced-spill A/B on the same config and data: one
     trajectory record whose gated value is the SPILL wall (so an
     out-of-core streaming regression trips tools/bench_gate.py), with
@@ -366,115 +326,89 @@ def _run_spill_ab(probe_ok: bool) -> dict | None:
     also demands byte-identical models — the out-of-core tier's core
     contract."""
     config = "spill_ab"
-    for platform, rows, warmup, measure, timeout_s in TIERS[config]:
-        if platform == "tpu" and not probe_ok:
-            continue
-        env = (_cpu_env() if platform.startswith("cpu")
-               else dict(os.environ))
-        pair = {}
-        for tier in ("resident", "spill"):
-            e = dict(env)
-            e["SUITE_DATA_IN_HBM"] = tier
-            pair[tier] = _run_child_record(config, platform, rows,
-                                           warmup, measure, timeout_s, e)
-        res, spl = pair["resident"], pair["spill"]
-        if res is None or spl is None:
-            continue
-        total_res = res["per_iter"] * TOTAL_ITERS_REF
-        total_spl = spl["per_iter"] * TOTAL_ITERS_REF
-        bit_identical = (res.get("model_sha") is not None
-                         and res.get("model_sha") == spl.get("model_sha"))
-        out = {
-            "config": config,
-            "metric": f"{config}_{spl['rows']}r_500iter_train_time_"
-                      f"{spl['backend']}_spill",
-            "value": round(total_spl, 2),
-            "unit": "s",
-            "impl": spl["impl"],
-            "chunk": spl.get("chunk", 1),
-            "quality": dict(
-                spl["quality"],
-                spill_wall_ratio=round(total_spl / max(total_res, 1e-9),
-                                       3),
-                bit_identical=bit_identical),
-            "quality_ok": bool(spl["quality_ok"] and res["quality_ok"]
-                               and bit_identical),
-            "resident_value": round(total_res, 2),
-            "metrics": spl.get("metrics"),
-        }
-        pr, ps = _peak_hbm(res), _peak_hbm(spl)
-        if pr is not None and ps is not None:
-            out["resident_peak_hbm_bytes"] = int(pr)
-            out["spill_peak_hbm_bytes"] = int(ps)
-            out["peak_hbm_delta_bytes"] = int(ps) - int(pr)
-        if spl["backend"] == "cpu" and platform == "tpu":
-            out["fallback"] = True
-        if platform.startswith("cpu") and "tpu" in (
-                t[0] for t in TIERS[config]):
-            out["fallback"] = True
-        return out
-    return None
+    rows, warmup, measure, timeout_s = SIZES[config]
+    pair = {}
+    for tier in ("resident", "spill"):
+        env = dict(os.environ, SUITE_DATA_IN_HBM=tier)
+        pair[tier] = _run_child_record(config, rows, warmup, measure,
+                                       timeout_s, env)
+    res, spl = pair["resident"], pair["spill"]
+    if res is None or spl is None:
+        return None
+    total_res = res["per_iter"] * TOTAL_ITERS_REF
+    total_spl = spl["per_iter"] * TOTAL_ITERS_REF
+    bit_identical = (res.get("model_sha") is not None
+                     and res.get("model_sha") == spl.get("model_sha"))
+    out = {
+        "config": config,
+        "metric": f"{config}_{spl['rows']}r_500iter_train_time_"
+                  f"{spl['backend']}_spill",
+        "value": round(total_spl, 2),
+        "unit": "s",
+        "device": spl["device"],
+        "impl": spl["impl"],
+        "chunk": spl.get("chunk", 1),
+        "quality": dict(
+            spl["quality"],
+            spill_wall_ratio=round(total_spl / max(total_res, 1e-9), 3),
+            bit_identical=bit_identical),
+        "quality_ok": bool(spl["quality_ok"] and res["quality_ok"]
+                           and bit_identical),
+        "resident_value": round(total_res, 2),
+        "metrics": spl.get("metrics"),
+    }
+    pr, ps = _peak_hbm(res), _peak_hbm(spl)
+    if pr is not None and ps is not None:
+        out["resident_peak_hbm_bytes"] = int(pr)
+        out["spill_peak_hbm_bytes"] = int(ps)
+        out["peak_hbm_delta_bytes"] = int(ps) - int(pr)
+    return out
 
 
-def run_config(config: str, probe_ok: bool) -> dict | None:
+def run_config(config: str) -> dict | None:
     if config == "spill_ab":
-        return _run_spill_ab(probe_ok)
-    for platform, rows, warmup, measure, timeout_s in TIERS[config]:
-        if platform == "tpu" and not probe_ok:
-            continue
-        env = (_cpu_env() if platform.startswith("cpu")
-               else dict(os.environ))
-        r = _run_child_record(config, platform, rows, warmup, measure,
-                              timeout_s, env)
-        if r is None:
-            continue
-        # bench.py:216's promotion contract for the suite: a TPU tier
-        # whose auto impl resolved to segment also measures the frontier
-        # grower and keeps it when it is faster at held quality, so a
-        # default (env-free) run reproduces the scoreboard numbers
-        if (platform == "tpu" and r["backend"] == "tpu"
-                and r["impl"] == "segment"
-                and "LIGHTGBM_TPU_IMPL" not in os.environ):
-            env2 = dict(env)
-            env2["LIGHTGBM_TPU_IMPL"] = "frontier"
-            r2 = _run_child_record(config, platform, rows, warmup,
-                                   measure, timeout_s, env2)
-            if (r2 is not None and r2["impl"] == "frontier"
-                    and r2["quality_ok"]
-                    and r2["per_iter"] < r["per_iter"]):
-                sys.stderr.write(
-                    f"suite A/B [{config}]: frontier "
-                    f"{r2['per_iter']:.4f} beats segment "
-                    f"{r['per_iter']:.4f} s/iter at held quality\n")
-                r = r2
-        total = r["per_iter"] * TOTAL_ITERS_REF
-        ref = REF_500_ITERS_S.get(config)
-        out = {
-            "config": config,
-            "metric": f"{config}_{r['rows']}r_500iter_train_time_"
-                      f"{r['backend']}",
-            "value": round(total, 2),
-            "unit": "s",
-            "impl": r["impl"],
-            "chunk": r.get("chunk", 1),
-            "quality": r["quality"],
-            "quality_ok": r["quality_ok"],
-            # the measured window's v2 telemetry blob (phases, transfer
-            # bytes, memory/cost envelope) rides along with every record
-            "metrics": r.get("metrics"),
-        }
-        if ref is not None:
-            scaled = ref * r["rows"] / REF_ROWS.get(config, r["rows"])
-            out["vs_baseline"] = round(total / scaled, 3)
-        if r["backend"] == "cpu" and platform == "tpu":
-            out["fallback"] = True
-        if platform == "cpu-mesh":
-            out["virtual_mesh"] = True
-        if platform.startswith("cpu") and "tpu" in (
-                t[0] for t in TIERS[config]):
-            out["fallback"] = True
-        return out
-    return None
+        return _run_spill_ab()
+    rows, warmup, measure, timeout_s = SIZES[config]
+    env = dict(os.environ)
+    r = _run_child_record(config, rows, warmup, measure, timeout_s, env)
+    if r is None:
+        return None
+    # bench.py's promotion contract for the suite: a run whose auto impl
+    # resolved to segment also measures the frontier grower and keeps it
+    # when it is faster at held quality, so a default (env-free) run
+    # reproduces the scoreboard numbers
+    if r["impl"] == "segment" and "LIGHTGBM_TPU_IMPL" not in os.environ:
+        r2 = _run_child_record(config, rows, warmup, measure, timeout_s,
+                               dict(env, LIGHTGBM_TPU_IMPL="frontier"))
+        if (r2 is not None and r2["impl"] == "frontier"
+                and r2["quality_ok"]
+                and r2["per_iter"] < r["per_iter"]):
+            sys.stderr.write(
+                f"suite A/B [{config}]: frontier "
+                f"{r2['per_iter']:.4f} beats segment "
+                f"{r['per_iter']:.4f} s/iter at held quality\n")
+            r = r2
+    total = r["per_iter"] * TOTAL_ITERS_REF
+    ref = REF_500_ITERS_S.get(config)
+    out = {
+        "config": config,
+        "metric": f"{config}_{r['rows']}r_500iter_train_time_"
+                  f"{r['backend']}",
+        "value": round(total, 2),
+        "unit": "s",
+        "device": r["device"],
+        "impl": r["impl"],
+        "chunk": r.get("chunk", 1),
+        "quality": r["quality"],
+        "quality_ok": r["quality_ok"],
+        # the measured window's v2 telemetry blob (phases, transfer
+        # bytes, memory/cost envelope) rides along with every record
+        "metrics": r.get("metrics"),
+    }
+    if ref is not None:
+        scaled = ref * r["rows"] / REF_ROWS.get(config, r["rows"])
+        out["vs_baseline"] = round(total / scaled, 3)
+    return out
 
 
 def _append_trajectory(results: list) -> None:
@@ -545,36 +479,36 @@ def _append_trajectory(results: list) -> None:
 
 def main():
     configs = [a for a in sys.argv[1:] if not a.startswith("-")] \
-        or list(TIERS)
-    sys.path.insert(0, REPO)
-    import bench
-    probe_ok = (not os.environ.get("BENCH_SKIP_TPU")) and bench.probe_tpu()
-    results = []
-    # A/B ladder runs (tools/onchip_r7.py) suffix their records so each
-    # env cell forms its OWN config series in the trajectory —
-    # bench_gate's per-config latency baselines never mix a forced
-    # variant with the defaults
+        or list(SIZES)
+    results, failed = [], []
+    # A/B ladder runs suffix their records so each env cell forms its OWN
+    # config series in the trajectory — bench_gate's per-config latency
+    # baselines never mix a forced variant with the defaults
     tag = os.environ.get("SUITE_CONFIG_TAG", "")
     for config in configs:
-        r = run_config(config, probe_ok)
+        r = run_config(config)
         if r is None:
-            r = {"config": config, "metric": f"{config}_failed",
-                 "value": -1.0, "unit": "s", "quality_ok": False}
+            failed.append(config)
+            continue
         if tag:
             r["config"] = f"{r['config']}+{tag}"
             r["metric"] = f"{r.get('metric', config)}+{tag}"
         results.append(r)
         print(json.dumps(r), flush=True)
+    if failed:
+        sys.stderr.write(f"suite: no record for {failed}\n")
+    if not results:
+        sys.exit(1)
     _append_trajectory(results)
     # subset runs merge into the existing artifact instead of clobbering
     # the other configs' records
     path = os.path.join(REPO, "BENCH_SUITE.json")
-    if set(configs) != set(TIERS):
+    if set(configs) != set(SIZES):
         def config_of(rec):
             if "config" in rec:
                 return rec["config"]
             # pre-"config"-field artifacts: longest-prefix fallback
-            names = [n for n in TIERS
+            names = [n for n in SIZES
                      if rec.get("metric", "").startswith(n)]
             return max(names, key=len) if names else rec.get("metric", "")
 
@@ -588,19 +522,21 @@ def main():
         results = list(old.values())
     with open(path, "w") as fh:
         json.dump(results, fh, indent=1)
+    rc = 1 if failed else 0
     if "--gate" in sys.argv[1:]:
         # perf-regression sentinel: judge the lines just appended
         # against the trailing trajectory (tools/bench_gate.py) after
         # the artifacts are safely on disk
         sys.path.insert(0, os.path.join(REPO, "tools"))
         import bench_gate
-        sys.exit(bench_gate.gate(
+        rc = max(rc, bench_gate.gate(
             os.path.join(REPO, "BENCH_TRAJECTORY.jsonl")))
+    sys.exit(rc)
 
 
 if __name__ == "__main__":
     if len(sys.argv) >= 2 and sys.argv[1] == "--child":
-        run_child(sys.argv[2], sys.argv[3], int(sys.argv[4]),
-                  int(sys.argv[5]), int(sys.argv[6]))
+        run_child(sys.argv[2], int(sys.argv[3]), int(sys.argv[4]),
+                  int(sys.argv[5]))
     else:
         main()

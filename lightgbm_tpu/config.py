@@ -226,9 +226,10 @@ _PARAMS: Dict[str, _P] = {
     "health_out": _P(""),
     # persistent on-disk XLA compilation cache so a restarted/resumed run
     # warm-starts its compiles: "" (default) = off, "1"/"true"/"on"/
-    # "default" = on at <repo>/.jax_cache, any other string = cache
-    # directory path.  Hits/misses surface as compile/cache_hits|misses
-    # telemetry counters
+    # "default" = on at <checkout>/.jax_cache, any other string = cache
+    # directory path; JAX_COMPILATION_CACHE_DIR, where set, wins over
+    # both.  Hits/misses surface as compile/cache_hits|misses telemetry
+    # counters
     "compile_cache": _P(""),
     # measured per-dispatch device timing (utils/jitcost.py): every
     # cost-instrumented jit dispatch is timed wall-to-ready (sync on the

@@ -48,12 +48,20 @@ def _host_tag() -> str:
 def _build(src: str, out: str) -> None:
     # -march=native vectorizes the quantizer's compare-count (the 8.8x
     # vs -O2); the output filename carries _host_tag() so the cache
-    # never crosses ISAs
+    # never crosses ISAs.  Built under a per-process name and renamed
+    # into place: processes racing on a fresh checkout (test workers)
+    # must never load a half-written library.
+    tmp = f"{out[:-len('.so')]}.{os.getpid()}.tmp.so"
     cmd = ["g++", "-O3", "-march=native", "-fPIC", "-shared",
-           "-std=c++17", src, "-o", out]
-    proc = subprocess.run(cmd, capture_output=True, text=True)
-    if proc.returncode != 0:
-        raise RuntimeError(proc.stderr[-500:])
+           "-std=c++17", src, "-o", tmp]
+    try:
+        proc = subprocess.run(cmd, capture_output=True, text=True)
+        if proc.returncode != 0:
+            raise RuntimeError(proc.stderr[-500:])
+        os.replace(tmp, out)
+    finally:
+        if os.path.exists(tmp):
+            os.unlink(tmp)
 
 
 def lib() -> Optional[ctypes.CDLL]:

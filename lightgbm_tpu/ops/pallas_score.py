@@ -25,7 +25,7 @@ import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 
-from .pallas_histogram import _interpret_default
+from .pallas_histogram import _interpret_default, gate_self_check
 
 BLOCK = 32768
 CHUNK = 512
@@ -50,17 +50,20 @@ def scorer_available() -> bool:
     if env in ("1", "on", "true"):
         return True
     if _SELF_CHECK is None:
-        rng = np.random.default_rng(0)
-        table = jnp.asarray(rng.standard_normal(255), jnp.float32)
-        lid = jnp.asarray(rng.integers(0, 255, 4096), jnp.int32)
-        score = jnp.asarray(rng.standard_normal(4096), jnp.float32)
-        try:
-            got = score_gather_add(score, lid, table)
-            want = score + table[lid]
-            _SELF_CHECK = bool(jnp.array_equal(got, want))
-        except Exception:  # lowering/compile failure -> gather path
-            _SELF_CHECK = False
+        _SELF_CHECK = gate_self_check("score-kernel",
+                                      _score_kernel_self_check)
     return _SELF_CHECK
+
+
+def _score_kernel_self_check() -> bool:
+    """``score_gather_add`` must reproduce ``score + table[leaf_id]``
+    bit-for-bit on the live backend."""
+    rng = np.random.default_rng(0)
+    table = jnp.asarray(rng.standard_normal(255), jnp.float32)
+    lid = jnp.asarray(rng.integers(0, 255, 4096), jnp.int32)
+    score = jnp.asarray(rng.standard_normal(4096), jnp.float32)
+    return bool(jnp.array_equal(score_gather_add(score, lid, table),
+                                score + table[lid]))
 
 
 def _kernel(lv_ref, lid_ref, score_ref, out_ref, *, table_pad):

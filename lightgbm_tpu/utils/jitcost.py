@@ -105,6 +105,12 @@ class CostJit:
     def __getattr__(self, name: str):
         return getattr(self._fn, name)
 
+    def executables(self) -> list:
+        """The executables compiled at this seam so far, one per input
+        signature (``.as_text()`` shows the kernels and collectives the
+        compiler put in)."""
+        return [c for c in self._compiled.values() if c is not None]
+
     def _aot_compile(self, args, key):
         from .telemetry import TELEMETRY
         try:

@@ -1,5 +1,5 @@
-"""bench.py impl A/B selection logic (pure-function tests; the on-chip
-tiers themselves run only on real hardware)."""
+"""bench.py impl A/B selection logic (pure-function tests; the measurement
+itself runs only on a TPU, and refuses anything else)."""
 
 import importlib.util
 import os
@@ -134,3 +134,15 @@ def test_ab_chunked_skips_pinned_env_and_rejects_slower(monkeypatch):
         lambda *a, **k: {"per_iter": 0.8, "rows": 100, "backend": "cpu",
                          "impl": "fused-onehot", "auc": 0.9, "chunk": 2})
     assert bench.maybe_ab_chunked(base, "cpu", 100, 1, 2, 60) is base
+
+
+def test_measurement_refuses_without_a_tpu(capsys):
+    """A measurement child on another backend exits non-zero and says why;
+    it produces no record to be mistaken for the chip's."""
+    import pytest
+
+    from lightgbm_tpu.utils import require_tpu
+    with pytest.raises(SystemExit) as e:
+        require_tpu("bench.py")
+    assert "bench.py: needs a TPU; JAX found cpu" in str(e.value.code)
+    assert capsys.readouterr().out == ""

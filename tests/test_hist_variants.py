@@ -260,8 +260,69 @@ def test_run_kernel_self_checks_green(capsys):
     out = capsys.readouterr().out
     assert "kernel self-checks: PASS" in out
     for name in ("packed_acc", "onehot_gather", "onehot_twolevel",
-                 "hist_stage", "fused_route", "fused_k"):
+                 "hist_stage", "fused_route", "fused_k", "route_kernel",
+                 "score_kernel"):
         assert f"ok {name}" in out, name
+
+
+def test_kernel_self_checks_report_each_variant(monkeypatch):
+    """One entry per variant: None for a pass, "mismatch" for a check
+    that ran and disagreed, the exception for one that raised — and one
+    variant's failure never stops the others."""
+    def boom():
+        raise ValueError("synthetic lowering failure\nShape mismatch")
+
+    monkeypatch.setattr(ph, "_fused_k_self_check", boom)
+    monkeypatch.setattr(ph, "_packed_acc_self_check", lambda: False)
+    results = ph.kernel_self_checks()
+    assert results["fused_k"] == "ValueError: Shape mismatch"
+    assert results["packed_acc"] == "mismatch"
+    assert set(ph.DEFAULT_PATH_CHECKS) <= set(results)
+    assert all(err is None for name, err in results.items()
+               if name not in ("fused_k", "packed_acc"))
+
+
+def test_gate_self_check_raise_surfaces_on_tpu(monkeypatch):
+    """On a tpu backend a self-check that raises is an error of the
+    program, not a reason to take another path; off it the interpreter
+    keeps falling back."""
+    import pytest
+
+    def boom():
+        raise RuntimeError("synthetic lowering failure")
+
+    assert ph.gate_self_check("x", boom) is False       # cpu: other path
+    monkeypatch.setattr(ph.jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="synthetic lowering failure"):
+        ph.gate_self_check("x", boom)
+    # through a gate: nothing is memoized, the error is heard again
+    monkeypatch.setattr(ph, "_PACKED_ACC_CHECK", None)
+    monkeypatch.setattr(ph, "_packed_acc_self_check", boom)
+    monkeypatch.setenv("LIGHTGBM_TPU_PACKED_ACC", "1")
+    for _ in range(2):
+        with pytest.raises(RuntimeError, match="synthetic"):
+            ph.packed_acc_enabled()
+
+
+def test_gate_self_check_mismatch_warns_and_counts(monkeypatch, capsys):
+    """A check that runs and reports "not equal" may select the other
+    path — on every backend — but says so: one warning, one count."""
+    from lightgbm_tpu.utils.telemetry import TELEMETRY
+
+    def count():
+        return TELEMETRY.stats()["counters"].get(
+            "hist/self_check_fallbacks", 0)
+
+    for backend in ("cpu", "tpu"):
+        monkeypatch.setattr(ph.jax, "default_backend", lambda b=backend: b)
+        before = count()
+        assert ph.gate_self_check("some-kernel", lambda: False) is False
+        assert count() == before + 1
+        assert (f"some-kernel self-check failed on the {backend} backend"
+                in capsys.readouterr().out)
+    before = count()
+    assert ph.gate_self_check("some-kernel", lambda: True) is True
+    assert count() == before
 
 
 def test_vmem_limit_autosize():

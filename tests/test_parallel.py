@@ -111,6 +111,27 @@ def test_data_parallel_segment_matches_serial_segment(rng):
         assert ts.num_leaves == td.num_leaves
 
 
+def test_data_parallel_segment_state_lives_on_the_mesh(rng, devices):
+    """Rows-sharded mesh learners place the bin matrix and the per-row
+    boosting state over the mesh once: left on the default device, every
+    tree would re-shard the whole matrix from device 0."""
+    D = len(devices)
+    X, y = make_data(rng, n=3200, f=9)
+    data = _train(X, y, "data", tpu_histogram_backend="pallas",
+                  tpu_tree_impl="segment", tpu_row_chunk=256)
+    g = data.gbdt
+    assert g._use_segment and g._mesh.devices.size == D
+    for arr, row_axis in ((g.bins, 1), (g.train_score, 1),
+                          (g.bag_weight, 0)):
+        shards = arr.addressable_shards
+        assert sorted(s.device.id for s in shards) == list(range(D))
+        assert all(s.data.shape[row_axis] == arr.shape[row_axis] // D
+                   for s in shards), arr.sharding
+    # nothing whole is cached for the default device either
+    assert getattr(g.train_set, "_device_binned_T", None) is None
+    assert float(np.mean((data.predict(X) - y) ** 2)) < 0.1 * y.var()
+
+
 def test_data_parallel_segment_binary_uneven(rng):
     X, y = make_data(rng, n=2507, f=6)
     yb = (y > np.median(y)).astype(float)

@@ -155,9 +155,10 @@ def test_swap_under_load_zero_failures(rng):
 def test_swap_rejected_nonfinite_candidate(rng, tmp_path):
     bstA, X, _ = _train(rng)
     bad, _, _ = _train(rng, rounds=6)
-    bad.gbdt.models[0].leaf_value = np.array(
-        bad.gbdt.models[0].leaf_value, dtype=np.float64)
-    bad.gbdt.models[0].leaf_value[0] = np.nan
+    # every leaf of the first tree: whichever leaves the holdout rows reach
+    # (that depends on the trained tree's shape), the scores are non-finite
+    bad.gbdt.models[0].leaf_value = np.full_like(
+        bad.gbdt.models[0].leaf_value, np.nan, dtype=np.float64)
     Xq = X[:24].copy()
     ref = bstA.predict(Xq)
     hpath = tmp_path / "serve_health.jsonl"

@@ -171,8 +171,13 @@ def test_registry_thread_safety_and_writer_check(monkeypatch):
     monkeypatch.setenv("LIGHTGBM_TPU_TELEMETRY", "2")
     reg = TelemetryRegistry(span_capacity=64)
     nthreads, per = 8, 400
+    # every writer alive at once: a thread ident is reused once its thread
+    # exits, so writers that happened to run one after another would look
+    # like ONE writer and the race below would never be flagged
+    all_started = threading.Barrier(nthreads)
 
     def work():
+        all_started.wait(timeout=60)
         for _ in range(per):
             reg.counter_add("t/hits")
             with reg.span("t_span"):
@@ -182,7 +187,8 @@ def test_registry_thread_safety_and_writer_check(monkeypatch):
     for t in threads:
         t.start()
     for t in threads:
-        t.join()
+        t.join(timeout=120)
+        assert not t.is_alive()
 
     stats = reg.stats()
     assert stats["counters"]["t/hits"] == nthreads * per

@@ -419,7 +419,7 @@ def self_test():
             {"config": "h", "value": 1.0, "unit": "s/iter",
              "quality_ok": True, "hist_pass_mean_s": None})),
     ]
-    # fused-K ladder records (tools/onchip_r7.py): the fused rounds
+    # fused-K ladder records: the fused rounds
     # dispatch under the grower's own label, so hist_pass_label takes
     # the "grow/frontier[fused_hist_kK]" shape, and SUITE_CONFIG_TAG
     # makes the cell its own config series — the gate must baseline the

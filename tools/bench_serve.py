@@ -1,11 +1,13 @@
 """Serving benchmark: BENCH_SERVE.json + trajectory records.
 
 Measures the lightgbm_tpu/serve stack the way bench_suite.py measures
-training: each model size runs in its own subprocess (hard timeout, one
-JSON result line per grid cell), the parent collects the grid into
-BENCH_SERVE.json and appends one digest line per cell to
-BENCH_TRAJECTORY.jsonl, where tools/bench_gate.py gates the p99 against
-the trailing median (+20%).
+training: each model size runs in a child of its own (hard timeout, one
+JSON result line per grid cell, stamped with the device it ran on) — one
+process holds the chip at a time, and this parent never imports jax.  The
+parent collects the grid into BENCH_SERVE.json and appends one digest line
+per cell to BENCH_TRAJECTORY.jsonl, where tools/bench_gate.py gates the
+p99 against the trailing median (+20%).  Without a TPU a child exits
+non-zero and no record is produced.
 
 The grid is (model size) x (batch bucket) x (serve_max_delay_ms):
 requests of exactly one bucket's rows are pushed through the
@@ -41,9 +43,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BUCKETS = [16, 64]
 DELAYS_MS = [0.0, 4.0]
 
-# model size -> (rows, feats, iters, leaves, child timeout s).  The
-# "large" cell is sized to stay trainable on a single-core CI box
-# inside its timeout; on a real accelerator both cells are quick.
+# model size -> (rows, feats, iters, leaves, child timeout s)
 SIZES = {
     "small": (20_000, 20, 60, 31, 900),
     "large": (30_000, 30, 100, 63, 2400),
@@ -61,8 +61,9 @@ def _percentile(sorted_vals, q):
 
 def run_child(size: str, smoke: bool) -> None:
     sys.path.insert(0, REPO)
-    from lightgbm_tpu.utils import enable_jax_compilation_cache
-    enable_jax_compilation_cache(REPO)
+    from lightgbm_tpu.utils import enable_jax_compilation_cache, require_tpu
+    device = require_tpu("bench_serve.py")
+    enable_jax_compilation_cache()
     import jax
     import numpy as np
 
@@ -116,7 +117,7 @@ def run_child(size: str, smoke: bool) -> None:
             qps = n_requests / max(wall, 1e-9)
             print(RESULT_TAG + json.dumps({
                 "config": f"serve-{size}-b{bucket}-d{delay:g}",
-                "model": size, "backend": backend,
+                "model": size, "backend": backend, "device": device,
                 "trees": iters, "leaves": leaves, "features": feats,
                 "bucket": bucket, "delay_ms": delay,
                 "requests": n_requests,
@@ -129,23 +130,13 @@ def run_child(size: str, smoke: bool) -> None:
             }), flush=True)
 
 
-def _child_env():
-    sys.path.insert(0, REPO)
-    import bench
-    if (not os.environ.get("BENCH_SKIP_TPU")) and bench.probe_tpu():
-        return dict(os.environ)
-    from lightgbm_tpu.utils import cpu_subprocess_env
-    return cpu_subprocess_env()
-
-
-def _run_size(size: str, timeout_s: float, env: dict,
-              smoke: bool = False) -> list:
+def _run_size(size: str, timeout_s: float, smoke: bool = False) -> list:
     cmd = [sys.executable, os.path.abspath(__file__), "--child", size]
     if smoke:
         cmd.append("--smoke")
     try:
-        proc = subprocess.run(cmd, env=env, timeout=timeout_s,
-                              capture_output=True, cwd=REPO)
+        proc = subprocess.run(cmd, timeout=timeout_s, capture_output=True,
+                              cwd=REPO)
     except subprocess.TimeoutExpired:
         sys.stderr.write(f"bench_serve: {size} timed out ({timeout_s}s)\n")
         return []
@@ -172,6 +163,7 @@ def _append_trajectory(records: list) -> None:
                 "ts": round(time.time(), 3),
                 "config": r["config"],
                 "backend": r.get("backend"),
+                "device": r.get("device"),
                 "qps": r.get("qps"),
                 "rows_per_s": r.get("rows_per_s"),
                 "p50_s": r.get("p50_s"),
@@ -184,14 +176,13 @@ def main(argv=None):
     ap = argparse.ArgumentParser(
         description="serve-path latency/QPS grid -> BENCH_SERVE.json")
     ap.add_argument("--smoke", action="store_true",
-                    help="one tiny cell, no artifacts (CI liveness leg)")
+                    help="one tiny cell, no artifacts")
     ap.add_argument("--gate", action="store_true",
                     help="run tools/bench_gate.py over the trajectory "
                          "after appending")
     args = ap.parse_args(argv)
-    env = _child_env()
     if args.smoke:
-        recs = _run_size(SMOKE_SIZE[0], SMOKE_SIZE[1][4], env, smoke=True)
+        recs = _run_size(SMOKE_SIZE[0], SMOKE_SIZE[1][4], smoke=True)
         for r in recs:
             print(json.dumps(r if "metrics" not in r
                              else {k: v for k, v in r.items()
@@ -203,7 +194,7 @@ def main(argv=None):
         return 0
     records = []
     for size in SIZES:
-        records.extend(_run_size(size, SIZES[size][4], env))
+        records.extend(_run_size(size, SIZES[size][4]))
     for r in records:
         print(json.dumps({k: v for k, v in r.items() if k != "metrics"}),
               flush=True)

@@ -688,7 +688,7 @@ def main(argv=None):
     args = ap.parse_args(argv)
     sys.path.insert(0, REPO)
     from lightgbm_tpu.utils import enable_jax_compilation_cache
-    enable_jax_compilation_cache(REPO)
+    enable_jax_compilation_cache()
     if args.smoke:
         return smoke()
 

@@ -10,7 +10,7 @@ blobs are all accepted: every section is optional and renders as
 
 Usage:
   python tools/trace_report.py metrics.json          # a raw blob
-  python tools/trace_report.py BENCH_r05.json        # a bench record
+  python tools/trace_report.py bench_record.json     # a bench.py record
                                                      # (reads .metrics)
   python tools/trace_report.py --diff a.json b.json  # phase/counter/
                                                      # memory/cost/
