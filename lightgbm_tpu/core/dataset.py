@@ -480,10 +480,12 @@ class TpuDataset:
         """The bin matrix as a device array (uploaded once, cached)."""
         import jax.numpy as jnp
         if self._device_binned is None:
+            from ..utils.phase import GLOBAL_TIMER
             from ..utils.telemetry import TELEMETRY
             TELEMETRY.counter_add("transfer/h2d_bytes",
                                   int(self.binned.nbytes))
-            self._device_binned = jnp.asarray(self.binned)
+            with GLOBAL_TIMER.phase("h2d_upload"):
+                self._device_binned = jnp.asarray(self.binned)
         return self._device_binned
 
     def device_binned_T(self, row_multiple: int = 1, packed4: bool = False):
@@ -496,10 +498,12 @@ class TpuDataset:
         import jax.numpy as jnp
         key = getattr(self, "_device_binned_T_key", None)
         if key != (row_multiple, packed4):
-            t = self.host_binned_T(row_multiple, packed4)
+            from ..utils.phase import GLOBAL_TIMER
             from ..utils.telemetry import TELEMETRY
-            TELEMETRY.counter_add("transfer/h2d_bytes", int(t.nbytes))
-            self._device_binned_T = jnp.asarray(t)
+            with GLOBAL_TIMER.phase("h2d_upload"):
+                t = self.host_binned_T(row_multiple, packed4)
+                TELEMETRY.counter_add("transfer/h2d_bytes", int(t.nbytes))
+                self._device_binned_T = jnp.asarray(t)
             self._device_binned_T_key = (row_multiple, packed4)
         return self._device_binned_T
 

@@ -135,7 +135,7 @@ def train(params: Dict[str, Any], train_set: Dataset,
     # a callback or device error mid-training must not leak an open jax
     # profiler trace session
     from .utils import maybe_enable_compile_cache
-    from .utils.phase import PROFILE_WINDOW, profile_session
+    from .utils.phase import GLOBAL_TIMER, PROFILE_WINDOW, profile_session
     from .utils.telemetry import HEALTH, TELEMETRY
     # compile_cache= knob: persistent on-disk XLA compilation cache, so a
     # restarted/resumed run warm-starts its compiles (hits/misses surface
@@ -180,6 +180,39 @@ def train(params: Dict[str, Any], train_set: Dataset,
         HEALTH.open(health_path,
                     meta={"source": "engine",
                           "num_iterations": int(num_boost_round)})
+
+    def replay_inscan(base_iter: int) -> bool:
+        """The chunk's per-iteration metric rows through the normal
+        callback cadence (print/record/early-stop see exactly what
+        per-iteration stepping shows them); True when a callback stopped
+        training inside the chunk."""
+        for j, vals in booster.take_inscan_evals():
+            jr = int(j) - base_iter
+            evaluation_result_list = booster.inscan_result_list(vals)
+            if HEALTH.active:
+                HEALTH.record("eval", {
+                    "iter": jr, "in_scan": True,
+                    "metrics": {f"{dn}/{mn}": float(v)
+                                for dn, mn, v, _ in evaluation_result_list}})
+            try:
+                for cb in callbacks_after:
+                    cb(callback_mod.CallbackEnv(
+                        model=booster, params=params, iteration=jr,
+                        begin_iteration=0, end_iteration=num_boost_round,
+                        evaluation_result_list=evaluation_result_list))
+            except callback_mod.EarlyStopException as e:
+                booster.best_iteration = e.best_iteration + 1
+                for item in e.best_score:
+                    booster.best_score.setdefault(
+                        item[0], {})[item[1]] = item[2]
+                # the stop fired INSIDE the chunk: surplus tail-of-chunk
+                # trees are discarded before they become model state, so
+                # the final model matches a per-iteration early stop
+                while booster.gbdt.current_iteration() > j + 1:
+                    booster.gbdt.rollback_one_iter()
+                return True
+        return False
+
     # memory_session brackets the run with HBM gauge samples and owns the
     # optional background sampler's lifetime (stopped even when a callback
     # or device error raises out of the loop)
@@ -210,41 +243,8 @@ def train(params: Dict[str, Any], train_set: Dataset,
                 it = i + step - 1
 
                 if use_inscan:
-                    # replay the chunk's per-iteration metric rows through
-                    # the normal callback cadence (print/record/early-stop
-                    # see exactly what per-iteration stepping shows them)
-                    stopped_early = False
-                    for j, vals in booster.take_inscan_evals():
-                        jr = int(j) - base_iter
-                        evaluation_result_list = (
-                            booster.inscan_result_list(vals))
-                        if HEALTH.active:
-                            HEALTH.record("eval", {
-                                "iter": jr, "in_scan": True,
-                                "metrics": {f"{dn}/{mn}": float(v)
-                                            for dn, mn, v, _ in
-                                            evaluation_result_list}})
-                        try:
-                            for cb in callbacks_after:
-                                cb(callback_mod.CallbackEnv(
-                                    model=booster, params=params,
-                                    iteration=jr, begin_iteration=0,
-                                    end_iteration=num_boost_round,
-                                    evaluation_result_list=(
-                                        evaluation_result_list)))
-                        except callback_mod.EarlyStopException as e:
-                            booster.best_iteration = e.best_iteration + 1
-                            for item in e.best_score:
-                                booster.best_score.setdefault(
-                                    item[0], {})[item[1]] = item[2]
-                            # the stop fired INSIDE the chunk: surplus
-                            # tail-of-chunk trees are discarded before
-                            # they become model state, so the final
-                            # model matches a per-iteration early stop
-                            while booster.gbdt.current_iteration() > j + 1:
-                                booster.gbdt.rollback_one_iter()
-                            stopped_early = True
-                            break
+                    with GLOBAL_TIMER.phase("callbacks"):
+                        stopped_early = replay_inscan(base_iter)
                     if stopped_early or should_stop:
                         break
                     i += step
@@ -263,12 +263,14 @@ def train(params: Dict[str, Any], train_set: Dataset,
                                     for dn, mn, v, _ in
                                     evaluation_result_list}})
                 try:
-                    for cb in callbacks_after:
-                        cb(callback_mod.CallbackEnv(
-                            model=booster, params=params, iteration=it,
-                            begin_iteration=0,
-                            end_iteration=num_boost_round,
-                            evaluation_result_list=evaluation_result_list))
+                    with GLOBAL_TIMER.phase("callbacks"):
+                        for cb in callbacks_after:
+                            cb(callback_mod.CallbackEnv(
+                                model=booster, params=params, iteration=it,
+                                begin_iteration=0,
+                                end_iteration=num_boost_round,
+                                evaluation_result_list=(
+                                    evaluation_result_list)))
                 except callback_mod.EarlyStopException as e:
                     booster.best_iteration = e.best_iteration + 1
                     for item in e.best_score:

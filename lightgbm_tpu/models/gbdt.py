@@ -30,11 +30,12 @@ from ..utils.faults import FAULTS, InjectedFault, oom_error
 from ..utils.jitcost import cost_jit
 from ..utils.log import (LightGBMError, check, log_fatal, log_info,
                          log_warning)
-from ..utils.phase import GLOBAL_TIMER as _PHASES, step_annotation
+from ..utils.phase import GLOBAL_TIMER as _PHASES
 from ..utils.telemetry import HEALTH, TELEMETRY
 from .grower import (GrowerParams, _pack_tree_device, fetch_tree_arrays,
                      fetch_tree_chunk, make_grow_tree, unpack_tree_buffers)
-from .grower_seg import print_seg_stats, seg_stats_enabled
+from .grower_seg import (SEG_STATS_SLOTS, print_seg_stats,
+                         seg_stats_enabled)
 from .tree import Tree
 
 
@@ -45,13 +46,16 @@ class _PendingChunk(NamedTuple):
     ``mvals`` is the in-scan evaluation's stacked [T, n_cols] metric
     rows (None when no eval program rides the chunk); ``wall_s`` is the
     chunk dispatch's host wall window (wall-to-ready under
-    device_timing), carried into the health stream's iter records."""
+    device_timing), carried into the health stream's iter records;
+    ``seg_stats`` is the grower's stacked [T, C, SEG_STATS_SLOTS]
+    counters (None for growers that return none)."""
     ints_all: jax.Array
     floats_all: jax.Array
     shrinkage: float
     length: int
     mvals: Optional[jax.Array] = None
     wall_s: Optional[float] = None
+    seg_stats: Optional[jax.Array] = None
 
 
 # batch-predict routing seams, one per static routing depth: the
@@ -75,34 +79,50 @@ def _route_seam(max_depth: int):
     return fn
 
 
-def _maybe_print_seg_stats(stats) -> None:
-    """Render a grower's counter output when LIGHTGBM_TPU_SEG_STATS asks
-    for it (stats is () for growers that emit none, e.g. the fused one).
-    The rows also feed the telemetry counters; fetching the stats vector
-    blocks on the device, so recording stays gated on the same env knob
-    that opts into per-iteration synchronization."""
+def _record_seg_stats(rows: np.ndarray, trees: int,
+                      block_rows: int) -> None:
+    """A grower's counters, [*, SEG_STATS_SLOTS] over ``trees`` trees
+    (one row a tree, or one a device and tree under the data-parallel
+    wrappers), into the ``seg/*`` and ``hist/*`` telemetry counters."""
+    rows = rows.reshape(-1, SEG_STATS_SLOTS)
+    TELEMETRY.counter_add("seg/scanned_blocks", int(rows[:, 0].sum()))
+    TELEMETRY.counter_add("seg/compactions", int(rows[:, 1].sum()))
+    TELEMETRY.counter_add("seg/grid_steps", int(rows[:, 2].sum()))
+    # what turns blocks into rows and totals into per-tree figures
+    TELEMETRY.counter_add("seg/trees", int(trees))
+    TELEMETRY.gauge_set("seg/block_rows", int(block_rows))
+    # quantization / staging counters stay 0 on paths that never
+    # quantize or stage — record only live events so trace_report's
+    # hist section renders n/a instead of misleading zero rates
+    if rows[:, 5].sum():
+        TELEMETRY.counter_add("hist/fused_k_rounds",
+                              int(rows[:, 5].sum()))
+    if rows[:, 6].sum():
+        TELEMETRY.counter_add("hist/quant_rescales", len(rows))
+        TELEMETRY.counter_add("hist/quant_clips", int(rows[:, 6].sum()))
+    if rows[:, 8].sum():
+        TELEMETRY.counter_add("hist/stage_hits", int(rows[:, 7].sum()))
+        TELEMETRY.counter_add("hist/stage_lookups",
+                              int(rows[:, 8].sum()))
+
+
+def _stack_seg_stats(stats_l):
+    """One scan step's per-class grower counters as a scan output:
+    ``([C, SEG_STATS_SLOTS],)``, or ``()`` where the grower returns none
+    (static at trace time, so such a chunk program carries no output)."""
+    return (jnp.stack([st[0] for st in stats_l]),) if stats_l[0] else ()
+
+
+def _maybe_print_seg_stats(stats, block_rows: int) -> None:
+    """The per-iteration paths: record and render one tree's counter
+    output when LIGHTGBM_TPU_SEG_STATS asks for it (stats is () for
+    growers that emit none, e.g. the fused one).  Fetching the stats
+    vector blocks on the device here, so both stay gated on the env knob
+    that opts into per-iteration synchronization; the chunk path records
+    always, from the copy that rides with its tree buffers
+    (``_entry_iter_arrays``)."""
     if stats and seg_stats_enabled():
-        from .grower_seg import SEG_STATS_SLOTS
-        rows = np.asarray(stats[0]).reshape(-1, SEG_STATS_SLOTS)
-        TELEMETRY.counter_add("seg/scanned_blocks",
-                              int(rows[:, 0].sum()))
-        TELEMETRY.counter_add("seg/compactions", int(rows[:, 1].sum()))
-        TELEMETRY.counter_add("seg/grid_steps", int(rows[:, 2].sum()))
-        # quantization / staging counters stay 0 on paths that never
-        # quantize or stage — record only live events so trace_report's
-        # hist section renders n/a instead of misleading zero rates
-        if rows[:, 5].sum():
-            TELEMETRY.counter_add("hist/fused_k_rounds",
-                                  int(rows[:, 5].sum()))
-        if rows[:, 6].sum():
-            TELEMETRY.counter_add("hist/quant_rescales", len(rows))
-            TELEMETRY.counter_add("hist/quant_clips",
-                                  int(rows[:, 6].sum()))
-        if rows[:, 8].sum():
-            TELEMETRY.counter_add("hist/stage_hits",
-                                  int(rows[:, 7].sum()))
-            TELEMETRY.counter_add("hist/stage_lookups",
-                                  int(rows[:, 8].sum()))
+        _record_seg_stats(np.asarray(stats[0]), 1, block_rows)
         print_seg_stats(stats[0])
 
 
@@ -248,7 +268,8 @@ def _grad_stats_core(grads, hesss):
         safe = jnp.where(jnp.isfinite(x), x, 0.0)
         return (jnp.min(safe, axis=1), jnp.max(safe, axis=1),
                 jnp.sqrt(jnp.sum(safe * safe, axis=1)), nonfinite)
-    return jnp.stack(one(grads) + one(hesss), axis=1)
+    with jax.named_scope("grad_stats"):
+        return jnp.stack(one(grads) + one(hesss), axis=1)
 
 
 _grad_stats = cost_jit("health/grad_stats", jax.jit(_grad_stats_core))
@@ -437,7 +458,8 @@ class GBDT:
         self.max_feature_idx = 0
         self._inscan_evals: List[tuple] = []
         if train_set is not None:
-            self.reset_train_data(train_set)
+            with _PHASES.phase("booster_init"):
+                self.reset_train_data(train_set)
 
     # ----------------------------------------------------------------- setup
     def _resolve_hist_backend(self, parallel: bool) -> str:
@@ -1163,7 +1185,8 @@ class GBDT:
                     g, h = obj.get_gradients(score[0])
                     return g[None], h[None]
                 return obj.get_gradients(score)
-            return _with_arrs(run, arrs)
+            with jax.named_scope("grad"):
+                return _with_arrs(run, arrs)
 
         fused_grad = cost_jit("boost/gradients", jax.jit(grad_core))
 
@@ -1186,6 +1209,7 @@ class GBDT:
             # chunk the classes when num_class exceeds the budget
             cap = channel_set_capacity(G_cols, self.num_bins, rb_)
 
+            @jax.named_scope("roots")
             def roots_core(grads, hesss, member, bins):
                 if pad:
                     grads = jnp.pad(grads, ((0, 0), (0, pad)))
@@ -1233,17 +1257,20 @@ class GBDT:
                                               fmeta, fmask, sub, **kw)
             if pad:
                 leaf_id = leaf_id[:N]
-            if use_score_kernel:
-                # one-hot-matmul scorer: the plain table gather lowers
-                # to ~1.6 GB/s on this backend (ops/pallas_score)
-                from ..ops.pallas_score import score_gather_add
-                new_row = score_gather_add(
-                    score[k], leaf_id, shrinkage * arrays.leaf_value)
-            else:
-                new_row = (score[k]
-                           + shrinkage * arrays.leaf_value[leaf_id])
-            score = score.at[k].set(new_row)
-            ints_d, floats_d = _pack_tree_device(arrays)
+            with jax.named_scope("score"):
+                if use_score_kernel:
+                    # one-hot-matmul scorer: the plain table gather
+                    # lowers to ~1.6 GB/s on this backend
+                    # (ops/pallas_score)
+                    from ..ops.pallas_score import score_gather_add
+                    new_row = score_gather_add(
+                        score[k], leaf_id, shrinkage * arrays.leaf_value)
+                else:
+                    new_row = (score[k]
+                               + shrinkage * arrays.leaf_value[leaf_id])
+                score = score.at[k].set(new_row)
+            with jax.named_scope("pack_tree"):
+                ints_d, floats_d = _pack_tree_device(arrays)
             # the raw TreeArrays ride along for the in-scan eval variant,
             # which re-routes the valid sets through the freshly grown tree
             return score, ints_d, floats_d, tuple(stats), arrays
@@ -1301,14 +1328,16 @@ class GBDT:
                     gstats = _grad_stats_core(grads, hesss)
                     roots = (roots_core(grads, hesss, member, bins)
                              if roots_core is not None else None)
-                    ints_l, floats_l = [], []
+                    ints_l, floats_l, stats_l = [], [], []
                     for k in range(C):
                         key, sub = jax.random.split(key)
-                        score, ints_d, floats_d, _, arrays = step_core_full(
+                        (score, ints_d, floats_d, stats,
+                         arrays) = step_core_full(
                             score, grads, hesss, member, bins, fmeta,
                             fmask, sub, shrinkage, jnp.int32(k), roots)
                         ints_l.append(ints_d)
                         floats_l.append(floats_d)
+                        stats_l.append(stats)
                         vscores = [
                             vs.at[k].add(shrinkage * _route_tree_rows(
                                 arrays, vb, fmeta, depth_bound))
@@ -1316,14 +1345,14 @@ class GBDT:
                     mvals = inscan.eval_fn(score, vscores, earrs)
                     return ((score, key, vscores),
                             (jnp.stack(ints_l), jnp.stack(floats_l),
-                             gstats, mvals))
+                             gstats, mvals, _stack_seg_stats(stats_l)))
 
-                carry, (ints_all, floats_all, gstats_all, mvals_all) = \
-                    jax.lax.scan(body, (score, key, vscores), None,
-                                 length=T)
+                carry, (ints_all, floats_all, gstats_all, mvals_all,
+                        seg_all) = jax.lax.scan(
+                    body, (score, key, vscores), None, length=T)
                 score, key, vscores = carry
                 return (score, key, vscores, ints_all, floats_all,
-                        gstats_all, mvals_all)
+                        gstats_all, mvals_all, seg_all)
 
             chunk_run_eval = cost_jit(f"boost/chunk_eval[{T}]",
                                       chunk_run_eval)
@@ -1342,22 +1371,25 @@ class GBDT:
                 gstats = _grad_stats_core(grads, hesss)
                 roots = (roots_core(grads, hesss, member, bins)
                          if roots_core is not None else None)
-                ints_l, floats_l = [], []
+                ints_l, floats_l, stats_l = [], [], []
                 for k in range(C):
                     # same key stream as the per-iteration paths, so the
                     # same seed grows the same trees at any chunk size
                     key, sub = jax.random.split(key)
-                    score, ints_d, floats_d, _ = step_core(
+                    score, ints_d, floats_d, stats = step_core(
                         score, grads, hesss, member, bins, fmeta, fmask,
                         sub, shrinkage, jnp.int32(k), roots)
                     ints_l.append(ints_d)
                     floats_l.append(floats_d)
+                    stats_l.append(stats)
                 return ((score, key),
-                        (jnp.stack(ints_l), jnp.stack(floats_l), gstats))
+                        (jnp.stack(ints_l), jnp.stack(floats_l), gstats,
+                         _stack_seg_stats(stats_l)))
 
-            (score, key), (ints_all, floats_all, gstats_all) = jax.lax.scan(
+            (score, key), (ints_all, floats_all, gstats_all,
+                           seg_all) = jax.lax.scan(
                 body, (score, key), None, length=T)
-            return score, key, ints_all, floats_all, gstats_all
+            return score, key, ints_all, floats_all, gstats_all, seg_all
 
         chunk_run = cost_jit(f"boost/chunk[{T}]", chunk_run)
         self._chunk_fns[cache_key] = chunk_run
@@ -1403,6 +1435,13 @@ class GBDT:
                 TELEMETRY.counter_add("transfer/eval_fetch_calls")
                 TELEMETRY.counter_add("transfer/eval_fetch_bytes",
                                       int(mv.nbytes))
+            if payload.seg_stats is not None and TELEMETRY.level >= 1:
+                # 9 int32 a tree that rode out with the tree buffers: no
+                # sync of its own, and not a tree fetch (fetch_calls and
+                # fetch_bytes count tree buffers alone)
+                seg = np.asarray(payload.seg_stats)
+                _record_seg_stats(seg, seg.shape[0] * seg.shape[1],
+                                  self.grower_params.row_chunk)
             return [(iter_idx + t,
                      [(arrays, payload.shrinkage) for arrays in per_class],
                      gnp[t] if gnp is not None else None,
@@ -1458,34 +1497,45 @@ class GBDT:
         deltas undone), matching the reference's drop of the all-constant
         iteration (gbdt.cpp:543-551) — just detected one iteration (or
         chunk) late.
+
+        Two phases an entry: ``fetch_wait`` blocks on the device->host
+        copy, ``materialize`` builds the Tree objects on the host.
         """
         while len(self._pending) > keep_latest:
-            per_iter = self._entry_iter_arrays(self._pending.pop(0))
-            for j, (iter_idx, pairs, gstats, clen, mrow,
-                    wall) in enumerate(per_iter):
-                trees, all_const = self._materialize_iter(pairs)
-                if all_const:
-                    rest = [(ii, self._materialize_iter(pp)[0])
-                            for ii, pp, _g, _c, _m, _w in per_iter[j + 1:]]
-                    self._undo_pending_scores([(iter_idx, trees)] + rest
-                                              + self._materialize_rest())
-                    self._pending = []
-                    self._stop_flag = True
-                    self.iter_ = iter_idx
-                    log_warning("Stopped training because there are no "
-                                "more leaves that meet the split "
-                                "requirements")
+            with _PHASES.phase("fetch_wait"):
+                per_iter = self._entry_iter_arrays(self._pending.pop(0))
+            with _PHASES.phase("materialize"):
+                if self._materialize_entry(per_iter):
                     return
-                self._models.extend(trees)
-                self._note_trees(trees)
-                self._apply_valid_scores(trees)
-                self._health_emit(iter_idx, trees, gstats, clen,
-                                  wall_s=wall)
-                # in-scan eval rows surface only for materialized
-                # iterations: tail-of-chunk rows past an all-constant
-                # stop are discarded with their trees
-                if mrow is not None:
-                    self._inscan_evals.append((iter_idx, mrow))
+
+    def _materialize_entry(self, per_iter) -> bool:
+        """One fetched entry's iterations into self._models; True when an
+        all-constant iteration stopped training."""
+        for j, (iter_idx, pairs, gstats, clen, mrow,
+                wall) in enumerate(per_iter):
+            trees, all_const = self._materialize_iter(pairs)
+            if all_const:
+                rest = [(ii, self._materialize_iter(pp)[0])
+                        for ii, pp, _g, _c, _m, _w in per_iter[j + 1:]]
+                self._undo_pending_scores([(iter_idx, trees)] + rest
+                                          + self._materialize_rest())
+                self._pending = []
+                self._stop_flag = True
+                self.iter_ = iter_idx
+                log_warning("Stopped training because there are no "
+                            "more leaves that meet the split "
+                            "requirements")
+                return True
+            self._models.extend(trees)
+            self._note_trees(trees)
+            self._apply_valid_scores(trees)
+            self._health_emit(iter_idx, trees, gstats, clen, wall_s=wall)
+            # in-scan eval rows surface only for materialized
+            # iterations: tail-of-chunk rows past an all-constant
+            # stop are discarded with their trees
+            if mrow is not None:
+                self._inscan_evals.append((iter_idx, mrow))
+        return False
 
     def _note_trees(self, trees) -> None:
         """Record which features the model has split on, feeding the next
@@ -1594,12 +1644,20 @@ class GBDT:
             f"for divergence, or set check_nonfinite=false to ship the "
             f"model anyway")
 
+    def _scores_finite(self) -> bool:
+        """check_nonfinite's read of the score buffer (True with the
+        guardrail off).  The read blocks until everything dispatched has
+        finished, so ``nonfinite_guard`` is the phase the host waits out
+        an iteration's or a chunk's device work in."""
+        if not getattr(self.config, "check_nonfinite", True):
+            return True
+        with _PHASES.phase("nonfinite_guard"):
+            return bool(_all_finite(self.train_score))
+
     def _guard_nonfinite(self, it: int) -> None:
         """Per-iteration finiteness guardrail: on NaN/Inf scores, drop
         the just-trained iteration and raise (check_nonfinite)."""
-        if not getattr(self.config, "check_nonfinite", True):
-            return
-        if bool(_all_finite(self.train_score)):
+        if self._scores_finite():
             return
         # settle the async pipeline first: a NaN iteration may grow an
         # all-constant tree, which the flush already discards (lowering
@@ -1618,9 +1676,7 @@ class GBDT:
         trees are enqueued: a non-finite score buffer discards the whole
         failing chunk (its buffers never become trees), settles the
         still-good in-flight chunk, and raises."""
-        if not getattr(self.config, "check_nonfinite", True):
-            return
-        if bool(_all_finite(self.train_score)):
+        if self._scores_finite():
             return
         self._flush_pending()        # older chunks are still good
         TELEMETRY.fault_event("nonfinite_rollback", site="grad/nonfinite",
@@ -1671,7 +1727,7 @@ class GBDT:
                 and self.objective is not None):
             return self._train_one_iter_fused()
 
-        with _PHASES.phase("boost") as box:
+        with _PHASES.phase("boost"):
             if grad is None or hess is None:
                 if self.objective is None:
                     log_fatal("No objective and no custom gradients")
@@ -1686,7 +1742,6 @@ class GBDT:
             # jitted reduce stays off the default hot path
             gstats = (_grad_stats(grads, hesss) if HEALTH.active
                       else None)
-            box[0] = grads
 
         bins = self._device_bins()
         if use_async:
@@ -1699,19 +1754,18 @@ class GBDT:
                     g_k = jnp.pad(g_k, (0, self._row_pad))
                     h_k = jnp.pad(h_k, (0, self._row_pad))
                     member = jnp.pad(member, (0, self._row_pad))
-                with _PHASES.phase("grow") as box:
+                with _PHASES.phase("grow"):
                     arrays, leaf_id, *stats = self._grow_fn(
                         bins, g_k, h_k, member, self.fmeta, fmask, sub)
-                    box[0] = leaf_id
-                _maybe_print_seg_stats(stats)
+                _maybe_print_seg_stats(stats,
+                                       self.grower_params.row_chunk)
                 if self._row_pad:
                     leaf_id = leaf_id[: self.num_data]
-                with _PHASES.phase("score") as box:
+                with _PHASES.phase("score"):
                     self.train_score = self.train_score.at[k].set(
                         _apply_tree_score(self.train_score[k],
                                           arrays.leaf_value, leaf_id,
                                           jnp.float32(self.shrinkage_rate)))
-                    box[0] = self.train_score
                 ints_d, floats_d = _pack_tree_device(arrays)
                 self._start_host_copy(ints_d, floats_d)
                 items.append((ints_d, floats_d, self.shrinkage_rate))
@@ -1736,11 +1790,10 @@ class GBDT:
                 g_k = jnp.pad(g_k, (0, self._row_pad))
                 h_k = jnp.pad(h_k, (0, self._row_pad))
                 member = jnp.pad(member, (0, self._row_pad))
-            with _PHASES.phase("grow") as box:
+            with _PHASES.phase("grow"):
                 arrays, leaf_id, *stats = self._grow_fn(
                     bins, g_k, h_k, member, self.fmeta, fmask, sub)
-                box[0] = leaf_id
-            _maybe_print_seg_stats(stats)
+            _maybe_print_seg_stats(stats, self.grower_params.row_chunk)
             if self._row_pad:
                 leaf_id = leaf_id[: self.num_data]
             with _PHASES.phase("fetch"):
@@ -1795,7 +1848,7 @@ class GBDT:
         if self._fused_fns is None:
             self._build_fused_step()
         fused_grad, fused_step, fused_roots = self._fused_fns
-        with _PHASES.phase("boost") as box:
+        with _PHASES.phase("boost"):
             grads, hesss = fused_grad(self.train_score, self._obj_arrs)
             # bagging runs AFTER the gradient dispatch (GOSS's device-side
             # select transforms the gradients; membership-mask baggings
@@ -1803,7 +1856,6 @@ class GBDT:
             grads, hesss = self._bagging(self.iter_, grads, hesss)
             gstats = (_grad_stats(grads, hesss) if HEALTH.active
                       else None)
-            box[0] = grads
         bins = self._device_bins()
         roots = None
         if fused_roots is not None:
@@ -1825,19 +1877,18 @@ class GBDT:
             if coll_kind is not None:
                 from ..parallel import network
                 network.probe_dispatch_collective(coll_kind)
-            with _PHASES.phase("grow") as box:
+            with _PHASES.phase("grow"):
                 extra = () if roots is None else (roots,)
                 self.train_score, ints_d, floats_d, stats_t = fused_step(
                     self.train_score, grads, hesss, self.bag_weight,
                     bins, self.fmeta, fmask, sub,
                     jnp.float32(self.shrinkage_rate), jnp.int32(k), *extra)
-                box[0] = self.train_score
             if coll_kind is not None:
                 from ..parallel import network
                 network.record_collective(
                     coll_kind, self._grow_fn._collective_bytes,
                     time.perf_counter() - t0_grow)
-            _maybe_print_seg_stats(stats_t)
+            _maybe_print_seg_stats(stats_t, self.grower_params.row_chunk)
             self._start_host_copy(ints_d, floats_d)
             items.append((ints_d, floats_d, self.shrinkage_rate))
         self._pending.append((self.iter_, items, gstats))
@@ -2015,8 +2066,9 @@ class GBDT:
         # default, wall-to-ready when device_timing syncs inside the
         # CostJit seam — carried into the health stream's iter records
         t0_wall = time.perf_counter()
-        with step_annotation("chunk", first_iter), \
-                _PHASES.phase("chunk") as box:
+        with jax.profiler.StepTraceAnnotation("chunk",
+                                              step_num=first_iter), \
+                _PHASES.phase("chunk"):
             if self._chunk_guard is not None:
                 with self._chunk_guard():
                     out = fn(*args)
@@ -2024,19 +2076,20 @@ class GBDT:
                 out = fn(*args)
             if inscan is not None:
                 (self.train_score, self._key, self._vscores_dev, ints_all,
-                 floats_all, gstats_all, mvals_all) = out
+                 floats_all, gstats_all, mvals_all, seg_all) = out
             else:
                 (self.train_score, self._key, ints_all, floats_all,
-                 gstats_all) = out
-            box[0] = self.train_score
+                 gstats_all, seg_all) = out
         wall_s = time.perf_counter() - t0_wall
         # before the chunk's buffers can become trees: a non-finite score
         # discards them and raises (older pending chunks stay good)
         self._guard_chunk_nonfinite(first_iter, t)
-        self._start_host_copy(ints_all, floats_all, gstats_all, mvals_all)
+        seg_stats = seg_all[0] if seg_all else None
+        self._start_host_copy(ints_all, floats_all, gstats_all, mvals_all,
+                              seg_stats)
         self._pending.append((self.iter_, _PendingChunk(
             ints_all, floats_all, self.shrinkage_rate, t, mvals_all,
-            wall_s), gstats_all))
+            wall_s, seg_stats), gstats_all))
         self.iter_ += t
         with _PHASES.phase("fetch"):
             # valid-set scores update at materialization, and eval at the

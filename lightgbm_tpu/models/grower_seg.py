@@ -583,6 +583,7 @@ def make_grow_tree_segment(num_bins: int, params: GrowerParams,
           st.leaf_mono_hi[leaves2])
         return _write_scans(st, leaves2, infos, gains)
 
+    @jax.named_scope("compact")
     def compact(st: _SegState) -> _SegState:
         return compact_state(st, L, rb)
 
@@ -612,12 +613,13 @@ def make_grow_tree_segment(num_bins: int, params: GrowerParams,
         def grid_of(nb):
             return segment_grid_size(bucket_arr, nb)
 
-        if packed_acc:
-            w8, qscales, qclips = quantize_pack_channels(
-                grad, hess, member, bits=qbits)
-        else:
-            w8 = pack_channels(grad, hess, member)
-            qscales, qclips = None, jnp.int32(0)
+        with jax.named_scope("quantize_pack"):
+            if packed_acc:
+                w8, qscales, qclips = quantize_pack_channels(
+                    grad, hess, member, bits=qbits)
+            else:
+                w8 = pack_channels(grad, hess, member)
+                qscales, qclips = None, jnp.int32(0)
         G0 = jnp.sum(grad * member)
         H0 = jnp.sum(hess * member)
         C0 = jnp.sum(member)
@@ -656,21 +658,23 @@ def make_grow_tree_segment(num_bins: int, params: GrowerParams,
             if fused_route and not comm.no_subtract:
                 # route + smaller-child histogram in ONE kernel pass over
                 # the parent interval (histogram_segment_routed)
-                route = pack_route(leaf, new_leaf, f, t, dl, cat, bitset,
-                                   fmeta, p.packed4)
-                leaf_id, out = histogram_segment_routed(
-                    st.binsT, st.w8, st.leaf_id, lo, hi - lo, smaller,
-                    route, B, rb, packed4=p.packed4)
-                hist_small = unpack_hist(out[:G_cols])
+                with jax.named_scope("hist_split"):
+                    route = pack_route(leaf, new_leaf, f, t, dl, cat,
+                                       bitset, fmeta, p.packed4)
+                    leaf_id, out = histogram_segment_routed(
+                        st.binsT, st.w8, st.leaf_id, lo, hi - lo, smaller,
+                        route, B, rb, packed4=p.packed4)
+                    hist_small = unpack_hist(out[:G_cols])
                 if comm.reduce_hist is not None:
                     hist_small = comm.reduce_hist(hist_small, None, None,
                                                   None, fmeta)
                 blk = hi - lo
             else:
-                leaf_id = apply_route(
-                    st.binsT, st.leaf_id, fmeta, p.packed4, rb,
-                    f, t, dl, cat, bitset, leaf, new_leaf, lo, hi - lo,
-                    route_kernel)
+                with jax.named_scope("route"):
+                    leaf_id = apply_route(
+                        st.binsT, st.leaf_id, fmeta, p.packed4, rb,
+                        f, t, dl, cat, bitset, leaf, new_leaf, lo, hi - lo,
+                        route_kernel)
 
             st = st._replace(
                 leaf_id=leaf_id,
@@ -696,16 +700,18 @@ def make_grow_tree_segment(num_bins: int, params: GrowerParams,
                 # voting-parallel: each call's election masks differ, so
                 # parent-minus-smaller is invalid (CommHooks doc) — build
                 # BOTH children from data over the same interval
-                hist_left, _b1 = hist_leaf(st, leaf, G_cols, fmeta,
-                                            qscales)
-                hist_right, _b2 = hist_leaf(st, new_leaf, G_cols, fmeta,
-                                            qscales)
+                with jax.named_scope("hist_split"):
+                    hist_left, _b1 = hist_leaf(st, leaf, G_cols, fmeta,
+                                                qscales)
+                    hist_right, _b2 = hist_leaf(st, new_leaf, G_cols,
+                                                fmeta, qscales)
                 blk = _b1 + _b2
                 grid_blk = grid_of(_b1) + grid_of(_b2)
             else:
                 if not fused_route:
-                    hist_small, blk = hist_leaf(st, smaller, G_cols,
-                                                fmeta, qscales)
+                    with jax.named_scope("hist_split"):
+                        hist_small, blk = hist_leaf(st, smaller, G_cols,
+                                                    fmeta, qscales)
                 grid_blk = grid_of(blk)
                 hist_parent = st.leaf_hist[leaf]
                 hist_large = hist_parent - hist_small
@@ -776,12 +782,13 @@ def make_grow_tree_segment(num_bins: int, params: GrowerParams,
                 leaf_c=st.leaf_c.at[leaf].set(Cl).at[new_leaf].set(Cr),
                 tree=tree,
             )
-            st = scan_pair(
-                st, jnp.stack([leaf, new_leaf]),
-                jnp.stack([hist_left, hist_right]),
-                jnp.stack([Gl, Gr]), jnp.stack([Hl, Hr]),
-                jnp.stack([Cl, Cr]), depth_child, fmeta, feature_mask, key,
-                jnp.stack([2 * step, 2 * step + 1]))
+            with jax.named_scope("split_scan"):
+                st = scan_pair(
+                    st, jnp.stack([leaf, new_leaf]),
+                    jnp.stack([hist_left, hist_right]),
+                    jnp.stack([Gl, Gr]), jnp.stack([Hl, Hr]),
+                    jnp.stack([Cl, Cr]), depth_child, fmeta, feature_mask,
+                    key, jnp.stack([2 * step, 2 * step + 1]))
             return st
 
         # adaptive compaction (module docstring): amortize the sort against
@@ -816,8 +823,9 @@ def make_grow_tree_segment(num_bins: int, params: GrowerParams,
         st = fresh_state(binsT, w8, n, L, G_cols, B, F, max_blocks,
                          G0, H0, C0, fmeta, p)
         if root_hist is None:
-            root_hist, root_blk = hist_leaf(st, jnp.int32(0), G_cols,
-                                            fmeta, qscales)
+            with jax.named_scope("hist_root"):
+                root_hist, root_blk = hist_leaf(st, jnp.int32(0), G_cols,
+                                                fmeta, qscales)
         else:
             # external batched pass: charge the same scan cost so the
             # adaptive-compaction accounting is unchanged
@@ -825,10 +833,12 @@ def make_grow_tree_segment(num_bins: int, params: GrowerParams,
         st = st._replace(leaf_hist=st.leaf_hist.at[0].set(root_hist),
                          scanned_since=root_blk, scanned_total=root_blk,
                          grid_total=jnp.int32(max_blocks))
-        st = scan_leaf(st, 0, root_hist, G0, H0, C0, jnp.int32(0), fmeta,
-                       feature_mask, key, 2 * L)
+        with jax.named_scope("split_scan"):
+            st = scan_leaf(st, 0, root_hist, G0, H0, C0, jnp.int32(0),
+                           fmeta, feature_mask, key, 2 * L)
         st = lax.while_loop(can_grow, epoch, st)
-        leaf_id_orig = _unpermute(st.order, st.leaf_id)
+        with jax.named_scope("unpermute"):
+            leaf_id_orig = _unpermute(st.order, st.leaf_id)
         # scan/compaction counters always leave the jit as a third output
         # (stable arity; no host callbacks, so no jax.debug.print, in
         # compiled code) — printing them is gated on

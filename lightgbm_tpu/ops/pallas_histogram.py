@@ -1179,6 +1179,9 @@ def _histogram_segment_routed(binsT: jax.Array, w8: jax.Array,
             vmem_limit_bytes=fused_vmem_limit(F, num_bins, 1, block_rows,
                                               packed4)),
         interpret=interpret,
+        # the name a profiler trace shows (PERF.md, the ledger's
+        # breakdown): pinned, so renaming this function cannot move it
+        name="_histogram_segment_routed",
     )(scalars, binsT, w8, frow, leaf_id.reshape(1, -1))
     return lid_out[0], hist.reshape(F_log, num_bins, och)
 
@@ -1333,6 +1336,7 @@ def _histogram_frontier_routed(binsT: jax.Array, w8: jax.Array,
             vmem_limit_bytes=fused_vmem_limit(F, num_bins, K, block_rows,
                                               packed4, targets_k=KT)),
         interpret=interpret,
+        name="_histogram_frontier_routed",     # as the trace shows it
     )(scalars, binsT, w8, frows, leaf_id.reshape(1, -1))
     return lid_out[0], hist.reshape(F_log, num_bins, KT,
                                     och).transpose(2, 0, 1, 3)
@@ -1626,6 +1630,7 @@ def route_window(binsT: jax.Array, leaf_id: jax.Array,
         # operands: scalars, frow, leaf_id — leaf_id aliases the output
         input_output_aliases={2: 0},
         interpret=interpret,
+        name="route_window",                   # as the trace shows it
     )(scalars, frow, leaf_id.reshape(1, -1))
     return lid_out[0]
 

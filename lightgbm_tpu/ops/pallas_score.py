@@ -115,5 +115,6 @@ def score_gather_add(score_row: jax.Array, leaf_id: jax.Array,
         out_specs=pl.BlockSpec((1, BLOCK), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct(sp.shape, jnp.float32),
         interpret=interpret,
+        name="score_gather_add",               # as the trace shows it
     )(tv, lp, sp)
     return out[0, :n]

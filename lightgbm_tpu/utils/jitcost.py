@@ -112,12 +112,16 @@ class CostJit:
         return [c for c in self._compiled.values() if c is not None]
 
     def _aot_compile(self, args, key):
+        from .phase import GLOBAL_TIMER
         from .telemetry import TELEMETRY
-        try:
-            compiled = self._fn.lower(*args).compile()
-            TELEMETRY.record_cost(self._label, harvest_cost(compiled))
-        except Exception:
-            compiled = None
+        # a phase of its own, nested in whatever phase dispatched: a
+        # reader takes the compile out of a cold first ``chunk``
+        with GLOBAL_TIMER.phase(f"compile[{self._label}]"):
+            try:
+                compiled = self._fn.lower(*args).compile()
+                TELEMETRY.record_cost(self._label, harvest_cost(compiled))
+            except Exception:
+                compiled = None
         self._compiled[key] = compiled
         return compiled
 

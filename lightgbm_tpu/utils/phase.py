@@ -12,11 +12,16 @@ registry (utils/telemetry.py), which adds counters, a per-iteration
 timeline and Chrome trace export on top.
 
 Because device work is dispatched asynchronously, a phase's wall time
-normally measures only host-side dispatch.  Set
-``LIGHTGBM_TPU_SYNC_TIMERS=1`` to block on device results at each phase
-boundary — slower, but attributes device time to the phase that spent it
-(the jax-profiler trace, ``LIGHTGBM_TPU_PROFILE_DIR``, is the zero-skew
-alternative).
+measures host-side dispatch only.  Device time per phase comes from a
+jax-profiler trace: every phase is also a
+``jax.profiler.TraceAnnotation("lgbm:<name>")`` and every chunk dispatch
+a ``StepTraceAnnotation`` (models/gbdt.py), so whatever capture is open
+— the program's own below, or one the caller started with
+``jax.profiler.start_trace`` — holds the host phases on the device
+trace's clock, nested by the host thread's stack, and the device
+program's operations carry the ``jax.named_scope`` path of the phase
+that issued them (docs/OBSERVABILITY.md).  With no capture open an
+annotation is a flag test.
 
 Profiler capture comes in two shapes: the original all-or-nothing
 session (``LIGHTGBM_TPU_PROFILE_DIR`` wraps the whole train loop) and
@@ -24,10 +29,7 @@ the windowed programmatic capture (``profile_window=START:END`` config
 parameter / ``LIGHTGBM_TPU_PROFILE_WINDOW`` env), which opens the
 ``jax.profiler`` trace only for that boosting-iteration span — a
 multi-hour run yields a viewable-sized artifact of exactly the steady
-state (or exactly the suspect iterations).  While either capture is
-open, phases are wrapped in ``jax.profiler.TraceAnnotation`` and chunk
-dispatches in ``StepTraceAnnotation`` (models/gbdt.py), so the device
-trace aligns with the host-side Chrome trace.  The artifact path and
+state (or exactly the suspect iterations).  The artifact path and
 actual window land in the metrics blob's ``timing`` section.
 """
 
@@ -37,12 +39,10 @@ import os
 import threading
 import time
 from collections import defaultdict
-from contextlib import contextmanager, nullcontext
+from contextlib import contextmanager
 from typing import Dict, Optional, Tuple
 
-
-def _sync_enabled() -> bool:
-    return os.environ.get("LIGHTGBM_TPU_SYNC_TIMERS", "") not in ("", "0")
+import jax
 
 
 class PhaseTimer:
@@ -56,34 +56,21 @@ class PhaseTimer:
         self.counts: Dict[str, int] = defaultdict(int)
 
     @contextmanager
-    def phase(self, name: str, sync_obj=None):
-        sync = _sync_enabled()
-        if sync and sync_obj is not None:
-            import jax
-            jax.block_until_ready(sync_obj)
-        ann = None
-        if profiler_active():
-            # align host phase structure with the device profiler trace
-            import jax
-            ann = jax.profiler.TraceAnnotation(f"lgbm:{name}")
-            ann.__enter__()
+    def phase(self, name: str):
         t0 = time.perf_counter()
-        box = [None]
-        try:
-            yield box
-        finally:
-            if sync and box[0] is not None:
-                import jax
-                jax.block_until_ready(box[0])
-            if ann is not None:
-                ann.__exit__(None, None, None)
-            dur = time.perf_counter() - t0
-            with self._lock:
-                self.seconds[name] += dur
-                self.counts[name] += 1
-            from .telemetry import TELEMETRY
-            TELEMETRY.record_span(name, t0, dur)
-            TELEMETRY.sample_memory(name)
+        # the host phase structure, on the clock of any open profiler
+        # trace (looked up per call: tests patch the annotation class)
+        with jax.profiler.TraceAnnotation(f"lgbm:{name}"):
+            try:
+                yield
+            finally:
+                dur = time.perf_counter() - t0
+                with self._lock:
+                    self.seconds[name] += dur
+                    self.counts[name] += 1
+                from .telemetry import TELEMETRY
+                TELEMETRY.record_span(name, t0, dur)
+                TELEMETRY.sample_memory(name)
 
     def reset(self) -> None:
         with self._lock:
@@ -102,8 +89,7 @@ class PhaseTimer:
         parts = []
         for name, (sec, n) in sorted(snap.items(), key=lambda kv: -kv[1][0]):
             parts.append(f"{name}={sec:.3f}s/{n}")
-        mode = "sync" if _sync_enabled() else "dispatch"
-        out = f"phases[{mode}] total={total:.3f}s " + " ".join(parts)
+        out = f"phases[dispatch] total={total:.3f}s " + " ".join(parts)
         # append the network collective counters (linkers.h:114-117
         # equivalent) when the parallel machinery has been used
         import sys
@@ -208,7 +194,6 @@ class ProfileWindow:
             if iteration >= self.end:
                 self._close(iteration)
         elif self.start <= iteration < self.end:
-            import jax
             jax.profiler.start_trace(self.dir)
             self.is_open = True
             self._opened_at = iteration
@@ -218,7 +203,6 @@ class ProfileWindow:
         # close() must not call it again on an already-broken session
         self.is_open = False
         self._done = True
-        import jax
         jax.profiler.stop_trace()
         from .telemetry import TELEMETRY
         TELEMETRY.record_profile_capture({
@@ -238,29 +222,11 @@ class ProfileWindow:
 PROFILE_WINDOW = ProfileWindow()
 
 
-def profiler_active() -> bool:
-    """True while ANY jax-profiler capture (whole-run session or
-    window) is open — gates the Trace/StepTraceAnnotation wrappers so
-    the un-profiled path stays annotation-free."""
-    return _profile_session is not None or PROFILE_WINDOW.is_open
-
-
-def step_annotation(name: str, step: int):
-    """``jax.profiler.StepTraceAnnotation`` while a capture is open
-    (the profiler's per-step grouping for chunk dispatches), else a
-    zero-overhead null context."""
-    if not profiler_active():
-        return nullcontext()
-    import jax
-    return jax.profiler.StepTraceAnnotation(name, step_num=int(step))
-
-
 def maybe_start_profile() -> None:
     """Start a jax-profiler trace if LIGHTGBM_TPU_PROFILE_DIR is set."""
     global _profile_session
     path = os.environ.get("LIGHTGBM_TPU_PROFILE_DIR")
     if path and _profile_session is None:
-        import jax
         jax.profiler.start_trace(path)
         _profile_session = path
 
@@ -271,7 +237,6 @@ def maybe_stop_profile() -> None:
         # clear the session marker FIRST: if stop_trace raises, a retry
         # must not call it again on an already-broken session
         path, _profile_session = _profile_session, None
-        import jax
         jax.profiler.stop_trace()
         from .telemetry import TELEMETRY
         TELEMETRY.record_profile_capture({"dir": path, "kind": "session"})

@@ -26,9 +26,10 @@ and exports the trace there at the end of training (plus an atexit
 backstop).
 
 Timing caveat: device work is dispatched asynchronously, so spans and
-phase seconds measure host-side dispatch unless
-``LIGHTGBM_TPU_SYNC_TIMERS=1`` (see utils/phase.py).  The ``mode`` field
-of ``stats()`` records which one a blob was collected under.
+phase seconds measure host-side dispatch (the ``mode`` field of
+``stats()`` is the constant ``"dispatch"``); device time per phase comes
+from a jax-profiler trace, which holds every phase as an ``lgbm:<name>``
+annotation (see utils/phase.py).
 
 Compile visibility comes from ``jax.monitoring`` listeners
 (install_jax_listeners): retrace counts/seconds, backend compile
@@ -414,6 +415,8 @@ class TelemetryRegistry:
         self._spans_recorded = 0
         self._timeline: deque = deque(maxlen=TIMELINE_CAPACITY)
         self._iter_snapshot: Dict[str, float] = {}
+        # GLOBAL_TIMER's (seconds, count) per phase at the last mark
+        self._phase_snapshot: Dict[str, Any] = {}
         self._epoch = time.perf_counter()
         self._config_level: Optional[int] = None
         self._jax_listeners_installed = False
@@ -586,10 +589,22 @@ class TelemetryRegistry:
     def mark_iteration(self, iteration: int, count: int = 1) -> None:
         """Close one timeline entry: iteration index (the last iteration
         when ``count`` > 1, i.e. a boosting chunk), the wall offset since
-        reset, and the counter deltas since the previous mark."""
+        reset, and the counter deltas and per-phase seconds and counts
+        (``phases``, from the global PhaseTimer) since the previous
+        mark.  A compile shows as a ``compile[label]`` phase of the
+        entry that paid it, so a reader takes the steady entries alone."""
         if self._level < 1:
             return
+        from .phase import GLOBAL_TIMER
+        now = GLOBAL_TIMER.snapshot()
         with self._lock:
+            phases = {}
+            for name, (sec, cnt) in now.items():
+                sec0, cnt0 = self._phase_snapshot.get(name, (0.0, 0))
+                if cnt != cnt0:
+                    phases[name] = {"seconds": round(sec - sec0, 9),
+                                    "count": cnt - cnt0}
+            self._phase_snapshot = now
             self._note_writer()
             deltas = {}
             for k, v in self._counters.items():
@@ -600,7 +615,7 @@ class TelemetryRegistry:
             self._timeline.append(
                 {"iter": int(iteration), "count": int(count),
                  "t": round(time.perf_counter() - self._epoch, 6),
-                 "counters": deltas})
+                 "counters": deltas, "phases": phases})
 
     # -------------------------------------------------------------- faults
     def fault_event(self, kind: str, site: str = "", detail: str = "",
@@ -1078,7 +1093,7 @@ class TelemetryRegistry:
         only when a drift window synced, so earlier blobs keep their
         v6 shape."""
         import sys
-        from .phase import GLOBAL_TIMER, _sync_enabled
+        from .phase import GLOBAL_TIMER
         with self._lock:
             counters = dict(self._counters)
             gauges = dict(self._gauges)
@@ -1097,7 +1112,7 @@ class TelemetryRegistry:
             "version": METRICS_VERSION,
             "level": self._level,
             "telemetry_level": self._level,
-            "mode": "sync" if _sync_enabled() else "dispatch",
+            "mode": "dispatch",
             "phases": phases,
             "counters": counters,
             "gauges": gauges,
@@ -1232,7 +1247,9 @@ class TelemetryRegistry:
         listeners) and re-zero the time base; also resets the network
         collective counters so a measurement window starts clean."""
         import sys
+        from .phase import GLOBAL_TIMER
         self.stop_mem_sampler()
+        phases_now = GLOBAL_TIMER.snapshot()
         with self._lock:
             self._counters.clear()
             self._gauges.clear()
@@ -1240,6 +1257,7 @@ class TelemetryRegistry:
             self._spans_recorded = 0
             self._timeline.clear()
             self._iter_snapshot = {}
+            self._phase_snapshot = phases_now
             self._epoch = time.perf_counter()
             self._writer = None
             self._race_flagged = False

@@ -505,3 +505,181 @@ def test_profile_session_is_exception_safe(monkeypatch, tmp_path):
         with phase.profile_session():
             raise RuntimeError("boom")
     assert started == [1] and stopped == [1]
+
+
+# ----------------------------------------- the chunked path's own work
+# (counters out of the scan, phases in the timeline and in any open
+# profiler trace, named scopes in the device program)
+
+SEG_KEYS = ("seg/scanned_blocks", "seg/grid_steps", "seg/compactions",
+            "seg/trees")
+# sha256 of the model string the PARENT commit (963c3c4) grows for
+# _seg_run(chunk=4, rounds=8): one more scan output and HLO metadata must
+# not move a bit of it
+PARENT_MODEL_SHA256 = (
+    "c0f98571037b0be86966ed82fa7fbe020d40df8a92de9744699bd4fad122a732")
+
+
+def _seg_data(n=800):
+    r = np.random.RandomState(20260925)
+    X = r.normal(size=(n, 8)).astype(np.float32)
+    y = (X[:, 0] + X[:, 1] * X[:, 2] > 0).astype(np.float64)
+    return X, y
+
+
+def _seg_run(chunk, rounds=8, n=800, **kw):
+    X, y = _seg_data(n)
+    return lgb.train(_params(num_leaves=15, tpu_tree_impl="segment",
+                             tpu_histogram_backend="pallas",
+                             tpu_boost_chunk=chunk, **kw),
+                     lgb.Dataset(X, y), num_boost_round=rounds)
+
+
+def _trees_of(bst):
+    return "\n".join(line for line in bst.model_to_string().splitlines()
+                     if not line.startswith(("[tpu_boost_chunk",
+                                             "[telemetry_level")))
+
+
+def test_chunked_seg_counters_match_per_iteration_path(monkeypatch):
+    """The chunk scan carries the grower's counters out with the tree
+    buffers: recorded with no env var, totals equal to what the
+    per-iteration path records under LIGHTGBM_TPU_SEG_STATS for the same
+    seed, and not counted as a tree fetch."""
+    bst = _seg_run(chunk=4, n=2500)
+    stats = bst.get_stats()
+    c = stats["counters"]
+    assert c["seg/trees"] == 8
+    assert c["seg/scanned_blocks"] > 0 and c["seg/compactions"] >= 1
+    assert c["seg/grid_steps"] >= c["seg/scanned_blocks"]
+    assert c["transfer/fetch_calls"] == 2        # two chunks, trees only
+    rb = bst.gbdt.grower_params.row_chunk
+    assert stats["gauges"]["seg/block_rows"] == rb
+    # the root alone scans every block of every tree
+    assert c["seg/scanned_blocks"] >= 8 * -(-2500 // rb)
+    chunked = {k: c[k] for k in SEG_KEYS}
+
+    GLOBAL_TIMER.reset()
+    TELEMETRY.reset()
+    monkeypatch.setenv("LIGHTGBM_TPU_SEG_STATS", "1")
+    per_iter = _seg_run(chunk=4, n=2500)
+    assert per_iter.gbdt.boost_chunk_size() == 1   # the env forces it
+    c1 = per_iter.get_stats()["counters"]
+    assert {k: c1[k] for k in SEG_KEYS} == chunked
+    assert _trees_of(per_iter) == _trees_of(bst)
+
+
+def test_chunked_seg_counters_off_at_level0():
+    """telemetry_level=0: the same chunk program, nothing recorded, the
+    same trees."""
+    import jax.numpy as jnp
+
+    def lowered(g):
+        return g._chunk_fns[4].lower(
+            g.train_score, g._key, g.bag_weight, g._device_bins(), g.fmeta,
+            g._full_fmask, jnp.float32(g.shrinkage_rate),
+            g._obj_arrs).as_text()
+
+    off = _seg_run(chunk=4, telemetry_level=0)
+    stats = off.get_stats()
+    assert not [k for k in list(stats["counters"]) + list(stats["gauges"])
+                if k.startswith(("seg/", "hist/", "transfer/"))]
+    assert stats["timeline"] == []
+    on = _seg_run(chunk=4)
+    assert on.get_stats()["counters"]["seg/trees"] == 8
+    assert _trees_of(off) == _trees_of(on)
+    # one program at every level: the scan's counter output is always
+    # there, only the host-side record is switched
+    assert lowered(off.gbdt) == lowered(on.gbdt)
+
+
+def test_timeline_entries_carry_phases():
+    """Each chunk's timeline entry holds the per-phase seconds and counts
+    since the last mark; the compile is a phase of the first entry
+    alone, so a reader takes the steady entries."""
+    bst = _seg_run(chunk=4)
+    first, second = bst.get_stats()["timeline"]
+    for entry in (first, second):
+        assert entry["count"] == 4
+        for name in ("chunk", "nonfinite_guard", "fetch"):
+            assert entry["phases"][name]["count"] == 1
+            assert entry["phases"][name]["seconds"] >= 0.0
+    # the first chunk's trees are fetched under the second's dispatch
+    assert "fetch_wait" not in first["phases"]
+    for name in ("fetch_wait", "materialize"):
+        assert second["phases"][name]["count"] == 1
+        assert second["phases"][name]["seconds"] \
+            <= second["phases"]["fetch"]["seconds"]
+    compile_key = "compile[boost/chunk[4]]"
+    assert first["phases"][compile_key]["seconds"] > 0.0
+    assert first["phases"]["chunk"]["seconds"] \
+        >= first["phases"][compile_key]["seconds"]
+    assert not [k for k in second["phases"] if k.startswith("compile[")]
+    # set-up's phases land in the first entry too
+    assert first["phases"]["booster_init"]["count"] == 1
+    assert "booster_init" not in second["phases"]
+    assert first["phases"]["bin_find"]["count"] == 1
+
+
+def test_phases_enter_whatever_profiler_trace_is_open(monkeypatch):
+    """No program-owned capture (no LIGHTGBM_TPU_PROFILE_DIR, no
+    profile_window): the phases are trace annotations all the same, so a
+    trace the CALLER opened holds them."""
+    import jax
+
+    entered = []
+
+    class Recorder:
+        def __init__(self, name, **kw):
+            self.name = name
+
+        def __enter__(self):
+            entered.append(self.name)
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Recorder)
+    monkeypatch.setattr(jax.profiler, "StepTraceAnnotation", Recorder)
+    seen = []
+    X, y = _seg_data()
+    lgb.train(_params(num_leaves=15, tpu_boost_chunk=4), lgb.Dataset(X, y),
+              num_boost_round=8, callbacks=[lambda env: seen.append(1)])
+    for name in ("lgbm:chunk", "lgbm:fetch", "lgbm:booster_init",
+                 "lgbm:fetch_wait", "lgbm:materialize", "lgbm:callbacks",
+                 "lgbm:nonfinite_guard",
+                 "lgbm:h2d_upload", "lgbm:compile[boost/chunk[4]]",
+                 "lgbm:bin_find", "lgbm:bin_quantize"):
+        assert name in entered, name
+    assert entered.count("chunk") == 2          # the step annotations
+    assert seen
+
+
+def test_phase_yields_nothing_and_mode_is_constant():
+    with GLOBAL_TIMER.phase("probe") as got:
+        pass
+    assert got is None
+    assert GLOBAL_TIMER.snapshot()["probe"][1] == 1
+    assert TELEMETRY.stats()["mode"] == "dispatch"
+    assert GLOBAL_TIMER.summary().startswith("phases[dispatch] ")
+
+
+def test_chunk_program_names_its_scopes_and_grows_the_parents_trees():
+    """jax.named_scope is HLO metadata only: the compiled chunk program
+    names the grower's and the step's phases, and the model of a fixed
+    seed is the parent commit's, bit for bit."""
+    import hashlib
+    import re
+
+    bst = _seg_run(chunk=4)
+    text = bst.gbdt._chunk_fns[4].executables()[0].as_text()
+    scopes = set()
+    for path in re.findall(r'op_name="([^"]*)"', text):
+        scopes.update(path.split("/"))
+    for name in ("compact", "hist_split", "split_scan", "unpermute",
+                 "hist_root", "quantize_pack", "grad", "grad_stats",
+                 "score", "pack_tree"):
+        assert name in scopes, name
+    digest = hashlib.sha256(bst.model_to_string().encode()).hexdigest()
+    assert digest == PARENT_MODEL_SHA256
