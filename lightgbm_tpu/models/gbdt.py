@@ -104,6 +104,15 @@ def _record_seg_stats(rows: np.ndarray, trees: int,
         TELEMETRY.counter_add("hist/stage_hits", int(rows[:, 7].sum()))
         TELEMETRY.counter_add("hist/stage_lookups",
                               int(rows[:, 8].sum()))
+    # the strict grower's splits and its lookahead lane sets (0 on the
+    # paths that run none: a hit share of 0, not a missing one)
+    if rows[:, 9].sum():
+        TELEMETRY.counter_add("seg/splits", int(rows[:, 9].sum()))
+        TELEMETRY.counter_add("seg/lookahead_hits", int(rows[:, 10].sum()))
+        TELEMETRY.counter_add("seg/lookahead_filled",
+                              int(rows[:, 11].sum()))
+        TELEMETRY.counter_add("seg/route_only_blocks",
+                              int(rows[:, 12].sum()))
 
 
 def _stack_seg_stats(stats_l):

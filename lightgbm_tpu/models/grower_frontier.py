@@ -676,7 +676,10 @@ def make_grow_tree_frontier(num_bins: int, params: GrowerParams,
         stats = jnp.stack([st.scanned_total, st.num_sorts, st.grid_total,
                            jnp.int32(max_blocks), jnp.int32(K),
                            fk_rounds, qclips.astype(jnp.int32),
-                           s_hits, s_looks])
+                           s_hits, s_looks]
+                          # splits and the strict grower's lookahead
+                          # counters: none here
+                          + [jnp.int32(0)] * 4)
         return st.tree, leaf_id_orig, stats
 
     if wrap is not None:

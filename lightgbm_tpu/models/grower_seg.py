@@ -19,7 +19,13 @@ re-partition per split (data-dependent scatter), so this grower uses
   * each split's smaller-child histogram runs the scalar-prefetched
     pallas segment kernel (ops/pallas_histogram.histogram_segment) over
     just that confinement interval: DMA and compute scale with the
-    interval, and out-of-range grid steps are skipped for free.
+    interval, and out-of-range grid steps are skipped for free;
+  * where the shape leaves lane sets free (``lookahead_width``), that
+    pass also fills them with the smaller-child histograms that the
+    PENDING best splits of other leaves inside the interval will need,
+    so a later split that finds its histogram ready only routes
+    (``lookahead_split``: the same best-first tree from about half the
+    scans).
 
 Everything — splits, routing, compaction — is one ``lax.fori_loop`` inside
 one jit; no host round-trips during growth.  Exact leaf-wise: the grown
@@ -38,12 +44,16 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..ops.pallas_histogram import (NUM_CHANNELS, _segment_buckets,
-                                    bucket_index, fused_packed_optin,
+                                    bucket_index, empty_lookahead_slots,
+                                    fused_packed_optin,
                                     fused_route_decisions,
                                     fused_route_policy,
                                     histogram_segment,
-                                    histogram_segment_routed, null_route,
-                                    pack_channels, pack_route,
+                                    histogram_segment_lookahead,
+                                    histogram_segment_routed,
+                                    lookahead_width, null_route,
+                                    pack_channels, pack_lookahead_slots,
+                                    pack_route,
                                     packed_acc_bits, packed_acc_decisions,
                                     packed_acc_enabled,
                                     quantize_pack_channels,
@@ -77,22 +87,25 @@ COMPACT_WASTE = float(_os.environ.get("LIGHTGBM_TPU_COMPACT_WASTE", "9.0"))
 # the growers' third jit output: i32 counter vector, one row per device
 # under the data-parallel wrappers.  Fixed width so every grower/wrapper
 # agrees; slots [fused_k_rounds, quant_clips, stage_hits, stage_lookups]
-# stay 0 on paths that don't fuse-K / quantize / stage.
-SEG_STATS_SLOTS = 9
+# stay 0 on paths that don't fuse-K / quantize / stage, and [lookahead_hits,
+# lookahead_filled, route_only_blocks] where no lookahead lane sets run.
+SEG_STATS_SLOTS = 13
 
 
 def seg_stats_enabled() -> bool:
     """When LIGHTGBM_TPU_SEG_STATS is set, the counters the growers
     return — [scanned_blocks, compactions, grid_steps, max_blocks, K,
-    fused_k_rounds, quant_clips, stage_hits, stage_lookups] — are
-    printed per tree."""
+    fused_k_rounds, quant_clips, stage_hits, stage_lookups, splits,
+    lookahead_hits, lookahead_filled, route_only_blocks] — are printed
+    per tree."""
     return bool(_os.environ.get("LIGHTGBM_TPU_SEG_STATS"))
 
 
 def print_seg_stats(stats) -> None:
     """Host-side rendering of the counters a grower returned (compiled
     code carries no host callbacks, so this replaces the old
-    jax.debug.print).  Accepts [6] or a per-device concatenation [D*6].
+    jax.debug.print).  Accepts one [SEG_STATS_SLOTS] vector or a
+    per-device concatenation of them.
 
     ``grid`` counts the kernel grid steps actually dispatched (the bucket
     the interval landed in, summed over calls); grid − scanned is the
@@ -104,7 +117,7 @@ def print_seg_stats(stats) -> None:
 
     rows = np.asarray(stats).reshape(-1, SEG_STATS_SLOTS)
     for d, (scanned, sorts, grid, max_blocks, k, fkr, clips, shits,
-            slooks) in enumerate(rows):
+            slooks, splits, lhits, lfill, ronly) in enumerate(rows):
         dev = f" dev{d}" if len(rows) > 1 else ""
         nb = max(int(max_blocks), 1)
         extra = ""
@@ -115,6 +128,10 @@ def print_seg_stats(stats) -> None:
         if slooks:
             extra += (f", stage hits {int(shits)}/{int(slooks)} "
                       f"({shits / max(int(slooks), 1):.0%})")
+        if lfill:
+            extra += (f", lookahead {int(lhits)} of {int(splits)} splits "
+                      f"served ({int(lfill)} filled, {int(ronly)} "
+                      f"route-only blocks)")
         sys.stderr.write(
             f"seg stats{dev}: scanned {int(scanned)} blocks "
             f"({scanned / nb:.1f} N-equivalents), "
@@ -153,6 +170,21 @@ class _SegState(NamedTuple):
     best_i32: jax.Array
     best_cat_bitset: jax.Array
     tree: TreeArrays
+    # lookahead histograms (strict grower, lookahead_split): the smaller
+    # child that leaf l's cached best split will make, filled by another
+    # leaf's pass over an interval holding all of l's rows.  An entry
+    # belongs to the split it was filled for: valid from the fill until l
+    # is split, whatever compactions fall between (they move rows, not
+    # sums), and cleared then, so the child that keeps l's id starts
+    # without one.  No rows / never set where the mechanism is off.
+    look_hist: jax.Array       # [L, G, B, 3] f32 ([0, G, B, 3] when off)
+    look_ok: jax.Array         # [L] bool
+    # i32 scalars: splits committed, those served by a lookahead
+    # histogram, histograms filled, blocks that were routed only
+    num_splits: jax.Array
+    look_hits: jax.Array
+    look_filled: jax.Array
+    route_only: jax.Array
 
 
 def _pack_bins_words(binsT):
@@ -384,9 +416,10 @@ def compact_state(st: _SegState, L: int, rb: int) -> _SegState:
 
 
 def fresh_state(binsT, w8, n, L, G_cols, B, F, max_blocks, G0, H0, C0,
-                fmeta, p) -> _SegState:
+                fmeta, p, lookahead: bool = False) -> _SegState:
     """Initial _SegState + TreeArrays for a new tree (root covers
-    everything).  Shared by the strict and frontier growers."""
+    everything).  Shared by the strict and frontier growers;
+    ``lookahead`` sizes the lookahead-histogram table (else no rows)."""
     neg = jnp.full(L, NEG_INF, dtype=jnp.float32)
     zeros_l = jnp.zeros(L, dtype=jnp.float32)
     tree0 = TreeArrays(
@@ -432,6 +465,13 @@ def fresh_state(binsT, w8, n, L, G_cols, B, F, max_blocks, G0, H0, C0,
         best_i32=jnp.zeros((L, 4), dtype=jnp.int32).at[:, 0].set(-1),
         best_cat_bitset=jnp.zeros((L, 8), dtype=jnp.uint32),
         tree=tree0,
+        look_hist=jnp.zeros((L if lookahead else 0, G_cols, B, 3),
+                            dtype=jnp.float32),
+        look_ok=jnp.zeros(L, dtype=bool),
+        num_splits=jnp.int32(0),
+        look_hits=jnp.int32(0),
+        look_filled=jnp.int32(0),
+        route_only=jnp.int32(0),
     )
 
 
@@ -444,6 +484,23 @@ def _unpack_w8_words(words):
     ch6 = lax.bitcast_convert_type(inter, jnp.bfloat16)
     return jnp.concatenate(
         [ch6, jnp.zeros((NUM_CHANNELS - 6, ch6.shape[1]), jnp.bfloat16)])
+
+
+def _lookahead_pending(st: _SegState, leaf, lo, hi) -> jax.Array:
+    """[L] f32: the cached gain of every leaf whose smaller-child
+    histogram a pass over blocks [lo, hi) for ``leaf``'s split may fill,
+    NEG_INF elsewhere.  Such a leaf is open, is not the one being split,
+    has a split worth making (gain > 0), holds no lookahead histogram
+    yet, and its confinement interval lies WHOLLY inside [lo, hi):
+    containment in blocks, not overlap, since after a compaction
+    neighbours share a boundary block and a pass over one of them sees
+    only part of the other."""
+    L = st.look_ok.shape[0]
+    gain = st.best_f32[:, 0]
+    ok = ((st.leaf_lo >= lo) & (st.leaf_hi <= hi)
+          & (st.leaf_hi > st.leaf_lo) & ~st.look_ok & (gain > 0.0)
+          & (jnp.arange(L, dtype=jnp.int32) != leaf))
+    return jnp.where(ok, gain, NEG_INF)
 
 
 def make_grow_tree_segment(num_bins: int, params: GrowerParams,
@@ -490,12 +547,29 @@ def make_grow_tree_segment(num_bins: int, params: GrowerParams,
                    and (not packed_acc or fused_packed_optin()))
     fused_route_decisions["segment"] = fused_route
     route_kernel = route_kernel_available()
+    # lookahead lane sets (lookahead_split below): the serial learner's
+    # fused split path on the f32 stream only.  Under reduce_hist every
+    # lookahead histogram would cross the wire, voting and the feature
+    # stripes never run the fused split path, and the packed stream
+    # accumulates other lanes.  How many lane sets comes from the shape
+    # (lookahead_width, at trace time): 1 builds today's program.
+    lookahead_ok = (fused_route and not comm.no_subtract
+                    and comm.reduce_hist is None and not packed_acc)
 
-    def hist_leaf(st: _SegState, leaf, G_cols, fmeta=None, scales=None):
+    def hist_leaf(st: _SegState, leaf, G_cols, fmeta=None, scales=None,
+                  look_k: int = 1):
         """Returns (hist [G,B,3], blocks scanned).  ``scales`` is the
         packed stream's [2] rescale vector (None on the f32 path)."""
         lo = st.leaf_lo[leaf]
         n_blk = st.leaf_hi[leaf] - lo
+        if look_k > 1:
+            # the lookahead kernel with every slot empty: the tree's one
+            # Mosaic compile, and the root summed as the splits are
+            _, outs = histogram_segment_lookahead(
+                st.binsT, st.w8, st.leaf_id, lo, n_blk, leaf, null_route(),
+                empty_lookahead_slots(look_k - 1), n_blk, B, rb,
+                packed4=p.packed4)
+            return unpack_hist(outs[0, :G_cols]), n_blk
         if comm.column_block is not None:
             # feature-parallel: histogram only this shard's column
             # stripe (the reference histograms only the rank's own
@@ -629,6 +703,55 @@ def make_grow_tree_segment(num_bins: int, params: GrowerParams,
             G0, H0, C0 = (comm.reduce_stats(G0), comm.reduce_stats(H0),
                           comm.reduce_stats(C0))
 
+        # lane sets a split pass fills; a tree of L leaves never has more
+        # than L - 2 leaves pending beside the one being split
+        look_k = (min(lookahead_width(G_cols, B, rb, p.packed4), L - 1)
+                  if lookahead_ok else 1)
+
+        def lookahead_split(st: _SegState, leaf, smaller, route, lo, hi):
+            """The fused split pass with lookahead lane sets.  Returns
+            (state, routed ids, smaller child's histogram, blocks that
+            accumulated).
+
+            hit: an earlier pass left the smaller child of ``leaf``'s
+            cached split in ``look_hist`` — the call only routes (n_acc
+            0: one kernel, one call site, no cond carrying the bin matrix
+            on the per-split path).  miss: the pass accumulates, and its
+            other lane sets take the pending leaves inside the interval
+            (``_lookahead_pending``), highest cached gain first.  A
+            leaf's cached split and its count are written once, when its
+            histogram is scanned, so the side picked here (do_split's own
+            ``Cl <= Cr``) is the side do_split will want, and the rows
+            are a function of the data alone."""
+            hit = st.look_ok[leaf]
+            pending = jnp.where(hit, NEG_INF,
+                                _lookahead_pending(st, leaf, lo, hi))
+            gains, cand = lax.top_k(pending, look_k - 1)
+            live = gains > 0.0
+            ci, cf = st.best_i32[cand], st.best_f32[cand]
+            slots = pack_lookahead_slots(
+                jnp.where(live, cand, -1),
+                cf[:, 3] <= st.leaf_c[cand] - cf[:, 3],
+                ci[:, 0], ci[:, 1], ci[:, 2], ci[:, 3],
+                st.best_cat_bitset[cand], fmeta, p.packed4)
+            blk = jnp.where(hit, 0, hi - lo)
+            leaf_id, outs = histogram_segment_lookahead(
+                st.binsT, st.w8, st.leaf_id, lo, hi - lo, smaller, route,
+                slots, blk, B, rb, packed4=p.packed4)
+            hists = unpack_hist(outs[:, :G_cols])
+            hist_small = jnp.where(hit, st.look_hist[leaf], hists[0])
+            put = jnp.where(live, cand, L)          # L: dropped
+            st = st._replace(
+                look_hist=st.look_hist.at[put].set(hists[1:], mode="drop"),
+                # the entry of ``leaf`` was its own split's and is spent:
+                # the child that keeps the id starts without one
+                look_ok=(st.look_ok.at[put].set(True, mode="drop")
+                         .at[leaf].set(False)),
+                look_hits=st.look_hits + hit.astype(jnp.int32),
+                look_filled=st.look_filled + jnp.sum(live, dtype=jnp.int32),
+                route_only=st.route_only + jnp.where(hit, hi - lo, 0))
+            return st, leaf_id, hist_small, blk
+
         def do_split(st: _SegState):
             # split ordinal (feature_fraction_bynode key folding); the
             # epoch-while structure has no fori index, but num_leaves-1
@@ -661,14 +784,18 @@ def make_grow_tree_segment(num_bins: int, params: GrowerParams,
                 with jax.named_scope("hist_split"):
                     route = pack_route(leaf, new_leaf, f, t, dl, cat,
                                        bitset, fmeta, p.packed4)
-                    leaf_id, out = histogram_segment_routed(
-                        st.binsT, st.w8, st.leaf_id, lo, hi - lo, smaller,
-                        route, B, rb, packed4=p.packed4)
-                    hist_small = unpack_hist(out[:G_cols])
+                    if look_k > 1:
+                        st, leaf_id, hist_small, blk = lookahead_split(
+                            st, leaf, smaller, route, lo, hi)
+                    else:
+                        leaf_id, out = histogram_segment_routed(
+                            st.binsT, st.w8, st.leaf_id, lo, hi - lo,
+                            smaller, route, B, rb, packed4=p.packed4)
+                        hist_small = unpack_hist(out[:G_cols])
+                        blk = hi - lo
                 if comm.reduce_hist is not None:
                     hist_small = comm.reduce_hist(hist_small, None, None,
                                                   None, fmeta)
-                blk = hi - lo
             else:
                 with jax.named_scope("route"):
                     leaf_id = apply_route(
@@ -712,7 +839,9 @@ def make_grow_tree_segment(num_bins: int, params: GrowerParams,
                     with jax.named_scope("hist_split"):
                         hist_small, blk = hist_leaf(st, smaller, G_cols,
                                                     fmeta, qscales)
-                grid_blk = grid_of(blk)
+                # a pass that only routed accumulated over no grid step
+                grid_blk = (jnp.where(blk > 0, grid_of(blk), 0)
+                            if look_k > 1 else grid_of(blk))
                 hist_parent = st.leaf_hist[leaf]
                 hist_large = hist_parent - hist_small
                 hist_left = jnp.where(smaller_is_left, hist_small,
@@ -776,6 +905,7 @@ def make_grow_tree_segment(num_bins: int, params: GrowerParams,
 
             st = st._replace(
                 num_leaves=st.num_leaves + 1,
+                num_splits=st.num_splits + 1,
                 leaf_hist=leaf_hist,
                 leaf_g=st.leaf_g.at[leaf].set(Gl).at[new_leaf].set(Gr),
                 leaf_h=st.leaf_h.at[leaf].set(Hl).at[new_leaf].set(Hr),
@@ -821,11 +951,11 @@ def make_grow_tree_segment(num_bins: int, params: GrowerParams,
             return st
 
         st = fresh_state(binsT, w8, n, L, G_cols, B, F, max_blocks,
-                         G0, H0, C0, fmeta, p)
+                         G0, H0, C0, fmeta, p, lookahead=look_k > 1)
         if root_hist is None:
             with jax.named_scope("hist_root"):
                 root_hist, root_blk = hist_leaf(st, jnp.int32(0), G_cols,
-                                                fmeta, qscales)
+                                                fmeta, qscales, look_k)
         else:
             # external batched pass: charge the same scan cost so the
             # adaptive-compaction accounting is unchanged
@@ -846,7 +976,8 @@ def make_grow_tree_segment(num_bins: int, params: GrowerParams,
         stats = jnp.stack([st.scanned_total, st.num_sorts, st.grid_total,
                            jnp.int32(max_blocks), jnp.int32(1),
                            jnp.int32(0), qclips.astype(jnp.int32),
-                           jnp.int32(0), jnp.int32(0)])
+                           jnp.int32(0), jnp.int32(0), st.num_splits,
+                           st.look_hits, st.look_filled, st.route_only])
         return st.tree, leaf_id_orig, stats
 
     if wrap is not None:

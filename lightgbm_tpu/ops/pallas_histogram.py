@@ -268,7 +268,7 @@ def _packed_wrows(wb: jax.Array) -> jax.Array:
 
 
 def _accumulate_block(binsT_ref, wfn, acc_ref, num_bins, packed4=False,
-                      onehot_build="iota"):
+                      onehot_build="iota", unroll=1):
     """Shared inner body: one [F, rb] bin block into the [F*B, 8]
     accumulator, one combined-one-hot matmul per (chunk, fblock).
 
@@ -276,6 +276,9 @@ def _accumulate_block(binsT_ref, wfn, acc_ref, num_bins, packed4=False,
     Chunks are walked with an in-kernel ``fori_loop`` so the Mosaic program
     size is independent of the row-block size (a fully unrolled 64-chunk
     body made kernel compilation a large share of the jit time).
+    ``unroll`` chunks share one loop body, in order (same accumulation
+    order, same bits), so one chunk's weights and one-hot build can
+    overlap the previous chunk's matmul.
 
     ``packed4``: the bin block holds TWO <=16-bin features per byte
     (feature 2i in the low nibble of row i, 2i+1 in the high) — the TPU
@@ -384,7 +387,16 @@ def _accumulate_block(binsT_ref, wfn, acc_ref, num_bins, packed4=False,
                 preferred_element_type=jnp.float32)
         return carry
 
-    lax.fori_loop(0, rb // chunk, one_chunk, 0)
+    n_chunks = rb // chunk
+    if n_chunks % unroll:
+        unroll = 1
+
+    def body(c, carry):
+        for u in range(unroll):
+            one_chunk(c * unroll + u, carry)
+        return carry
+
+    lax.fori_loop(0, n_chunks // unroll, body, 0)
 
 
 def _kernel_all(binsT_ref, w_ref, out_ref, acc_ref, *, num_bins, packed4,
@@ -1043,23 +1055,23 @@ def null_route() -> jax.Array:
     return (jnp.zeros(_ROUTE_WORDS, jnp.int32).at[0].set(-1))
 
 
-def _route_block_ids(sref, o: int, frow, lid, packed4: bool):
-    """[1, rb] updated leaf ids from the route descriptor at scalar
-    offset ``o`` (all sref reads are static-offset SMEM scalars);
-    ``frow`` is the split feature's [1, rb] bin-row block (a value).
+def _route_go_left(word, g, packed4: bool):
+    """0/1 i32 "goes left" of the bin values ``g`` under the route whose
+    word ``j`` is ``word(j)`` (``pack_route``'s layout): an SMEM scalar
+    for the split at hand ([1, rb] ``g``), or a [K, chunk] tile of the
+    lookahead slots' operand ([K, chunk] ``g``, one slot a sublane).
 
     All mask logic is i32 0/1 arithmetic and every select predicate is
     a single fresh compare: Mosaic materializes composed bool vectors
     (scalar-bool broadcasts, i1 & / ~ chains) through i8 and then fails
     to compile the i8->i1 trunci ("Unsupported target bitwidth for
     truncation", v5e)."""
-    g = frow.astype(jnp.int32)                          # [1, rb]
     if packed4:
-        par = sref[o + 3] % 2                           # 0/1 i32 scalar
+        par = word(3) % 2                               # 0/1 i32
         g = par * (g >> 4) + (1 - par) * (g & 15)
-    thr, dl = sref[o + 4], sref[o + 5]                  # dl: 0/1 i32
-    cat, mt = sref[o + 6], sref[o + 7]                  # cat: 0/1 i32
-    dbin, nbf, off = sref[o + 8], sref[o + 9], sref[o + 10]
+    thr, dl = word(4), word(5)                          # dl: 0/1 i32
+    cat, mt = word(6), word(7)                          # cat: 0/1 i32
+    dbin, nbf, off = word(8), word(9), word(10)
     in_range = ((g >= off).astype(jnp.int32)
                 * (g < off + nbf).astype(jnp.int32))
     fcol = jnp.where(in_range == 1, g - off, dbin)
@@ -1072,11 +1084,19 @@ def _route_block_ids(sref, o: int, frow, lid, packed4: bool):
                 + (1 - is_missing) * (fcol <= thr).astype(jnp.int32))
     idx = jnp.clip(fcol, 0, 255)
     # cat bitset membership: 8 unrolled word selects (no vector SMEM loads)
-    word = jnp.zeros_like(g)
+    bits = jnp.zeros_like(g)
     for k in range(8):
-        word = jnp.where(idx // 32 == k, sref[o + 11 + k], word)
-    cat_left = (word >> (idx % 32)) & 1
-    go_left = cat * cat_left + (1 - cat) * num_left
+        bits = jnp.where(idx // 32 == k, word(11 + k), bits)
+    cat_left = (bits >> (idx % 32)) & 1
+    return cat * cat_left + (1 - cat) * num_left
+
+
+def _route_block_ids(sref, o: int, frow, lid, packed4: bool):
+    """[1, rb] updated leaf ids from the route descriptor at scalar
+    offset ``o`` (all sref reads are static-offset SMEM scalars);
+    ``frow`` is the split feature's [1, rb] bin-row block (a value)."""
+    go_left = _route_go_left(lambda j: sref[o + j],
+                             frow.astype(jnp.int32), packed4)
     take = (lid == sref[o]).astype(jnp.int32) * (1 - go_left)
     return jnp.where(take == 1, sref[o + 1], lid)
 
@@ -1208,6 +1228,265 @@ def histogram_segment_routed(binsT: jax.Array, w8: jax.Array,
                                      n_blocks, target_leaf, route,
                                      num_bins, block_rows, interpret,
                                      packed4, onehot_build_mode())
+
+
+# ---------------------------------------------------------------------------
+# Lookahead lane sets (PERF.md, PR 27).  The routed segment pass contracts a
+# [F*B, chunk] one-hot against [8, chunk] channels, and the MXU pads those 8
+# output lanes to 128: the pass costs the same whether it fills 1 lane set
+# or 16.  This variant spends the other lane sets on the smaller children
+# that PENDING best splits of other leaves inside the scanned interval will
+# need (grower_seg.lookahead_split), so that a later split of such a leaf
+# only routes.  Each lane set is an independent column block of the same
+# matmuls in the same chunk order, so lane set k holds bit for bit what lane
+# set 0 of a pass over the same blocks would hold for that membership.
+# ---------------------------------------------------------------------------
+
+# chunks a loop body of the lookahead kernel (``_accumulate_block``):
+# 3.31 ns a row at 1, 3.02 at 4 (16 lane sets; PERF.md, PR 27)
+_LOOKAHEAD_UNROLL = 4
+# the K memberships of a chunk are one [K, chunk] i32 tile: 8 rows are one
+# vreg high, 16 are two.  The second eight cost 0.24 ns a row scanned and
+# found a pending leaf to fill on few passes (PERF.md, PR 27: 0.562 of the
+# splits served at 16 lane sets against 0.545 at 8), so a pass fills 8.
+_LOOKAHEAD_SETS = 8
+
+
+def lookahead_width(F_log: int, num_bins: int, block_rows: int,
+                    packed4: bool) -> int:
+    """Lane sets one routed segment pass fills at this shape: the split at
+    hand plus the lookahead slots.  ``frontier_width`` says how many
+    8-channel sets the accumulator holds (16 fill the 128 lanes), of
+    which ``_LOOKAHEAD_SETS`` are used; 1 where only one fits or the
+    wider kernel's working set does not (``fused_route_fits``): the
+    callers then keep today's kernel."""
+    K = min(frontier_width(F_log, num_bins), _LOOKAHEAD_SETS)
+    F_phys = (F_log + 1) // 2 if packed4 else F_log
+    if K > 1 and not fused_route_fits(F_phys, num_bins, 1, block_rows,
+                                      packed4, targets_k=K):
+        return 1
+    return K
+
+
+def pack_lookahead_slots(leaves, smaller_is_left, f, t, dl, cat, bitset,
+                         fmeta, packed4: bool) -> jax.Array:
+    """[K-1, _ROUTE_WORDS] i32 lookahead-slot descriptors for
+    ``histogram_segment_lookahead``: ``pack_route``'s layout, one row a
+    pending leaf (-1: empty slot, matches no row), with the smaller side
+    (1 = left) where a route carries the new leaf's id.  All arguments
+    are [K-1] vectors ([K-1, 8] ``bitset``)."""
+    return jax.vmap(
+        lambda *a: pack_route(*a, fmeta, packed4))(
+            leaves, jnp.asarray(smaller_is_left, jnp.int32), f, t, dl, cat,
+            bitset)
+
+
+def empty_lookahead_slots(k: int) -> jax.Array:
+    """[k, _ROUTE_WORDS] slots that match no row (a pass that fills no
+    lane set but its own: the root's, a reference's)."""
+    return jnp.tile(null_route()[None], (k, 1))
+
+
+def _lookahead_masks(slots_ref, KP: int, bins_i32, lc, first,
+                     packed4: bool):
+    """[KP, chunk] 0/1 i32 memberships of one chunk, one slot a sublane.
+
+    Row 0 is ``first`` ([1, chunk]: the split at hand, from the routed
+    ids).  Row k >= 1 is a lookahead slot: the rows of pending leaf X
+    (``lc == X``, ids AFTER this pass's route) that X's cached best split
+    sends to its smaller child, decided by the arithmetic that routes
+    (``_route_go_left``).  ``slots_ref`` ([_ROUTE_WORDS * KP, chunk] i32
+    in VMEM, fetched once a call) holds word j of slot k at row KP j + k,
+    already spread along the lanes, so the KP evaluations are one
+    sublane-dense tile and not KP serial [1, chunk] updates.  The slots'
+    split-feature rows come out of ``bins_i32`` ([F_phys, chunk]: the
+    bin block the pass already holds in VMEM), never from a second
+    stream out of HBM."""
+    shape = (KP, lc.shape[1])
+
+    def word(j):
+        return slots_ref[KP * j:KP * (j + 1), :]
+
+    rows = word(2)
+    g = jnp.zeros(shape, jnp.int32)
+    for r in range(bins_i32.shape[0]):
+        g = jnp.where(rows == r, bins_i32[r:r + 1], g)
+    go_left = _route_go_left(word, g, packed4)
+    member = ((lc == word(0)).astype(jnp.int32)
+              * (go_left == word(1)).astype(jnp.int32))
+    slot = lax.broadcasted_iota(jnp.int32, shape, 0)
+    return jnp.where(slot == 0, first, member)
+
+
+def _kernel_segment_lookahead(sref, binsT_ref, w_ref, frow_ref, lid_ref,
+                              slots_ref, lid_out_ref, out_ref, acc_ref,
+                              hi_ref, lo_ref, *, num_bins, K, packed4,
+                              onehot_build="iota"):
+    # sref: [4 + _ROUTE_WORDS] = (start_block, n_blocks, target_leaf,
+    #   route, n_acc): every block of the interval is routed, the first
+    #   n_acc of them accumulate (n_blocks, or 0 for a pass that only
+    #   routes: the caller already holds the histogram)
+    i = pl.program_id(0)
+    KP = slots_ref.shape[0] // _ROUTE_WORDS
+
+    @pl.when(i == 0)
+    def _():
+        hi_ref[:] = jnp.zeros_like(hi_ref)
+        lo_ref[:] = jnp.zeros_like(lo_ref)
+
+    # 1) route this block (as _kernel_segment_routed)
+    lid_out_ref[...] = _route_block_ids(sref, 3, frow_ref[...],
+                                        lid_ref[...], packed4)
+
+    # 2) accumulate the K lane sets from the UPDATED ids
+    @pl.when(i < sref[3 + _ROUTE_WORDS])
+    def _():
+        def wfn(c, chunk):
+            wc = w_ref[:, pl.ds(c * chunk, chunk)]          # [8, chunk]
+            lc = lid_out_ref[:, pl.ds(c * chunk, chunk)]    # [1, chunk]
+            masks = _lookahead_masks(
+                slots_ref, KP,
+                binsT_ref[:, pl.ds(c * chunk, chunk)].astype(jnp.int32),
+                lc, (lc == sref[2]).astype(jnp.int32), packed4)
+            # [8K, chunk]: K masked copies of the channels, as
+            # _kernel_frontier builds them
+            return jnp.concatenate(
+                [(masks[k:k + 1] == 1).astype(jnp.bfloat16) * wc
+                 for k in range(K)], axis=0)
+
+        # the block's own sum first, then into the running (hi, lo) pair
+        # with an error-free addition: a lookahead lane set is summed over
+        # the whole interval the pass covers, its rows a few to a block,
+        # where the scan it stands in for would have found them packed
+        # after a compaction.  Adding every such sliver straight into one
+        # f32 total rounds once a row; (hi, lo) rounds once a block's
+        # chunks and carries the rest.  A block outside the leaf adds an
+        # exact zero: s == hi, err == 0.
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+        _accumulate_block(binsT_ref, wfn, acc_ref, num_bins, packed4,
+                          onehot_build, unroll=_LOOKAHEAD_UNROLL)
+        p = acc_ref[:]
+        h = hi_ref[:]
+        s = h + p
+        bb = s - h
+        lo_ref[:] += (h - (s - bb)) + (p - bb)
+        hi_ref[:] = s
+
+    @pl.when(i == pl.num_programs(0) - 1)
+    def _():
+        out_ref[:] = hi_ref[:] + lo_ref[:]
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("num_bins", "block_rows", "interpret",
+                                    "packed4", "onehot_build"))
+def _histogram_segment_lookahead(binsT: jax.Array, w8: jax.Array,
+                                 leaf_id: jax.Array, start_block: jax.Array,
+                                 n_blocks: jax.Array, target_leaf: jax.Array,
+                                 route: jax.Array, slots: jax.Array,
+                                 n_acc: jax.Array, num_bins: int,
+                                 block_rows: int = 0,
+                                 interpret: bool | None = None,
+                                 packed4: bool = False,
+                                 onehot_build: str = "iota"):
+    F, n = binsT.shape
+    F_log = 2 * F if packed4 else F
+    assert w8.dtype != jnp.int32, "lookahead lane sets ride the f32 stream"
+    och = NUM_CHANNELS
+    K = 1 + int(slots.shape[0])
+    assert K * och <= _LANES, K
+    if block_rows <= 0:
+        block_rows = pick_block_rows(F_log, num_bins)
+    assert n % block_rows == 0, (n, block_rows)
+    if interpret is None:
+        interpret = _interpret_default()
+    max_blocks = n // block_rows
+    grid_n = jnp.clip(n_blocks, 1, max_blocks).astype(jnp.int32)
+    scalars = jnp.concatenate([
+        jnp.stack([start_block, n_blocks, target_leaf]).astype(jnp.int32),
+        route.astype(jnp.int32),
+        jnp.asarray(n_acc, jnp.int32).reshape(1)])
+    frow = lax.dynamic_slice(binsT, (route[2].astype(jnp.int32), 0), (1, n))
+    # word j of slot k at row KP j + k, spread along one chunk's lanes
+    # (_lookahead_masks); slot 0 (the split at hand, which the scalars
+    # describe) and the rows that pad K to whole sublane groups match
+    # nothing.  622 KB at K = 16 and 512 lanes, fetched once.
+    KP = -(-K // 8) * 8
+    chunk = _pick_chunk(block_rows)
+    words = jnp.concatenate([
+        null_route()[None], slots.astype(jnp.int32),
+        empty_lookahead_slots(KP - K)]).T                   # [words, KP]
+    slots_op = jnp.broadcast_to(
+        words[:, :, None], (_ROUTE_WORDS, KP, chunk)).reshape(
+            _ROUTE_WORDS * KP, chunk)
+
+    def im_data(i, s):
+        return (0, jnp.minimum(s[0] + i, max_blocks - 1))
+
+    acc_shape = (F_log * num_bins, K * och)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(grid_n,),
+        in_specs=[
+            pl.BlockSpec((F, block_rows), im_data),
+            pl.BlockSpec((NUM_CHANNELS, block_rows), im_data),
+            pl.BlockSpec((1, block_rows), im_data),
+            pl.BlockSpec((1, block_rows), im_data),
+            pl.BlockSpec((_ROUTE_WORDS * KP, chunk), lambda i, s: (0, 0)),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, block_rows), im_data),
+            pl.BlockSpec(acc_shape, lambda i, s: (0, 0)),
+        ],
+        scratch_shapes=[pltpu.VMEM(acc_shape, jnp.float32)] * 3,
+    )
+    lid_out, hist = pl.pallas_call(
+        functools.partial(_kernel_segment_lookahead, num_bins=num_bins,
+                          K=K, packed4=packed4, onehot_build=onehot_build),
+        out_shape=[jax.ShapeDtypeStruct((1, n), jnp.int32),
+                   jax.ShapeDtypeStruct(acc_shape, jnp.float32)],
+        grid_spec=grid_spec,
+        # alias indices include the scalar operand: input 4 is leaf_id
+        input_output_aliases={4: 0},
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=fused_vmem_limit(F, num_bins, 1, block_rows,
+                                              packed4, targets_k=K)),
+        interpret=interpret,
+        # the routed segment pass, widened: the trace keeps its name
+        name="_histogram_segment_routed",
+    )(scalars, binsT, w8, frow, leaf_id.reshape(1, -1), slots_op)
+    # [F*B, K*8] -> [K, F, B, 8]
+    return lid_out[0], hist.reshape(F_log, num_bins, K, och).transpose(
+        2, 0, 1, 3)
+
+
+def histogram_segment_lookahead(binsT: jax.Array, w8: jax.Array,
+                                leaf_id: jax.Array, start_block: jax.Array,
+                                n_blocks: jax.Array, target_leaf: jax.Array,
+                                route: jax.Array, slots: jax.Array,
+                                n_acc: jax.Array, num_bins: int,
+                                block_rows: int = 0,
+                                interpret: bool | None = None,
+                                packed4: bool = False):
+    """``histogram_segment_routed`` with K = 1 + len(slots) lane sets:
+    returns ``(leaf_id', [K, F, B, 8])``.
+
+    Entry 0 is the target's histogram from the routed ids, as the routed
+    kernel gives it.  Entry k >= 1 is the histogram of ``slots[k-1]``
+    ([_ROUTE_WORDS] i32, ``pack_lookahead_slots``): the rows of a pending
+    leaf that its cached best split sends to its smaller child, summed
+    over the same interval with nothing written for them.  The caller
+    sees to it that such a leaf's rows lie wholly inside the interval.
+    ``n_acc`` is how many of the interval's blocks accumulate:
+    ``n_blocks``, or 0 for a call that only routes and returns zeros.
+    Sums are f32 a block and an error-free (hi, lo) pair across blocks
+    (``_kernel_segment_lookahead``), so lane set 0 agrees with the routed
+    kernel to f32 rounding, not bit for bit; f32 channel stream only.
+    """
+    return _histogram_segment_lookahead(
+        binsT, w8, leaf_id, jnp.asarray(start_block, jnp.int32),
+        jnp.asarray(n_blocks, jnp.int32), target_leaf, route, slots, n_acc,
+        num_bins, block_rows, interpret, packed4, onehot_build_mode())
 
 
 def _kernel_frontier_routed(sref, binsT_ref, w_ref, frows_ref, lid_ref,
@@ -1773,7 +2052,8 @@ def _fused_route_self_check() -> bool:
         sys.stderr.write(f"fused-route self-check FAILED leg: {leg}\n")
         return False
 
-    F, B, rb, nblk = 4, 16, 512, 6
+    # blocks of 4 chunks, so the lookahead kernel's unrolled loop runs
+    F, B, rb, nblk = 4, 16, _LOOKAHEAD_UNROLL * CHUNK, 6
     n = rb * nblk
     binsT = jnp.asarray(rng.integers(0, B, (F, n)), jnp.uint8)
     grad = jnp.asarray(rng.standard_normal(n), jnp.float32)
@@ -1874,6 +2154,60 @@ def _fused_route_self_check() -> bool:
     exp5[(exp5 == 3) & (fcol > 2) & win] = 9
     if not np.array_equal(np.asarray(lid5), exp5):
         return _fail("efb lid")
+
+    # lookahead lane sets: the split at hand (leaf 3 -> 9) plus slots for
+    # leaf 5 under a NaN-missing numeric split (smaller side right) and
+    # under a categorical one (left), for the rows this very pass moves to
+    # leaf 9 (slots read the UPDATED ids), and empty slots.  Lane set 0
+    # must be the routed kernel's to f32 rounding (sums are kept as a
+    # pair across blocks), every other one bit for bit lane set 0 of a
+    # pass over that slot's rows, and a route-only call (n_acc 0) must
+    # route the same and return zeros.
+    route = pack_route(3, 9, 0, B // 2, True, False, bitset, _M, False)
+    lid1, h1 = histogram_segment_routed(
+        binsT, w8, lid, jnp.int32(1), jnp.int32(3), jnp.int32(9), route,
+        B, rb)
+    live = ((5, 0, 2, B // 2, False, False), (5, 1, 1, 0, True, True),
+            (9, 1, 3, 5, False, False))
+    KL = 8
+    pad = KL - 1 - len(live)
+    cols = list(zip(*live))
+    slots = pack_lookahead_slots(
+        jnp.asarray(cols[0] + (-1,) * pad, jnp.int32),
+        jnp.asarray(cols[1] + (0,) * pad, jnp.int32),
+        jnp.asarray(cols[2] + (0,) * pad, jnp.int32),
+        jnp.asarray(cols[3] + (0,) * pad, jnp.int32),
+        jnp.asarray(cols[4] + (False,) * pad),
+        jnp.asarray(cols[5] + (False,) * pad),
+        jnp.tile(bitset[None], (KL - 1, 1)), _M, False)
+    lidk, hk = histogram_segment_lookahead(
+        binsT, w8, lid, jnp.int32(1), jnp.int32(3), jnp.int32(9), route,
+        slots, jnp.int32(3), B, rb)
+    if not (np.array_equal(np.asarray(lidk), np.asarray(lid1))
+            and np.allclose(np.asarray(hk[0]), np.asarray(h1), atol=1e-5)):
+        return _fail("lookahead lane set 0")
+    from ..models.grower import routed_left
+    empty = empty_lookahead_slots(KL - 1)
+    for k, (leaf, side, f, t, dl, cat) in enumerate(live, start=1):
+        go = routed_left(binsT[f].astype(jnp.int32), t, dl, cat, bitset,
+                         _M.missing_type[f], _M.default_bin[f],
+                         _M.num_bin[f])
+        member = (lid1 == leaf) & (go == bool(side))
+        _, ref = histogram_segment_lookahead(
+            binsT, w8, jnp.where(member, 999, lid1), jnp.int32(1),
+            jnp.int32(3), jnp.int32(999), null_route(), empty,
+            jnp.int32(3), B, rb)
+        if not (np.asarray(ref[0]).any()
+                and np.array_equal(np.asarray(hk[k]), np.asarray(ref[0]))):
+            return _fail(f"lookahead lane set {k}")
+    if np.asarray(hk[1 + len(live):]).any():
+        return _fail("lookahead empty slots")
+    lid0, h0 = histogram_segment_lookahead(
+        binsT, w8, lid, jnp.int32(1), jnp.int32(3), jnp.int32(9), route,
+        slots, jnp.int32(0), B, rb)
+    if (not np.array_equal(np.asarray(lid0), np.asarray(lid1))
+            or np.asarray(h0).any()):
+        return _fail("lookahead route-only")
 
     # frontier: one real route + one null slot
     K = 2

@@ -338,7 +338,7 @@ def _stripe_setup(mesh: Mesh, num_columns: int, feat_group):
                                     feat_group)
 
     in_specs = (P(None, axis), P(axis), P(axis), P(axis), P(), P(), P())
-    # third output: the grower's [6] counter vector, stacked per device so
+    # third output: the grower's [SEG_STATS_SLOTS] counters, one row a device so
     # the host prints one seg-stats row per shard
     out_specs = (P(), P(axis), P(axis))
     return axis, D, Gpad, per, shard_mask, in_specs, out_specs

@@ -346,3 +346,35 @@ def test_voting_parallel_frontier_trains(rng):
     assert vote.gbdt._use_segment
     mse = float(np.mean((vote.predict(X) - y) ** 2))
     assert mse < 0.1 * y.var()
+
+
+@pytest.mark.parametrize("learner", ["data", "feature", "voting"])
+def test_mesh_segment_learners_run_no_lookahead(rng, monkeypatch, learner):
+    """The strict grower's lookahead lane sets are the serial learner's:
+    under every mesh wrapper the segment grower builds the program it
+    built before them — its lookahead counters stay 0 and the model is,
+    bit for bit, that of a build whose shape admits one lane set."""
+    import lightgbm_tpu.models.grower_seg as gs
+    from lightgbm_tpu.utils.telemetry import TELEMETRY
+    monkeypatch.setenv("LIGHTGBM_TPU_SEG_STATS", "1")
+    X, y = make_data(rng, n=2400, f=7)
+    kw = dict(tpu_histogram_backend="pallas", tpu_tree_impl="segment",
+              tpu_row_chunk=128, num_leaves=24)
+
+    def train():
+        ds = lgb.Dataset(X, y)
+        params = {"objective": "regression", "verbose": -1,
+                  "tree_learner": learner, "max_bin": 63, "seed": 5, **kw}
+        return lgb.train(params, ds, num_boost_round=3, verbose_eval=False)
+
+    TELEMETRY.reset()
+    a = train()
+    assert a.gbdt._use_segment
+    counters = TELEMETRY.stats()["counters"]
+    assert counters["seg/splits"] > 0
+    assert counters["seg/lookahead_hits"] == 0
+    assert counters["seg/lookahead_filled"] == 0
+    assert counters["seg/route_only_blocks"] == 0
+    monkeypatch.setattr(gs, "lookahead_width", lambda *a: 1)
+    b = train()
+    assert a.model_to_string() == b.model_to_string()

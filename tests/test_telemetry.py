@@ -126,17 +126,23 @@ def test_fetch_counters_exact_for_two_chunk_run(rng):
 
 def test_compaction_counters_under_seg_stats(rng, monkeypatch):
     """LIGHTGBM_TPU_SEG_STATS opts into fetching the segment grower's
-    device counters; the training shape crosses the compaction
-    milestones (test_grower_seg.py) so at least one compaction lands in
-    seg/compactions."""
+    device counters; the training shape scans past the compaction budget
+    (9 N: about half of 39 splits scan, the lookahead lane sets serve the
+    rest) so at least one compaction lands in seg/compactions, beside
+    the split and lookahead counters."""
     monkeypatch.setenv("LIGHTGBM_TPU_SEG_STATS", "1")
     X, y = make_binary(rng, n=800, f=8)
-    bst = lgb.train(_params(num_leaves=15, tpu_tree_impl="segment",
+    bst = lgb.train(_params(num_leaves=40, tpu_tree_impl="segment",
                             tpu_histogram_backend="pallas"),
                     lgb.Dataset(X, y), num_boost_round=3)
     c = bst.get_stats()["counters"]
     assert c.get("seg/compactions", 0) >= 1
     assert c.get("seg/scanned_blocks", 0) > 0
+    assert c["seg/splits"] == sum(t.num_leaves - 1
+                                  for t in bst.gbdt.models)
+    assert 0 < c["seg/lookahead_hits"] <= c["seg/lookahead_filled"]
+    assert c["seg/lookahead_hits"] < c["seg/splits"]
+    assert c["seg/route_only_blocks"] > 0
 
 
 def test_level0_adds_nothing(rng):

@@ -111,6 +111,16 @@ def _segment_routed(sh):
         sh.bins, sh.w8, sh.leaf_id, sh.i32, sh.i32, sh.i32, sh.route)
 
 
+def _segment_lookahead(sh):
+    K = ph.lookahead_width(sh.F, sh.B, sh.rb, False)
+    assert K >= 2
+    return _compile(
+        lambda b, w, l, s0, nb, t, r, sl, na: ph._histogram_segment_lookahead(
+            b, w, l, s0, nb, t, r, sl, na, sh.B, sh.rb, interpret=False),
+        sh.bins, sh.w8, sh.leaf_id, sh.i32, sh.i32, sh.i32, sh.route,
+        sh.s((K - 1, ph._ROUTE_WORDS), jnp.int32), sh.i32)
+
+
 def _route_window(sh):
     return _compile(
         lambda b, l, s0, nb, r: ph.route_window(b, l, s0, nb, r, sh.rb,
@@ -135,7 +145,8 @@ def _score(sh):
 
 
 @pytest.mark.parametrize("kernel", [_all, _segment_dyn, _segment_routed,
-                                    _route_window, _frontier_dyn, _score],
+                                    _segment_lookahead, _route_window,
+                                    _frontier_dyn, _score],
                          ids=lambda f: f.__name__.lstrip("_"))
 def test_default_path_kernel_compiles_at_higgs_shape(one_chip, kernel):
     sh = _Shapes(one_chip, *HIGGS)
@@ -167,3 +178,12 @@ def test_supported_agrees_with_compiler(one_chip, F, B):
         assert "vmem" in str(e).lower(), e
         compiles = False
     assert ph.supported(F, B, jnp.uint8) == compiles
+
+
+@pytest.mark.parametrize("F,B,rows", SUITE_WIDTHS,
+                         ids=["goss", "multiclass_cat", "lambdarank"])
+def test_lookahead_kernel_compiles_at_suite_widths(one_chip, F, B, rows):
+    """Wherever the grower would pick more than one lane set, the kernel
+    with that many compiles within the VMEM limit it asks for."""
+    assert "tpu_custom_call" in _segment_lookahead(
+        _Shapes(one_chip, F, B, rows)).as_text()
