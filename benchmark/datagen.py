@@ -2,14 +2,21 @@
 
 `higgs_proxy` is `chip_smoke.make_data` (itself bench.py's HIGGS proxy):
 standard-normal float32 features and a label from a fixed non-linear rule
-with noise, drawn in bulk.  The same seed gives the same rows.
+with noise, drawn in bulk.  The same seed gives the same rows.  Any other
+name is a file, `generators/<name>.py`, whose `make(rng, rows, **data)`
+returns float32 rows and the labels: a later PR's data shape is a new file.
 """
 
 from __future__ import annotations
 
-from typing import Dict, Tuple
+import os
+from typing import Callable, Dict, Tuple
 
 import numpy as np
+
+from manifest import load_module
+
+HERE = os.path.dirname(os.path.abspath(__file__))
 
 
 def higgs_proxy(rng: np.random.Generator, rows: int, features: int,
@@ -23,15 +30,20 @@ def higgs_proxy(rng: np.random.Generator, rows: int, features: int,
     return X, (logit + eps * np.float32(noise) > 0).astype(np.float64)
 
 
-GENERATORS = {"higgs_proxy": higgs_proxy}
+def generator(name: str, here: str = HERE) -> Callable:
+    """`higgs_proxy`, or `make` of `generators/<name>.py`, found as
+    `manifest.reader` finds a metric."""
+    if name == "higgs_proxy":
+        return higgs_proxy
+    path = os.path.join(here, "generators", f"{name}.py")
+    if not os.path.exists(path):
+        raise SystemExit(f"unknown generator {name!r}: no file {path}")
+    return load_module(path, f"generator_{name}").make
 
 
-def make(data: Dict, rows: int, rng: np.random.Generator):
+def make(data: Dict, rows: int, rng: np.random.Generator, here: str = HERE):
     """`data` is the configuration's `data` group: the generator's name and
     its parameters.  `rows` is passed apart: a rehearsal cuts it."""
-    kind = data["generator"]
-    if kind not in GENERATORS:
-        raise SystemExit(f"unknown generator {kind!r}")
     kwargs = {k: v for k, v in data.items()
               if k not in ("generator", "rows")}
-    return GENERATORS[kind](rng, int(rows), **kwargs)
+    return generator(data["generator"], here)(rng, int(rows), **kwargs)

@@ -22,6 +22,16 @@ def _load_json(path: str) -> Dict:
         return json.load(fh)
 
 
+def load_module(path: str, tag: str):
+    """The module in the file at `path`, under a name of its own made from
+    `tag`: how a metric's reader, a generator and a tool are found by file."""
+    spec = importlib.util.spec_from_file_location(
+        "bench_" + tag.replace(".", "_").replace("-", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
 class Manifest:
     def __init__(self, root: str = ROOT, here: str = HERE):
         self.root, self.here = root, here
@@ -61,11 +71,7 @@ class Manifest:
         path = os.path.join(self.here, "metrics", f"{metric}.py")
         if not os.path.exists(path):
             raise SystemExit(f"metric {metric!r} has no reader at {path}")
-        spec = importlib.util.spec_from_file_location(
-            f"bench_metric_{metric.replace('.', '_').replace('-', '_')}", path)
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod.read
+        return load_module(path, f"metric_{metric}").read
 
     def peaks(self, device_kind: str) -> Dict:
         table = _load_json(os.path.join(self.here, "peaks.json"))

@@ -24,6 +24,13 @@ three exact bfloat16 pieces (cut by bit mask), accumulated in float32 and
 compensated across blocks.  `precision="bfloat16"` is the control: scores,
 gradients and the summed products kept in bfloat16, the step a later PR
 would be tempted by.
+
+Nothing is set for a width.  The block is sized from the table's shape
+(`block_rows`), routing reads only the columns a tree splits on, the rows
+sit on the device once, raw float32, in the layout the passes read
+(`_upload_blocks`), and the host's part is one BLAS product and a pass over
+a few splits at a time: the cost follows the rows and the candidates, not
+the columns a tree does not use.
 """
 
 from __future__ import annotations
@@ -35,6 +42,47 @@ from typing import Dict, List
 import numpy as np
 
 K_EPS = 1e-15       # LightGBM's kEpsilon, added to every hessian sum
+
+MAX_BLOCK = 16384           # rows a block, where the width allows it
+MIN_BLOCK = 256             # `dot_t` sums leaves over 256 rows at a time
+PARTIAL_ROWS = 4096         # rows one float32 partial sum of a histogram takes
+TEMP_BYTES = 2 << 30        # what one block's temporaries may take
+SLAB_BYTES = 256 << 20      # rows uploaded in one transfer
+SPLITS_AT_ONCE = 16         # splits whose candidate gains are held at once
+
+
+def block_rows(n_feat: int, n_cand: int, n_leaf: int) -> int:
+    """Rows a block, from the shape alone: the largest power of two, at most
+    MAX_BLOCK and at least MIN_BLOCK, whose temporaries stay under TEMP_BYTES
+    (2 GiB, an eighth of a v5e chip; a constant and not the device's own
+    figure, because the block sets the order of the float32 sums, and the
+    readings should not move with the device).
+
+    A row of a block is counted at 8 bytes for every (feature, candidate)
+    pair and 64 bytes a leaf.  The pairs: the compiler keeps the row's value
+    spread over its candidates in float32 and fuses the compare and the
+    bfloat16 0/1 into the product, 4 bytes a pair (compiled for a described
+    v5e at 2048 and 4096 rows, PR 29); row-major blocks took 7.1 on the
+    chip (PR 28), so 8 leaves a margin of two.  The leaves: the one-hot, the
+    [R, 3L] float32 weights and their [R, 9L] bfloat16 pieces.
+
+        28 x 64 x 256:    30,720 B a row; 16384 rows take 0.50 GB  -> 16384
+        2000 x 64 x 256:  1,040,384 B a row; 2048 rows take 2.13 GB -> 2048
+
+    What does not shrink with the block: the histogram, [3L, F * B] float32
+    (393 MB at 2000 x 64 x 256), held as a Kahan pair and copied out, and
+    the product's [9L, F * B] output before its three pieces are added
+    (1.18 GB there) times the partial sums a block is cut into, which is
+    why a partial sum takes min(block, PARTIAL_ROWS) rows (one at 2048, four
+    at 16384 as before PR 29) and not a fixed quarter of the block.  With
+    the rows themselves (8.8 GB at 1,100,000 x 2000) the compiler counts
+    12.24 GB there, of the chip's 16.9; `PERF.md` section 2 has the peak
+    measured."""
+    per_row = 8 * n_feat * n_cand + 64 * n_leaf
+    rows = MAX_BLOCK
+    while rows > MIN_BLOCK and rows * per_row > TEMP_BYTES:
+        rows //= 2
+    return rows
 
 
 # ---------------------------------------------------------------- the model
@@ -180,7 +228,19 @@ def _split3(a):
             lo.astype(jnp.bfloat16))
 
 
-def _build_passes(n_feat: int, sigmoid: float, low: bool, with_hist: bool):
+def route(X, feat, thr, path, depth):
+    """[R, L] one-hot of the leaf each row of a block falls in, by the tables
+    of `tree_tables`.  It reads only the columns the tree splits on, one
+    gather of [R, S], so a block costs the same at 28 columns and at 2000."""
+    import jax.numpy as jnp
+    xs = jnp.take(X, feat, axis=1)
+    # +-1 and 0 are exact in whatever precision the product is made
+    d = jnp.where(xs > thr[None, :], 1.0, -1.0).astype(jnp.float32)
+    match = jnp.dot(d, path, preferred_element_type=jnp.float32)
+    return match == depth[None, :]
+
+
+def _build_passes(sigmoid: float, low: bool, with_hist: bool):
     """The two jitted passes over all blocks: sums under one tree, and the
     score update with the loss."""
     import jax
@@ -197,14 +257,6 @@ def _build_passes(n_feat: int, sigmoid: float, low: bool, with_hist: bool):
         resp = -ysign * sigmoid / (1.0 + jnp.exp(ysign * sigmoid * score))
         a = jnp.abs(resp)
         return resp, a * (sigmoid - a)
-
-    def route(X, feat, thr, path, depth):
-        xs = jnp.zeros((X.shape[0], feat.shape[0]), f32)
-        for f in range(n_feat):
-            xs = jnp.where(feat[None, :] == f, X[:, f:f + 1], xs)
-        d = jnp.where(xs > thr[None, :], 1.0, -1.0).astype(bf16)
-        match = jnp.dot(d, path.astype(bf16), preferred_element_type=f32)
-        return match == depth[None, :]                        # [R, L] one-hot
 
     def pieces(a):
         """[R, K] float32 -> [R, 3K] bfloat16 (K in the control): the three
@@ -234,7 +286,8 @@ def _build_passes(n_feat: int, sigmoid: float, low: bool, with_hist: bool):
         L = path.shape[1]
 
         def body(carry, blk):
-            X, ysign, score = blk
+            Xt, ysign, score = blk
+            X = Xt.T
             g, h = gradients(ysign, score)
             onehot = route(X, feat, thr, path, depth)
             valid = (ysign != 0)
@@ -251,7 +304,7 @@ def _build_passes(n_feat: int, sigmoid: float, low: bool, with_hist: bool):
             if with_hist:
                 ind = (X[:, :, None] <= cand[None, :, :]).reshape(
                     X.shape[0], -1).astype(bf16)
-                hist = dot_t(a, ind, X.shape[0] // 4)      # [L*3, F*B]
+                hist = dot_t(a, ind, min(X.shape[0], PARTIAL_ROWS))
                 tot, comp = kahan(carry[0], carry[1], hist)
                 carry = (tot, comp)
             return carry, (leaf_sums, leaf_id, bad)
@@ -286,8 +339,8 @@ def _build_passes(n_feat: int, sigmoid: float, low: bool, with_hist: bool):
     def apply_pass(Xb, sb, feat, thr, path, depth, values):
         """score + values[leaf of the row], block by block."""
         def body(_, blk):
-            X, score = blk
-            onehot = route(X, feat, thr, path, depth)
+            Xt, score = blk
+            onehot = route(Xt.T, feat, thr, path, depth)
             add = jnp.sum(jnp.where(onehot, values[None, :], 0.0), axis=1)
             return (), score + add
         return jax.lax.scan(body, (), (Xb, sb))[1]
@@ -315,12 +368,51 @@ class StepReading:
     tested_loss: float | None = None    # the same under the tested values
 
 
+def _upload_blocks(X: np.ndarray, R: int):
+    """[n, F] float32 rows on the host -> [nb, F, R] on the device: blocks of
+    R rows, each with its rows along the last axis, zero rows after the last.
+
+    The passes read a block as `block.T`, [R, F].  Rows-last is the layout
+    the compiler wants under the compare and the products: compiled for a
+    described v5e at 1,100,000 x 2000, row-major [nb, R, F] blocks get a
+    copy of the whole table hoisted out of the loop (19 GB, refused), these
+    none (12.24 GB).  The rows go up flat, a slab of whole blocks at a time
+    (no tiling for the host to lay out), are turned on the device and
+    written into a buffer that each call donates and gets back: the host
+    pads the last block only, and the device holds the table and one slab."""
+    import jax
+    import jax.numpy as jnp
+    n, F = X.shape
+    nb = -(-n // R)
+    full = n // R
+
+    def put(buf, flat, at):
+        part = jnp.swapaxes(flat.reshape(-1, R, F), 1, 2)
+        return jax.lax.dynamic_update_slice(buf, part, (at, 0, 0))
+
+    put = jax.jit(put, donate_argnums=0)
+    buf = jnp.zeros((nb, F, R), jnp.float32)
+    slab = max(1, SLAB_BYTES // (R * F * 4))
+    for b in range(0, full, slab):
+        e = min(b + slab, full)
+        # waited for: slabs sent ahead would wait on the device, as many
+        # bytes again as the table (peak 16.2 GB of 16.9 at 1,100,000 x 2000)
+        buf = jax.block_until_ready(put(buf, np.ascontiguousarray(
+            X[b * R:e * R], np.float32).reshape(-1), b))
+    if full < nb:
+        last = np.zeros((R, F), np.float32)
+        last[:n - full * R] = X[full * R:]
+        buf = put(buf, last.reshape(-1), full)
+    return buf
+
+
 class Follower:
     """Holds the rows on the device in blocks and follows training step by
-    step under the trees it is given."""
+    step under the trees it is given.  The block is `block_rows` of the shape:
+    nothing is set for a width."""
 
     def __init__(self, X: np.ndarray, y: np.ndarray, params: Dict,
-                 cand: np.ndarray, n_leaf: int, *, block_rows: int = 16384,
+                 cand: np.ndarray, n_leaf: int, *,
                  precision: str = "float32", with_hist: bool = True):
         import jax.numpy as jnp
         self.n, self.F = X.shape
@@ -335,13 +427,12 @@ class Follower:
         if precision not in ("float32", "bfloat16"):
             raise ValueError(f"unknown precision {precision!r}")
         self.with_hist = with_hist
-        R = int(block_rows)
+        R = self.block_rows = block_rows(self.F, cand.shape[1], self.n_leaf)
         nb = -(-self.n // R)
         pad = nb * R - self.n
         pos = y > 0
         ysign = np.where(pos, 1.0, -1.0).astype(np.float32)
-        self.Xb = jnp.asarray(np.concatenate(
-            [X, np.zeros((pad, self.F), np.float32)]).reshape(nb, R, self.F))
+        self.Xb = _upload_blocks(X, R)
         self.yb = jnp.asarray(np.concatenate(
             [ysign, np.zeros(pad, np.float32)]).reshape(nb, R))
         # BoostFromScore: log-odds of the positive rate over sigmoid
@@ -353,7 +444,7 @@ class Follower:
         self.cand = jnp.asarray(cand)
         self.cand_np = np.asarray(cand, np.float64)
         self._sums, self._update, self._apply = _build_passes(
-            self.F, self.sigmoid, self.low, with_hist)
+            self.sigmoid, self.low, with_hist)
 
     def step(self, tree: RefTree, tested_values=None) -> StepReading:
         """Follow one step under `tree`.  `tested_values` are the leaf values
@@ -386,26 +477,9 @@ class Follower:
                 - score(node[:, 0], node[:, 1]))
         best = other = None
         if self.with_hist and S > 0:
-            h4 = np.asarray(hist, np.float64).reshape(
-                self.n_leaf, 3, self.F, -1)[:L]
-            nh = np.einsum("sl,lkfb->skfb", anc, h4)            # left sums
-            tot = node[:, :, None, None]
-            lg, lh, lc = nh[:, 0], nh[:, 1], nh[:, 2]
-            rg, rh, rc = tot[:, 0] - lg, tot[:, 1] - lh, tot[:, 2] - lc
-            ok = ((lc >= self.min_data) & (rc >= self.min_data)
-                  & (lh >= self.min_hess) & (rh >= self.min_hess)
-                  & np.isfinite(self.cand_np)[None])
-            cg = (score(lg, lh) + score(rg, rh)
-                  - score(node[:, 0], node[:, 1])[:, None, None])
-            cg = np.where(ok, cg, -np.inf)
-            best = cg.reshape(S, -1).max(axis=1)
-            # the best a split scan would find with the chosen feature left
-            # out: the planted fault that sets best_split_shortfall's upper
-            # reading
-            mine = (np.arange(self.F)[None, :]
-                    == np.asarray(tree.split_feature)[:, None])
-            other = np.where(mine[:, :, None], -np.inf, cg).reshape(
-                S, -1).max(axis=1)
+            best, other = self._candidate_gains(
+                np.asarray(hist, np.float64).reshape(self.n_leaf, -1)[:L],
+                anc, node, np.asarray(tree.split_feature))
         vals = np.zeros(self.n_leaf, np.float32)
         vals[:L] = value
         self.sb, loss = self._update(self.yb, self.sb, leaf_id,
@@ -425,6 +499,41 @@ class Follower:
             best_gain=best, other_gain=other,
             loss=float(np.asarray(loss, np.float64).sum() / self.n),
             unrouted=int(np.asarray(bad).sum()))
+
+    def _candidate_gains(self, hist, anc, node, split_feature):
+        """For each split the best gain any candidate threshold of any
+        feature would have given its node, and the best with the feature
+        the program chose left out (the planted fault that sets
+        best_split_shortfall's upper reading).
+
+        `hist` is [L, 3 * F * B] float64: each leaf's sums of gradient,
+        hessian and rows at or under each candidate.  A node's are its
+        leaves' added up: ONE product `anc @ hist`, [S, L] x [L, 3 * F * B],
+        which BLAS makes in seconds where einsum's loop took a minute at
+        2000 x 64.  Its result ([S, 3, F, B]: three [S, F, B] float64
+        arrays, 260 MB each at 254 x 2000 x 64) and `hist` (as large) are
+        what is alive at width; the gains are taken SPLITS_AT_ONCE splits at
+        a time, and their dozen temporaries are 16 MB each."""
+        S = anc.shape[0]
+        nh = (anc @ hist).reshape(S, 3, self.F, -1)             # left sums
+        finite = np.isfinite(self.cand_np)[None]
+        parent = node[:, 0] * node[:, 0] / (node[:, 1] + K_EPS)
+        best, other = np.empty(S), np.empty(S)
+        for s0 in range(0, S, SPLITS_AT_ONCE):
+            at = slice(s0, min(s0 + SPLITS_AT_ONCE, S))
+            n = at.stop - s0
+            lg, lh, lc = nh[at, 0], nh[at, 1], nh[at, 2]
+            tot = node[at, :, None, None]
+            rg, rh, rc = tot[:, 0] - lg, tot[:, 1] - lh, tot[:, 2] - lc
+            ok = ((lc >= self.min_data) & (rc >= self.min_data)
+                  & (lh >= self.min_hess) & (rh >= self.min_hess) & finite)
+            cg = (lg * lg / (lh + K_EPS) + rg * rg / (rh + K_EPS)
+                  - parent[at, None, None])
+            cg = np.where(ok, cg, -np.inf)
+            best[at] = cg.reshape(n, -1).max(axis=1)
+            cg[np.arange(n), split_feature[at]] = -np.inf
+            other[at] = cg.reshape(n, -1).max(axis=1)
+        return best, other
 
     def sum_forest(self, trees) -> np.ndarray:
         """The raw score of every row under `trees` as serialised (the first

@@ -7,8 +7,10 @@ import os
 import re
 import shutil
 
+import numpy as np
 import pytest
 
+import datagen
 from manifest import HERE, ROOT, Manifest
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
@@ -77,17 +79,28 @@ def test_every_name_has_its_files():
 
 
 def test_new_files_are_found_by_name(tmp_path):
-    """A later PR's configuration, traffic mix, cell and metric: new files
-    and new entries only."""
+    """A later PR's configuration, traffic mix, cell, metric and generator:
+    new files and new entries only."""
     root = tmp_path / "checkout"
     shutil.copytree(HERE, root / "benchmark",
                     ignore=shutil.ignore_patterns("__pycache__", "tests"))
     doc = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
     before = {p: open(p).read() for p in
-              (str(x) for x in (root / "benchmark").rglob("*.json"))}
+              (str(x) for x in (root / "benchmark").rglob("*.*")
+               if x.suffix in (".json", ".py"))}
     cfg = json.load(open(root / "benchmark/configs/higgs63.json"))
     cfg["name"] = "higgs15"
     cfg["params"]["max_bin"] = 15
+    cfg["data"] = {"generator": "one_hot_blocks", "groups": 3, "width": 4,
+                   "rows": 1000}
+    os.makedirs(root / "benchmark/generators")
+    (root / "benchmark/generators/one_hot_blocks.py").write_text(
+        "import numpy as np\n"
+        "def make(rng, rows, groups, width):\n"
+        "    hot = rng.integers(0, width, (rows, groups))\n"
+        "    X = (hot[:, :, None] == np.arange(width)).reshape(rows, -1)\n"
+        "    return X.astype(np.float32), (hot[:, 0] > 1).astype(np.float64)"
+        "\n")
     (root / "benchmark/configs/higgs15.json").write_text(json.dumps(cfg))
     (root / "benchmark/traffic/train-eval.json").write_text(json.dumps(
         {"valid_rows": 500000, "warmup_chunks": 1,
@@ -117,4 +130,23 @@ def test_new_files_are_found_by_name(tmp_path):
     assert "gen_s" not in [m["name"] for m in
                            man.metrics("per_layer", "higgs63-train")]
     assert man.reader("gen_s")({"spans": {"gen_s": 1.5}}) == 1.5
+    data = man.config(cell["config"])["data"]
+
+    def rows_of(seed):
+        return datagen.make(data, 50, np.random.default_rng(seed),
+                            here=man.here)
+
+    X, y = rows_of(2 ** 31 + 11)
+    assert X.shape == (50, 12) and X.dtype == np.float32 and len(y) == 50
+    assert np.all(X.sum(axis=1) == 3) and 0 < y.sum() < 50
+    assert np.array_equal(X, rows_of(2 ** 31 + 11)[0])
+    assert not np.array_equal(X, rows_of(5)[0])
+    # the generator that was there is found as before, a name with no file
+    # is refused
+    assert datagen.make(man.config("higgs63")["data"], 8,
+                        np.random.default_rng(1), here=man.here)[0].shape \
+        == (8, 28)
+    with pytest.raises(SystemExit):
+        datagen.make(dict(data, generator="no_such_rows"), 8,
+                     np.random.default_rng(1), here=man.here)
     assert all(open(p).read() == text for p, text in before.items())
