@@ -33,8 +33,14 @@ def _pandas_categories(data) -> Optional[List[list]]:
 
 
 def _as_2d_float(data, num_features: Optional[int] = None,
-                 pandas_categorical: Optional[List[list]] = None
-                 ) -> np.ndarray:
+                 pandas_categorical: Optional[List[list]] = None,
+                 keep_float32: bool = False) -> np.ndarray:
+    if (keep_float32 and isinstance(data, np.ndarray) and data.ndim == 2
+            and data.dtype == np.float32):
+        # binning reads a float32 table as it is (every value's float64
+        # image is exact, and the native quantizer has an exact float32
+        # path): a float64 copy of an 8.8 GB table is 17.6 GB and 40 s
+        return data
     if hasattr(data, "dtypes") and hasattr(data, "columns") and any(
             str(dt) == "category" for dt in data.dtypes):
         # pandas DataFrame with category columns -> category CODES
@@ -158,7 +164,8 @@ class Dataset:
                 else _pandas_categories(self.data))
         data = (self.data if is_sparse
                 else _as_2d_float(self.data,
-                                  pandas_categorical=self.pandas_categorical))
+                                  pandas_categorical=self.pandas_categorical,
+                                  keep_float32=True))
         feature_names = None
         if isinstance(self.feature_name, (list, tuple)):
             feature_names = list(self.feature_name)

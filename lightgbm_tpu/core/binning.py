@@ -239,11 +239,25 @@ class BinMapper:
         columns pass only their non-zero entries (bin.cpp:210-235).
         """
         values = np.asarray(values, dtype=np.float64)
-        num_sample_values = len(values)
         nan_mask = np.isnan(values)
-        values = values[~nan_mask]
-        na_cnt = int(nan_mask.sum())
+        # -0.0 counts as 0.0: the sorted sample is then the same sequence
+        # whatever sort made it (``find_bin_sorted``'s callers use others)
+        return self.find_bin_sorted(
+            np.sort(values[~nan_mask] + 0.0, kind="stable"),
+            int(nan_mask.sum()), total_sample_cnt, max_bin, min_data_in_bin,
+            min_split_data, bin_type, use_missing, zero_as_missing)
 
+    def find_bin_sorted(self, values_sorted: np.ndarray, na_cnt: int,
+                        total_sample_cnt: int, max_bin: int,
+                        min_data_in_bin: int = 3, min_split_data: int = 20,
+                        bin_type: int = BIN_TYPE_NUMERICAL,
+                        use_missing: bool = True,
+                        zero_as_missing: bool = False) -> "BinMapper":
+        """``find_bin`` from the sample already sorted: ``values_sorted``
+        is float64, ascending, without its ``na_cnt`` NaNs and with no
+        -0.0.  Bulk callers sort many columns at once
+        (core/dataset.py)."""
+        values = values_sorted
         if not use_missing:
             self.missing_type = MISSING_NONE
         elif zero_as_missing:
@@ -256,7 +270,6 @@ class BinMapper:
         self.bin_type = bin_type
         self.default_bin = 0
         zero_cnt = int(total_sample_cnt - len(values) - na_cnt)
-        values_sorted = np.sort(values, kind="stable")
         distinct, counts = _distinct_with_zero(values_sorted, zero_cnt)
         if len(distinct) == 0:
             self.is_trivial = True
@@ -281,13 +294,12 @@ class BinMapper:
                 bounds.append(math.nan)  # trailing NaN bin
             self.bin_upper_bound = np.asarray(bounds, dtype=np.float64)
             self.num_bin = len(bounds)
-            # count per bin for trivial-feature filtering
-            cnt_in_bin = [0] * self.num_bin
-            i_bin = 0
-            for v, c in zip(distinct, counts):
-                while v > self.bin_upper_bound[i_bin]:
-                    i_bin += 1
-                cnt_in_bin[i_bin] += int(c)
+            # count per bin for trivial-feature filtering: a distinct value
+            # lands in the first bin whose bound is not under it (a
+            # trailing NaN bound sorts last and takes none)
+            cnt_in_bin = [int(c) for c in np.bincount(
+                np.searchsorted(self.bin_upper_bound, distinct, side="left"),
+                weights=counts, minlength=self.num_bin)]
             if self.missing_type == MISSING_NAN:
                 cnt_in_bin[self.num_bin - 1] = na_cnt
             check(self.num_bin <= max_bin, "num_bin exceeds max_bin")

@@ -56,6 +56,35 @@ class FeatureInfo:
         self.offset = offset
 
 
+class _SortedSample:
+    """The binning sample's columns, sorted: ``column(f)`` is what
+    ``BinMapper.find_bin`` would make of column ``f`` itself (float64,
+    ascending, NaNs counted and dropped, -0.0 as 0.0), from one
+    ``np.sort`` over a slab of ``SLAB`` columns at a time.  Columns are
+    asked for in order, so a slab is sorted once."""
+
+    SLAB = 64
+
+    def __init__(self, block: np.ndarray):
+        self._block = block
+        self._first = -1
+
+    def column(self, f: int):
+        first = f - f % self.SLAB
+        if first != self._first:
+            # a COPY (np.array): one float64 column transposed is already
+            # contiguous, and sorting a view would sort the caller's data
+            x = np.array(self._block[:, first:first + self.SLAB].T,
+                         dtype=np.float64, order="C")
+            x += 0.0
+            x.sort(axis=1)              # NaNs last
+            self._rows, self._first = x, first
+            self._na = np.isnan(x).sum(axis=1)
+        na = int(self._na[f - first])
+        row = self._rows[f - first]
+        return row[:len(row) - na], na
+
+
 class TpuDataset:
     """Binned dataset: dense uint8/16 matrix + per-feature BinMappers + Metadata."""
 
@@ -136,14 +165,29 @@ class TpuDataset:
     def _fit_bin_mappers(self, data: np.ndarray, cfg: Config,
                          categorical: set) -> None:
         sample_idx = self._pick_sample(data.shape[0], cfg)
+        total = len(sample_idx)
+        sorted_cols = None
+        from ..parallel import network
+        if (isinstance(data, np.ndarray) and data.dtype.kind == "f"
+                and network.binning_world()[0] == 1):
+            # the sampled [S, F] block gathered ONCE (a fancy-indexed
+            # column apiece re-reads the whole table F times), then its
+            # numerical columns sorted a slab at a time (a rank of a
+            # multi-process run fits a strided share of the columns, one
+            # at a time as before)
+            block = (data if len(sample_idx) >= data.shape[0]
+                     else data[sample_idx])
+            sorted_cols = _SortedSample(block)
+            data, sample_idx = block, slice(None)
         self._fit_bin_mappers_from_cols(
             cfg, categorical, data.shape[1],
             lambda f: np.asarray(data[sample_idx, f], dtype=np.float64),
-            len(sample_idx))
+            total, sorted_cols)
 
     def _fit_bin_mappers_from_cols(self, cfg: Config, categorical: set,
                                    num_features: int, col_vals_fn,
-                                   total_sample_cnt: int) -> None:
+                                   total_sample_cnt: int,
+                                   sorted_cols=None) -> None:
         """Shared bin-fitting tail for the dense and sparse constructors.
 
         ``col_vals_fn(f)`` returns feature f's sampled values; for sparse
@@ -155,7 +199,11 @@ class TpuDataset:
         of its modulo-strided feature subset from its local sample, then
         the serialized mappers are allgathered and merged — the
         reference's distributed bin finding
-        (dataset_loader.cpp:933-1034)."""
+        (dataset_loader.cpp:933-1034).
+
+        ``sorted_cols`` (a ``_SortedSample``) hands a numerical column
+        over already sorted, many sorted in one call: the same
+        BinMappers, bit for bit (tests/test_bulk_binning.py)."""
         from ..parallel import network
         world, rank = network.binning_world()
         max_bin_by_feature = list(cfg.max_bin_by_feature or [])
@@ -165,12 +213,15 @@ class TpuDataset:
                   else BIN_TYPE_NUMERICAL)
             mb = (max_bin_by_feature[f] if f < len(max_bin_by_feature)
                   else cfg.max_bin)
-            return BinMapper().find_bin(
-                col_vals_fn(f), total_sample_cnt=total_sample_cnt,
-                max_bin=mb, min_data_in_bin=cfg.min_data_in_bin,
-                min_split_data=cfg.min_data_in_leaf,
-                bin_type=bt, use_missing=cfg.use_missing,
-                zero_as_missing=cfg.zero_as_missing)
+            kw = dict(total_sample_cnt=total_sample_cnt,
+                      max_bin=mb, min_data_in_bin=cfg.min_data_in_bin,
+                      min_split_data=cfg.min_data_in_leaf,
+                      bin_type=bt, use_missing=cfg.use_missing,
+                      zero_as_missing=cfg.zero_as_missing)
+            if sorted_cols is not None and bt == BIN_TYPE_NUMERICAL:
+                return BinMapper().find_bin_sorted(*sorted_cols.column(f),
+                                                   **kw)
+            return BinMapper().find_bin(col_vals_fn(f), **kw)
 
         if world > 1:
             local = {f: fit_one(f).to_dict()
@@ -380,7 +431,10 @@ class TpuDataset:
                     data, [used[j] for j in num_pos], self.bin_mappers,
                     dtype)
                 if nat is not None:
-                    out[:, num_pos] = nat
+                    if len(num_pos) == len(used):
+                        out = nat       # every column: no scatter by column
+                    else:
+                        out[:, num_pos] = nat
                     for j in num_pos:
                         done[j] = True
         for j, f in enumerate(used):
@@ -453,19 +507,25 @@ class TpuDataset:
         resident and spilled training see identical device bytes)."""
         return self.binned
 
-    def host_binned_T(self, row_multiple: int = 1,
-                      packed4: bool = False) -> np.ndarray:
+    def host_binned_T(self, row_multiple: int = 1, packed4: bool = False,
+                      feature_multiple: int = 1) -> np.ndarray:
         """Host-side feature-major training layout — the exact byte
         image ``device_binned_T`` uploads (see there for the layout
         contract); factored out so the host-spill store streams the
         same bytes the resident path would."""
-        npad = (-self.num_data) % row_multiple
-        t = np.ascontiguousarray(self.binned.T)
-        if npad:
-            t = np.pad(t, ((0, 0), (0, npad)))
+        n, f = self.binned.shape
+        npad = (-n) % row_multiple
         if packed4:
             from ..ops.pallas_histogram import pack_bins_4bit
-            t = pack_bins_4bit(t)
+            t = pack_bins_4bit(np.pad(np.ascontiguousarray(self.binned.T),
+                                      ((0, 0), (0, npad))))
+            fpad = (-t.shape[0]) % feature_multiple
+            return np.pad(t, ((0, fpad), (0, 0))) if fpad else t
+        # one allocation at the padded size: the transposed copy is the
+        # only pass over a table that may hold gigabytes
+        t = np.zeros((f + (-f) % feature_multiple, n + npad),
+                     self.binned.dtype)
+        t[:f, :n] = self.binned.T
         return t
 
     def drop_device_cache(self) -> None:
@@ -488,23 +548,29 @@ class TpuDataset:
                 self._device_binned = jnp.asarray(self.binned)
         return self._device_binned
 
-    def device_binned_T(self, row_multiple: int = 1, packed4: bool = False):
+    def device_binned_T(self, row_multiple: int = 1, packed4: bool = False,
+                        feature_multiple: int = 1):
         """Feature-major [F, Npad] bin matrix, rows padded to a multiple of
         ``row_multiple`` (pad rows are bin 0; training must give them zero
         weight).  This is the training layout: each feature is a contiguous
         lane stream for the histogram kernels.  ``packed4`` packs two
         <=16-bin columns per byte (Dense4bitsBin equivalent,
-        dense_nbits_bin.hpp:42): [ceil(F/2), Npad] on device."""
+        dense_nbits_bin.hpp:42): [ceil(F/2), Npad] on device.
+        ``feature_multiple`` pads the feature axis with all-zero bin rows
+        to whole feature tiles (ops/pallas_histogram.feature_tile), which
+        no split reads."""
         import jax.numpy as jnp
         key = getattr(self, "_device_binned_T_key", None)
-        if key != (row_multiple, packed4):
+        if key != (row_multiple, packed4, feature_multiple):
             from ..utils.phase import GLOBAL_TIMER
             from ..utils.telemetry import TELEMETRY
             with GLOBAL_TIMER.phase("h2d_upload"):
-                t = self.host_binned_T(row_multiple, packed4)
+                t = self.host_binned_T(row_multiple, packed4,
+                                       feature_multiple)
                 TELEMETRY.counter_add("transfer/h2d_bytes", int(t.nbytes))
                 self._device_binned_T = jnp.asarray(t)
-            self._device_binned_T_key = (row_multiple, packed4)
+            self._device_binned_T_key = (row_multiple, packed4,
+                                         feature_multiple)
         return self._device_binned_T
 
     def check_align(self, other: "TpuDataset") -> None:

@@ -208,6 +208,10 @@ def _bind_quantize(L) -> bool:
     return True
 
 
+# threads the whole-matrix quantizer spreads a table of over 256 MB on
+_QUANTIZE_THREADS = 4
+
+
 def quantize_rows_native(data: np.ndarray, feat_idx, mappers,
                          out_dtype) -> Optional[np.ndarray]:
     """One native pass quantizing every NUMERICAL used column of a
@@ -255,6 +259,23 @@ def quantize_rows_native(data: np.ndarray, feat_idx, mappers,
     fidx = np.asarray(feat_idx, dtype=np.int64)
     out = np.empty((n, n_used), dtype=out_dtype)
     max_nb = int(np.max(offs[1:] - offs[:-1], initial=0))
+
+    def over_row_ranges(call):
+        """``call(rows_in, n_rows, rows_out)`` over ranges of whole rows,
+        on a few threads where the table is large (rows are independent
+        and ctypes releases the GIL; the bytes written are the same)."""
+        workers = min(_QUANTIZE_THREADS, os.cpu_count() or 1,
+                      max(1, data.nbytes >> 28))
+        if workers <= 1:
+            call(data, n, out)
+            return
+        step = -(-n // workers)
+        from concurrent.futures import ThreadPoolExecutor
+        with ThreadPoolExecutor(workers) as pool:
+            list(pool.map(lambda a: call(data[a:a + step],
+                                         min(step, n - a), out[a:a + step]),
+                          range(0, n, step)))
+
     if is_f64 == 0 and out_dtype == np.uint8 and max_nb <= 128:
         # f32 fast path with EXACT thresholds: t[b] = smallest float
         # whose f64 value is > ub[b]; then ub[b] < (double)v  <=>
@@ -264,22 +285,22 @@ def quantize_rows_native(data: np.ndarray, feat_idx, mappers,
         not_past = t.astype(np.float64) <= flat
         t = np.where(not_past, np.nextafter(t, np.float32(np.inf)), t)
         t = np.ascontiguousarray(t, dtype=np.float32)
-        L.lgbmtpu_quantize_rows_f32(
-            data.ctypes.data_as(ctypes.c_void_p), n, f_total,
+        over_row_ranges(lambda rows, n_rows, dst: L.lgbmtpu_quantize_rows_f32(
+            rows.ctypes.data_as(ctypes.c_void_p), n_rows, f_total,
             fidx.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)), n_used,
             t.ctypes.data_as(ctypes.POINTER(ctypes.c_float)),
             offs.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),
             mt.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)),
             nb.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)),
-            out.ctypes.data_as(ctypes.c_void_p))
+            dst.ctypes.data_as(ctypes.c_void_p)))
         return out
-    L.lgbmtpu_quantize_rows(
-        data.ctypes.data_as(ctypes.c_void_p), is_f64, n, f_total,
+    over_row_ranges(lambda rows, n_rows, dst: L.lgbmtpu_quantize_rows(
+        rows.ctypes.data_as(ctypes.c_void_p), is_f64, n_rows, f_total,
         fidx.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)), n_used,
         flat.ctypes.data_as(ctypes.POINTER(ctypes.c_double)),
         offs.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),
         mt.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)),
         nb.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)),
         1 if out_dtype == np.uint16 else 0,
-        out.ctypes.data_as(ctypes.c_void_p))
+        dst.ctypes.data_as(ctypes.c_void_p)))
     return out

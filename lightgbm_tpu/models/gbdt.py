@@ -91,6 +91,12 @@ def _record_seg_stats(rows: np.ndarray, trees: int,
     # what turns blocks into rows and totals into per-tree figures
     TELEMETRY.counter_add("seg/trees", int(trees))
     TELEMETRY.gauge_set("seg/block_rows", int(block_rows))
+    # the strict grower's shape facts: feature tiles a pass walks (1: the
+    # table whole) and the bytes of its per-leaf histogram tables
+    if rows[:, 13].max():
+        TELEMETRY.gauge_set("seg/feature_tiles", int(rows[:, 13].max()))
+        TELEMETRY.gauge_set("seg/leaf_hist_bytes",
+                            1024 * int(rows[:, 14].max()))
     # quantization / staging counters stay 0 on paths that never
     # quantize or stage — record only live events so trace_report's
     # hist section renders n/a instead of misleading zero rates
@@ -390,9 +396,11 @@ def estimate_working_set(config, data_shape, *,
     dict.  ``num_bins`` defaults to ``max_bin`` (the post-binning upper
     bound; a constructed dataset may resolve fewer bins and a slightly
     smaller matrix).  The single-device bin layout is resolved the same
-    way training resolves it: the pallas kernel's feature-major padded/
-    packed layout when the shape supports it, the row-major matrix
-    otherwise.  A warm process adds its compiled programs' recorded
+    way training resolves it: the pallas kernels' feature-major padded/
+    packed layout where they take the shape (whole, or in feature tiles
+    with the bin rows padded to whole tiles: 2000 columns x 64 bins is
+    16 tiles of 128), the row-major matrix otherwise.  A warm process
+    adds its compiled programs' recorded
     temp+argument+output bytes; a cold one contributes 0.  See
     docs/TUNING.md (working-set budgeting)."""
     if not isinstance(config, Config):
@@ -410,12 +418,16 @@ def estimate_working_set(config, data_shape, *,
     choice = str(config.tpu_histogram_backend).strip().lower()
     if (choice != "onehot" and not config.gpu_use_dp
             and not config.tpu_double_precision):
-        from ..ops.pallas_histogram import pick_block_rows, supported
+        from ..ops.pallas_histogram import (feature_tile, pick_block_rows,
+                                            supported)
         nb2 = _round_up_pow2(max(bins, 2))
         if supported(num_columns, nb2, np.dtype(np.uint8)):
             rb = (int(config.tpu_row_chunk) if config.tpu_row_chunk > 0
                   else pick_block_rows(num_columns, bins, num_data))
             layout = ("T", rb, bins <= 16)
+            tile = feature_tile(num_columns, nb2)
+            if tile < num_columns:
+                num_columns = -(-num_columns // tile) * tile
     return working_set_bytes(num_data, num_columns,
                              num_tree_per_iteration=C, layout=layout)
 
@@ -471,21 +483,26 @@ class GBDT:
                 self.reset_train_data(train_set)
 
     # ----------------------------------------------------------------- setup
-    def _resolve_hist_backend(self, parallel: bool) -> str:
+    def _resolve_hist_backend(self, parallel: bool,
+                              walks_tiles: bool = True) -> str:
         """auto -> pallas on TPU when the kernel supports the shape
         (ops/pallas_histogram.supported); parallel learners and explicit
-        double-precision requests stay on the XLA one-hot path."""
+        double-precision requests stay on the XLA one-hot path, and so
+        does a table that takes several feature tiles a pass under any
+        grower but the serial segment one (``walks_tiles``), whose fused
+        kernels alone walk them."""
         cfg = self.config
         choice = str(cfg.tpu_histogram_backend).strip().lower()
         if choice == "onehot":
             return "onehot"
         if choice == "pallas" or choice == "auto":
             import jax
-            from ..ops.pallas_histogram import supported
-            shape_ok = supported(self.train_set.num_columns,
-                                 _round_up_pow2(
-                                     max(self.train_set.max_column_bin, 2)),
-                                 self.train_set.binned.dtype)
+            from ..ops.pallas_histogram import feature_tiles, supported
+            nb2 = _round_up_pow2(max(self.train_set.max_column_bin, 2))
+            shape_ok = (supported(self.train_set.num_columns, nb2,
+                                  self.train_set.binned.dtype)
+                        and (walks_tiles or feature_tiles(
+                            self.train_set.num_columns, nb2) == 1))
             ok = (shape_ok and not parallel
                   and not cfg.gpu_use_dp and not cfg.tpu_double_precision)
             if choice == "pallas":
@@ -501,8 +518,8 @@ class GBDT:
                 # on the chip a shape the kernels cannot take never
                 # selects the slow grower in silence
                 log_warning(
-                    f"the pallas histogram kernels do not fit "
-                    f"{self.train_set.num_columns} columns x "
+                    f"the pallas histogram kernels of this learner do not "
+                    f"fit {self.train_set.num_columns} columns x "
                     f"{self.train_set.max_column_bin} bins in VMEM; using "
                     f"the XLA one-hot grower")
             return "pallas" if ok else "onehot"
@@ -589,11 +606,19 @@ class GBDT:
                        and not forced_plan)
         oleaf_mode = data_mode or feature_mode or voting_mode
         D = int(mesh.devices.size) if parallel else 1
-        backend = self._resolve_hist_backend(parallel and not oleaf_mode)
+        backend = self._resolve_hist_backend(
+            parallel and not oleaf_mode,
+            walks_tiles=(not parallel and impl in ("auto", "segment")
+                         and not forced_plan
+                         and not cfg.cegb_penalty_feature_lazy))
         rb = 0
         self._packed4 = False
+        # bin rows the device table is padded to a multiple of: one
+        # feature tile's where the segment kernels walk tiles
+        self._bins_row_multiple = 1
         if backend == "pallas":
-            from ..ops.pallas_histogram import pick_block_rows
+            from ..ops.pallas_histogram import (feature_tile, feature_tiles,
+                                                pick_block_rows)
             # feature-parallel replicates rows (only split FINDING is
             # sharded); rows-sharded modes pad to whole blocks per shard
             rows_D = 1 if (parallel and feature_mode) else D
@@ -610,6 +635,10 @@ class GBDT:
             self._packed4 = self.num_bins <= 16 and not (
                 parallel and feature_mode)
             self._bins_layout = ("T", rb * rows_D, self._packed4)
+            if feature_tiles(train_set.num_columns, self.num_bins) > 1:
+                tile = feature_tile(train_set.num_columns, self.num_bins)
+                self._bins_row_multiple = (tile // 2 if self._packed4
+                                           else tile)
         else:
             self._bins_layout = ("rows", 0, False)
         # The rows-sharded mesh learners keep the bin matrix and the
@@ -850,8 +879,12 @@ class GBDT:
         on record (a resumed/warm process knows its compiled programs'
         temp+argument+output bytes; a cold one contributes 0)."""
         ts = self.train_set
+        # bin rows as uploaded: padded to whole feature tiles where the
+        # kernels walk tiles (two logical columns a row under packed4)
+        per_row = 2 if self._bins_layout[2] else 1
+        m = self._bins_row_multiple * per_row
         return working_set_bytes(
-            self.num_data, ts.num_columns,
+            self.num_data, ts.num_columns + (-ts.num_columns) % m,
             num_tree_per_iteration=self.num_tree_per_iteration,
             layout=self._bins_layout,
             itemsize=ts.binned.dtype.itemsize)
@@ -912,7 +945,9 @@ class GBDT:
                     train_set.host_binned_T(rm, packed4=packed4),
                     self._row_sharding)
             else:
-                self.bins = train_set.device_binned_T(rm, packed4=packed4)
+                self.bins = train_set.device_binned_T(
+                    rm, packed4=packed4,
+                    feature_multiple=self._bins_row_multiple)
             self._row_pad = int(self.bins.shape[1]) - self.num_data
         else:
             self.bins = train_set.device_binned()
@@ -925,7 +960,9 @@ class GBDT:
         from ..data.hostspill import HostSpillStore
         kind, rm, packed4 = self._bins_layout
         if kind == "T":
-            mat = train_set.host_binned_T(rm, packed4=packed4)
+            mat = train_set.host_binned_T(
+                rm, packed4=packed4,
+                feature_multiple=self._bins_row_multiple)
             self._row_pad = int(mat.shape[1]) - self.num_data
             axis = 1
         else:
@@ -1206,7 +1243,9 @@ class GBDT:
         # their histogram reduction, and the fused grower's layout is
         # row-major.
         batched_roots = (C > 1 and self._use_segment
-                         and getattr(self, "_mesh", None) is None)
+                         and getattr(self, "_mesh", None) is None
+                         # histogram_all takes a table whole or not at all
+                         and self._bins_row_multiple == 1)
         if batched_roots:
             from ..ops.pallas_histogram import (channel_set_capacity,
                                                 histogram_all,

@@ -53,8 +53,8 @@ from ..ops.pallas_histogram import (_segment_buckets, frontier_width,
 from ..ops.split import (NEG_INF, FeatureMeta, best_split,
                          expand_group_hist)
 from .grower import (GrowerParams, _node_feature_mask, mono_handoff)
-from .grower_seg import (COMPACT_WASTE, _COMPACT_MUT, _SegState,
-                         _unpermute, apply_route, compact_state,
+from .grower_seg import (COMPACT_WASTE, SEG_STATS_SLOTS, _COMPACT_MUT,
+                         _SegState, _unpermute, apply_route, compact_state,
                          cond_narrow, fresh_state, stripe_histogram)
 
 # build-time decision, keyed "frontier" — benches read whether the
@@ -677,9 +677,9 @@ def make_grow_tree_frontier(num_bins: int, params: GrowerParams,
                            jnp.int32(max_blocks), jnp.int32(K),
                            fk_rounds, qclips.astype(jnp.int32),
                            s_hits, s_looks]
-                          # splits and the strict grower's lookahead
-                          # counters: none here
-                          + [jnp.int32(0)] * 4)
+                          # splits, the strict grower's lookahead
+                          # counters and its feature tiles: none here
+                          + [jnp.int32(0)] * (SEG_STATS_SLOTS - 9))
         return st.tree, leaf_id_orig, stats
 
     if wrap is not None:

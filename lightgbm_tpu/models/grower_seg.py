@@ -45,7 +45,7 @@ from jax import lax
 
 from ..ops.pallas_histogram import (NUM_CHANNELS, _segment_buckets,
                                     bucket_index, empty_lookahead_slots,
-                                    fused_packed_optin,
+                                    feature_tile, fused_packed_optin,
                                     fused_route_decisions,
                                     fused_route_policy,
                                     histogram_segment,
@@ -87,17 +87,18 @@ COMPACT_WASTE = float(_os.environ.get("LIGHTGBM_TPU_COMPACT_WASTE", "9.0"))
 # the growers' third jit output: i32 counter vector, one row per device
 # under the data-parallel wrappers.  Fixed width so every grower/wrapper
 # agrees; slots [fused_k_rounds, quant_clips, stage_hits, stage_lookups]
-# stay 0 on paths that don't fuse-K / quantize / stage, and [lookahead_hits,
-# lookahead_filled, route_only_blocks] where no lookahead lane sets run.
-SEG_STATS_SLOTS = 13
+# stay 0 on paths that don't fuse-K / quantize / stage, [lookahead_hits,
+# lookahead_filled, route_only_blocks] where no lookahead lane sets run, and
+# [feature_tiles, leaf_hist_kib] on the growers that do not report them.
+SEG_STATS_SLOTS = 15
 
 
 def seg_stats_enabled() -> bool:
     """When LIGHTGBM_TPU_SEG_STATS is set, the counters the growers
     return — [scanned_blocks, compactions, grid_steps, max_blocks, K,
     fused_k_rounds, quant_clips, stage_hits, stage_lookups, splits,
-    lookahead_hits, lookahead_filled, route_only_blocks] — are printed
-    per tree."""
+    lookahead_hits, lookahead_filled, route_only_blocks, feature_tiles,
+    leaf_hist_kib] — are printed per tree."""
     return bool(_os.environ.get("LIGHTGBM_TPU_SEG_STATS"))
 
 
@@ -108,16 +109,17 @@ def print_seg_stats(stats) -> None:
     per-device concatenation of them.
 
     ``grid`` counts the kernel grid steps actually dispatched (the bucket
-    the interval landed in, summed over calls); grid − scanned is the
-    skipped-step waste the static bucket ladder pays
-    (ops/pallas_histogram._segment_buckets)."""
+    the interval landed in, summed over calls, once for every feature
+    tile that walks it); grid − scanned x tiles is the skipped-step waste
+    the static bucket ladder pays (ops/pallas_histogram._segment_buckets)."""
     import sys
 
     import numpy as np
 
     rows = np.asarray(stats).reshape(-1, SEG_STATS_SLOTS)
     for d, (scanned, sorts, grid, max_blocks, k, fkr, clips, shits,
-            slooks, splits, lhits, lfill, ronly) in enumerate(rows):
+            slooks, splits, lhits, lfill, ronly, tiles,
+            _hist_kib) in enumerate(rows):
         dev = f" dev{d}" if len(rows) > 1 else ""
         nb = max(int(max_blocks), 1)
         extra = ""
@@ -132,6 +134,8 @@ def print_seg_stats(stats) -> None:
             extra += (f", lookahead {int(lhits)} of {int(splits)} splits "
                       f"served ({int(lfill)} filled, {int(ronly)} "
                       f"route-only blocks)")
+        if tiles > 1:
+            extra += f", {int(tiles)} feature tiles a pass"
         sys.stderr.write(
             f"seg stats{dev}: scanned {int(scanned)} blocks "
             f"({scanned / nb:.1f} N-equivalents), "
@@ -391,18 +395,25 @@ def compact_state(st: _SegState, L: int, rb: int) -> _SegState:
         lid, perm = lax.sort(
             (st.leaf_id, jnp.arange(n, dtype=jnp.int32)),
             num_keys=1, is_stable=True)
-        binsT = jnp.take(st.binsT, perm, axis=1)
-        if packed_w:
-            w8 = jnp.take(st.w8, perm, axis=1)
-        else:
-            # channels 6-7 are structurally zero (pack_channels) — move
-            # only the live ones, refill the rest (same trim the sort
-            # path makes)
-            w8 = jnp.concatenate(
-                [jnp.take(st.w8[:6], perm, axis=1),
-                 jnp.zeros((st.w8.shape[0] - 6, st.w8.shape[1]),
-                           st.w8.dtype)])
-        order = jnp.take(st.order, perm)
+        with jax.named_scope("compact_gather"):
+            # ``perm`` is a permutation: promised in bounds, the gather
+            # builds no table-sized mask to fill the rows that miss
+            def move(x):
+                return x.at[..., perm].get(unique_indices=True,
+                                           mode="promise_in_bounds")
+
+            binsT = move(st.binsT)
+            if packed_w:
+                w8 = move(st.w8)
+            else:
+                # channels 6-7 are structurally zero (pack_channels) —
+                # move only the live ones, refill the rest (same trim
+                # the sort path makes)
+                w8 = jnp.concatenate(
+                    [move(st.w8[:6]),
+                     jnp.zeros((st.w8.shape[0] - 6, st.w8.shape[1]),
+                               st.w8.dtype)])
+            order = move(st.order)
     leaves = jnp.arange(L, dtype=jnp.int32)
     starts = jnp.searchsorted(lid, leaves, side="left").astype(jnp.int32)
     ends = jnp.searchsorted(lid, leaves, side="right").astype(jnp.int32)
@@ -675,8 +686,20 @@ def make_grow_tree_segment(num_bins: int, params: GrowerParams,
         F = fmeta.num_bin.shape[0]
         assert n % rb == 0, (n, rb)
         max_blocks = n // rb
-        # pad physical rows to a multiple of 4 for the sort word packing
-        fpad = (-n_phys) % 4
+        # feature tiles a pass of the routed segment kernels walks (1: the
+        # table whole, today's program).  Those kernels alone walk tiles.
+        tile_cols = feature_tile(G_cols, B)
+        n_tiles = -(-G_cols // tile_cols)
+        if n_tiles > 1 and not (fused_route and not comm.no_subtract):
+            raise ValueError(
+                f"{G_cols} columns x {B} bins take {n_tiles} feature tiles, "
+                f"which only the fused route+histogram kernels of the "
+                f"serial segment grower walk; they are off here")
+        # pad physical rows to a multiple of 4 for the sort word packing,
+        # or to whole feature tiles (GBDT uploads the table so padded: no
+        # copy is made here)
+        fpad = (-n_phys) % ((tile_cols // 2 if p.packed4 else tile_cols)
+                            if n_tiles > 1 else 4)
         if fpad:
             binsT = jnp.pad(binsT, ((0, fpad), (0, 0)))
 
@@ -685,7 +708,7 @@ def make_grow_tree_segment(num_bins: int, params: GrowerParams,
         bucket_arr = jnp.asarray(_segment_buckets(max_blocks), jnp.int32)
 
         def grid_of(nb):
-            return segment_grid_size(bucket_arr, nb)
+            return n_tiles * segment_grid_size(bucket_arr, nb)
 
         with jax.named_scope("quantize_pack"):
             if packed_acc:
@@ -962,7 +985,7 @@ def make_grow_tree_segment(num_bins: int, params: GrowerParams,
             root_blk = jnp.int32(max_blocks)
         st = st._replace(leaf_hist=st.leaf_hist.at[0].set(root_hist),
                          scanned_since=root_blk, scanned_total=root_blk,
-                         grid_total=jnp.int32(max_blocks))
+                         grid_total=jnp.int32(n_tiles * max_blocks))
         with jax.named_scope("split_scan"):
             st = scan_leaf(st, 0, root_hist, G0, H0, C0, jnp.int32(0),
                            fmeta, feature_mask, key, 2 * L)
@@ -977,7 +1000,10 @@ def make_grow_tree_segment(num_bins: int, params: GrowerParams,
                            jnp.int32(max_blocks), jnp.int32(1),
                            jnp.int32(0), qclips.astype(jnp.int32),
                            jnp.int32(0), jnp.int32(0), st.num_splits,
-                           st.look_hits, st.look_filled, st.route_only])
+                           st.look_hits, st.look_filled, st.route_only,
+                           jnp.int32(n_tiles),
+                           jnp.int32((st.leaf_hist.size
+                                      + st.look_hist.size) * 4 // 1024)])
         return st.tree, leaf_id_orig, stats
 
     if wrap is not None:

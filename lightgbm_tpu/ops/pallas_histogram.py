@@ -104,37 +104,10 @@ def _pick_chunk(rb: int) -> int:
     return rb
 
 
-def supported(num_features: int, num_bins: int, dtype) -> bool:
-    """Whether the kernels handle this shape (else callers fall back to the
-    XLA one-hot path in ops/histogram.py)."""
-    if dtype not in (jnp.uint8, jnp.int8):
-        return False
-    if num_bins > 256:
-        return False
-    # The kernel's scoped-VMEM stack as Mosaic lays it out must fit the
-    # compiler's limit (tests/test_tpu_compile.py holds this to the
-    # compiler).  The [F*B, och] f32 accumulator is tiled (8, 128), so its
-    # och <= 128 lanes pad to 128 (16x the logical bytes at och = 8); the
-    # double-buffered bins / weights / leaf-id blocks share the stack,
-    # and the one-hot temporaries took up to 0.7 MB more in the compiles
-    # measured (_SCOPED_VMEM_SLACK).  F rounds up to a multiple of 4 — the
-    # segment grower pads features to pack them into sort words.
-    F4 = -(-num_features // 4) * 4
-    acc = F4 * num_bins * _LANES * 4
-    streams = 2 * pick_block_rows(num_features, num_bins) * (
-        F4 + 2 * NUM_CHANNELS + 4)
-    return acc + streams + _SCOPED_VMEM_SLACK <= _SCOPED_VMEM_LIMIT
-
-
-def pick_block_rows(num_features: int, num_bins: int,
-                    num_rows: int = 0) -> int:
-    """Largest power-of-two row block whose VMEM working set fits budget.
-
-    ``num_rows`` (when known) caps the block at the next power of two >=
-    the dataset, so small datasets are not padded to a huge block.
-    """
-    F4 = -(-num_features // 4) * 4
-    acc = F4 * num_bins * NUM_CHANNELS * 4
+def _block_rows_for(cols: int, num_bins: int, num_rows: int = 0) -> int:
+    """Largest power-of-two row block whose VMEM working set fits the
+    budget when a pass holds ``cols`` (a multiple of 4) columns."""
+    acc = cols * num_bins * NUM_CHANNELS * 4
     # one-hot chunk (bf16) + its integer compare intermediate
     onehot = _fblk(num_bins) * num_bins * CHUNK * (2 + 4)
     rb = 4 * DEFAULT_BLOCK_ROWS
@@ -143,11 +116,88 @@ def pick_block_rows(num_features: int, num_bins: int,
         rb = min(rb, max(CHUNK, cap))
     while rb > CHUNK:
         # double-buffered input blocks (bins u8, w8 bf16, leaf_id i32)
-        streams = 2 * rb * (F4 + 2 * NUM_CHANNELS + 4)
+        streams = 2 * rb * (cols + 2 * NUM_CHANNELS + 4)
         if acc + streams + onehot <= _VMEM_BUDGET:
             return rb
         rb //= 2
     return rb
+
+
+def _scoped_vmem_need(cols: int, num_bins: int) -> int:
+    """The scoped-VMEM stack of a pass over ``cols`` columns as Mosaic
+    lays it out (tests/test_tpu_compile.py holds this to the compiler).
+    The [cols*B, och] f32 accumulator is tiled (8, 128), so its och <= 128
+    lanes pad to 128 (16x the logical bytes at och = 8); the
+    double-buffered bins / weights / leaf-id blocks share the stack, and
+    the one-hot temporaries took up to 0.7 MB more in the compiles
+    measured (_SCOPED_VMEM_SLACK)."""
+    acc = cols * num_bins * _LANES * 4
+    streams = 2 * _block_rows_for(cols, num_bins) * (
+        cols + 2 * NUM_CHANNELS + 4)
+    return acc + streams + _SCOPED_VMEM_SLACK
+
+
+# Feature tiles.  A table too wide for one accumulator (2000 columns x 64
+# bins is 65.5 MB of lane-padded f32) is walked in tiles of columns by the
+# routed segment kernels: each tile's accumulator gets a quarter of the
+# scoped-VMEM limit, which leaves room for the lookahead kernel's (block
+# sum, hi, lo) triple and its double-buffered output under
+# _FUSED_VMEM_CAP.  A tile is a whole number of u8 (32, 128) VMEM tiles
+# of physical bin rows.  Tiles are admitted at the one accumulator height
+# a tiled pass has run at on the chip, 64 bins (128 columns a tile:
+# benchmark cell epsilon63-train); at any other a table past one
+# accumulator is refused as before (256 bins would walk 32-column tiles:
+# compiled for a described v5e, never run, PERF.md section 7).
+_TILE_ACC_BYTES = _SCOPED_VMEM_LIMIT // 4
+_TILE_ROW_ALIGN = 32
+_TILE_BINS = 64
+
+
+def feature_tile(num_features: int, num_bins: int) -> int:
+    """Columns one pass of the routed segment kernels accumulates at a
+    time: all of them (rounded up to a multiple of 4 — the segment grower
+    pads features to pack them into sort words) where the whole
+    accumulator fits the compiler's default limit as ``supported`` always
+    reckoned it or the bins are not ``_TILE_BINS`` (such a table is not
+    ``supported``), else the tile width."""
+    F4 = -(-num_features // 4) * 4
+    if (num_bins != _TILE_BINS
+            or _scoped_vmem_need(F4, num_bins) <= _SCOPED_VMEM_LIMIT):
+        return F4
+    cols = _TILE_ACC_BYTES // (num_bins * _LANES * 4)
+    return max(_TILE_ROW_ALIGN, cols // _TILE_ROW_ALIGN * _TILE_ROW_ALIGN)
+
+
+def feature_tiles(num_features: int, num_bins: int) -> int:
+    """Tiles a pass walks at this shape; 1 where every kernel takes the
+    table whole."""
+    return -(-num_features // feature_tile(num_features, num_bins))
+
+
+def supported(num_features: int, num_bins: int, dtype) -> bool:
+    """Whether the serial segment grower's kernels handle this shape
+    (else callers fall back to the XLA one-hot path in ops/histogram.py):
+    whole, or tile by tile (``feature_tiles`` > 1: the routed segment
+    kernels alone walk tiles, so GBDT keeps every other learner and
+    grower at such a shape off the Pallas backend)."""
+    if dtype not in (jnp.uint8, jnp.int8):
+        return False
+    if num_bins > 256:
+        return False
+    return (_scoped_vmem_need(feature_tile(num_features, num_bins), num_bins)
+            <= _SCOPED_VMEM_LIMIT)
+
+
+def pick_block_rows(num_features: int, num_bins: int,
+                    num_rows: int = 0) -> int:
+    """Largest power-of-two row block whose VMEM working set fits budget,
+    reckoned for one feature tile.
+
+    ``num_rows`` (when known) caps the block at the next power of two >=
+    the dataset, so small datasets are not padded to a huge block.
+    """
+    return _block_rows_for(feature_tile(num_features, num_bins), num_bins,
+                           num_rows)
 
 
 def pack_channels(grad: jax.Array, hess: jax.Array,
@@ -1103,16 +1153,21 @@ def _route_block_ids(sref, o: int, frow, lid, packed4: bool):
 
 def _kernel_segment_routed(sref, binsT_ref, w_ref, frow_ref, lid_ref,
                            lid_out_ref, out_ref, acc_ref, *,
-                           num_bins, packed4, onehot_build="iota"):
+                           num_bins, packed4, onehot_build="iota",
+                           block_axis=0):
     # sref: [3 + _ROUTE_WORDS] = (start_block, n_blocks, target_leaf, route)
-    i = pl.program_id(0)
+    # block_axis 1: grid (feature tiles, blocks), one tile's columns in
+    # binsT_ref / acc_ref / out_ref, every tile walking the same blocks
+    i = pl.program_id(block_axis)
 
     @pl.when(i == 0)
     def _():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
     # 1) route this block — unconditional: skipped steps revisit an
-    # in-range block and the update is idempotent
+    # in-range block and the update is idempotent (so is a later tile's:
+    # it finds the block routed, or routes the ids it prefetched before
+    # the write landed, to the same values)
     lid_out_ref[...] = _route_block_ids(sref, 3, frow_ref[...],
                                         lid_ref[...], packed4)
 
@@ -1129,14 +1184,45 @@ def _kernel_segment_routed(sref, binsT_ref, w_ref, frow_ref, lid_ref,
         _accumulate_block(binsT_ref, wfn, acc_ref, num_bins, packed4,
                           onehot_build)
 
-    @pl.when(i == pl.num_programs(0) - 1)
+    @pl.when(i == pl.num_programs(block_axis) - 1)
     def _():
         out_ref[:] = acc_ref[:]
 
 
+def _tile_index_maps(max_blocks: int):
+    """Index maps of a (feature tiles, blocks) grid over the interval the
+    scalars open with: a per-row stream's block, which every tile reads
+    again, and the tile's own block of the bin table."""
+    def im_row(t, i, s):
+        return (0, jnp.minimum(s[0] + i, max_blocks - 1))
+
+    def im_tile(t, i, s):
+        return (t, jnp.minimum(s[0] + i, max_blocks - 1))
+
+    return im_row, im_tile
+
+
+def _tile_rows(binsT: jax.Array, num_bins: int, packed4: bool,
+               feature_tile_cols: int | None) -> int:
+    """Physical bin rows a tile of the routed segment kernels holds, or 0
+    where the pass takes the table whole (today's program).
+    ``feature_tile_cols`` (logical columns) overrides ``feature_tile``'s
+    arithmetic: how the tests force several tiles at a small shape."""
+    F = binsT.shape[0]
+    F_log = 2 * F if packed4 else F
+    cols = feature_tile_cols or feature_tile(F_log, num_bins)
+    if cols >= F_log:
+        return 0
+    rows = cols // 2 if packed4 else cols
+    assert rows > 0 and F % rows == 0, (
+        f"a pass in feature tiles of {rows} bin rows needs the table "
+        f"padded to whole tiles, got {F} rows")
+    return rows
+
+
 @functools.partial(jax.jit,
                    static_argnames=("num_bins", "block_rows", "interpret",
-                                    "packed4", "onehot_build"))
+                                    "packed4", "onehot_build", "tile_rows"))
 def _histogram_segment_routed(binsT: jax.Array, w8: jax.Array,
                               leaf_id: jax.Array, start_block: jax.Array,
                               n_blocks: jax.Array, target_leaf: jax.Array,
@@ -1144,7 +1230,8 @@ def _histogram_segment_routed(binsT: jax.Array, w8: jax.Array,
                               block_rows: int = 0,
                               interpret: bool | None = None,
                               packed4: bool = False,
-                              onehot_build: str = "iota"):
+                              onehot_build: str = "iota",
+                              tile_rows: int = 0):
     F, n = binsT.shape
     F_log = 2 * F if packed4 else F
     CHW = int(w8.shape[0])
@@ -1154,6 +1241,11 @@ def _histogram_segment_routed(binsT: jax.Array, w8: jax.Array,
     assert n % block_rows == 0, (n, block_rows)
     if interpret is None:
         interpret = _interpret_default()
+    if tile_rows:
+        return _histogram_segment_routed_tiled(
+            binsT, w8, leaf_id, start_block, n_blocks, target_leaf, route,
+            num_bins, block_rows, interpret, packed4, onehot_build,
+            tile_rows)
     max_blocks = n // block_rows
     grid_n = jnp.clip(n_blocks, 1, max_blocks).astype(jnp.int32)
     scalars = jnp.concatenate([
@@ -1206,13 +1298,77 @@ def _histogram_segment_routed(binsT: jax.Array, w8: jax.Array,
     return lid_out[0], hist.reshape(F_log, num_bins, och)
 
 
+def _histogram_segment_routed_tiled(binsT, w8, leaf_id, start_block,
+                                    n_blocks, target_leaf, route, num_bins,
+                                    block_rows, interpret, packed4,
+                                    onehot_build, tile_rows):
+    """``_histogram_segment_routed`` over a table too wide for one
+    accumulator: grid (feature tiles, blocks).  Each tile accumulates its
+    ``tile_rows`` bin rows over the whole interval into its own
+    accumulator and writes its slab of the histogram at its last block;
+    the channel, id and split-row streams are read again by every tile
+    (25 bytes a row beside the tile's bin rows) and every tile routes the
+    block it holds, which is idempotent.  Per (tile, block) the matmuls
+    are the untiled kernel's over those columns, in the same chunk order:
+    the sums are the untiled kernel's bit for bit."""
+    F, n = binsT.shape
+    T = tile_rows
+    n_tiles = F // T
+    F_log = 2 * F if packed4 else F
+    T_log = 2 * T if packed4 else T
+    CHW = int(w8.shape[0])
+    och = PACKED_CHANNELS if w8.dtype == jnp.int32 else NUM_CHANNELS
+    max_blocks = n // block_rows
+    grid_n = jnp.clip(n_blocks, 1, max_blocks).astype(jnp.int32)
+    scalars = jnp.concatenate([
+        jnp.stack([start_block, n_blocks, target_leaf]).astype(jnp.int32),
+        route.astype(jnp.int32)])
+    frow = lax.dynamic_slice(binsT, (route[2].astype(jnp.int32), 0), (1, n))
+
+    im_row, im_tile = _tile_index_maps(max_blocks)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(n_tiles, grid_n),
+        in_specs=[
+            pl.BlockSpec((T, block_rows), im_tile),
+            pl.BlockSpec((CHW, block_rows), im_row),
+            pl.BlockSpec((1, block_rows), im_row),
+            pl.BlockSpec((1, block_rows), im_row),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, block_rows), im_row),
+            pl.BlockSpec((T_log * num_bins, och), lambda t, i, s: (t, 0)),
+        ],
+        scratch_shapes=[pltpu.VMEM((T_log * num_bins, och), jnp.float32)],
+    )
+    with jax.named_scope("tile_walk"):
+        lid_out, hist = pl.pallas_call(
+            functools.partial(_kernel_segment_routed, num_bins=num_bins,
+                              packed4=packed4, onehot_build=onehot_build,
+                              block_axis=1),
+            out_shape=[jax.ShapeDtypeStruct((1, n), jnp.int32),
+                       jax.ShapeDtypeStruct((F_log * num_bins, och),
+                                            jnp.float32)],
+            grid_spec=grid_spec,
+            input_output_aliases={4: 0},
+            compiler_params=pltpu.CompilerParams(
+                vmem_limit_bytes=fused_vmem_limit(F, num_bins, 1, block_rows,
+                                                  packed4)),
+            interpret=interpret,
+            # the routed segment pass, tile by tile: the trace keeps its name
+            name="_histogram_segment_routed",
+        )(scalars, binsT, w8, frow, leaf_id.reshape(1, -1))
+    return lid_out[0], hist.reshape(F_log, num_bins, och)
+
+
 def histogram_segment_routed(binsT: jax.Array, w8: jax.Array,
                              leaf_id: jax.Array, start_block: jax.Array,
                              n_blocks: jax.Array, target_leaf: jax.Array,
                              route: jax.Array, num_bins: int,
                              block_rows: int = 0,
                              interpret: bool | None = None,
-                             packed4: bool = False):
+                             packed4: bool = False,
+                             feature_tile_cols: int | None = None):
     """Apply one split's route to ``leaf_id`` AND histogram ``target_leaf``
     in a single pass over the confinement interval.
 
@@ -1222,12 +1378,14 @@ def histogram_segment_routed(binsT: jax.Array, w8: jax.Array,
     keep their values via input/output aliasing); a [2, Npad] i32
     ``w8`` runs the packed-accumulator stream ([F, B, 4] output).
     Dynamic-grid only — callers needing the bucket ladder use the
-    unfused pair.
+    unfused pair.  A table wider than one accumulator holds
+    (``feature_tile``) is walked tile by tile, its bin rows padded to
+    whole tiles by the caller.
     """
-    return _histogram_segment_routed(binsT, w8, leaf_id, start_block,
-                                     n_blocks, target_leaf, route,
-                                     num_bins, block_rows, interpret,
-                                     packed4, onehot_build_mode())
+    return _histogram_segment_routed(
+        binsT, w8, leaf_id, start_block, n_blocks, target_leaf, route,
+        num_bins, block_rows, interpret, packed4, onehot_build_mode(),
+        _tile_rows(binsT, num_bins, packed4, feature_tile_cols))
 
 
 # ---------------------------------------------------------------------------
@@ -1259,8 +1417,10 @@ def lookahead_width(F_log: int, num_bins: int, block_rows: int,
     8-channel sets the accumulator holds (16 fill the 128 lanes), of
     which ``_LOOKAHEAD_SETS`` are used; 1 where only one fits or the
     wider kernel's working set does not (``fused_route_fits``): the
-    callers then keep today's kernel."""
-    K = min(frontier_width(F_log, num_bins), _LOOKAHEAD_SETS)
+    callers then keep today's kernel.  All of it is reckoned for one
+    feature tile, which is what a pass holds at a time."""
+    K = min(frontier_width(feature_tile(F_log, num_bins), num_bins),
+            _LOOKAHEAD_SETS)
     F_phys = (F_log + 1) // 2 if packed4 else F_log
     if K > 1 and not fused_route_fits(F_phys, num_bins, 1, block_rows,
                                       packed4, targets_k=K):
@@ -1287,8 +1447,18 @@ def empty_lookahead_slots(k: int) -> jax.Array:
     return jnp.tile(null_route()[None], (k, 1))
 
 
-def _lookahead_masks(slots_ref, KP: int, bins_i32, lc, first,
-                     packed4: bool):
+def _slot_rows_of_block(slots_ref, KP: int, bins_i32):
+    """[KP, chunk] i32: each slot's split-feature bin row (word 2 of the
+    slot), picked out of ``bins_i32`` ([F_phys, chunk]: the bin block the
+    pass already holds in VMEM), never from a second stream out of HBM."""
+    rows = slots_ref[KP * 2:KP * 3, :]
+    g = jnp.zeros(rows.shape, jnp.int32)
+    for r in range(bins_i32.shape[0]):
+        g = jnp.where(rows == r, bins_i32[r:r + 1], g)
+    return g
+
+
+def _lookahead_masks(slots_ref, KP: int, g, lc, first, packed4: bool):
     """[KP, chunk] 0/1 i32 memberships of one chunk, one slot a sublane.
 
     Row 0 is ``first`` ([1, chunk]: the split at hand, from the routed
@@ -1298,19 +1468,14 @@ def _lookahead_masks(slots_ref, KP: int, bins_i32, lc, first,
     (``_route_go_left``).  ``slots_ref`` ([_ROUTE_WORDS * KP, chunk] i32
     in VMEM, fetched once a call) holds word j of slot k at row KP j + k,
     already spread along the lanes, so the KP evaluations are one
-    sublane-dense tile and not KP serial [1, chunk] updates.  The slots'
-    split-feature rows come out of ``bins_i32`` ([F_phys, chunk]: the
-    bin block the pass already holds in VMEM), never from a second
-    stream out of HBM."""
+    sublane-dense tile and not KP serial [1, chunk] updates.  ``g``
+    ([KP, chunk] i32) holds the slots' split-feature bin rows
+    (``_slot_rows_of_block``, or the tiled pass's own operand)."""
     shape = (KP, lc.shape[1])
 
     def word(j):
         return slots_ref[KP * j:KP * (j + 1), :]
 
-    rows = word(2)
-    g = jnp.zeros(shape, jnp.int32)
-    for r in range(bins_i32.shape[0]):
-        g = jnp.where(rows == r, bins_i32[r:r + 1], g)
     go_left = _route_go_left(word, g, packed4)
     member = ((lc == word(0)).astype(jnp.int32)
               * (go_left == word(1)).astype(jnp.int32))
@@ -1319,14 +1484,21 @@ def _lookahead_masks(slots_ref, KP: int, bins_i32, lc, first,
 
 
 def _kernel_segment_lookahead(sref, binsT_ref, w_ref, frow_ref, lid_ref,
-                              slots_ref, lid_out_ref, out_ref, acc_ref,
-                              hi_ref, lo_ref, *, num_bins, K, packed4,
-                              onehot_build="iota"):
+                              slots_ref, *rest, num_bins, K, packed4,
+                              onehot_build="iota", block_axis=0):
     # sref: [4 + _ROUTE_WORDS] = (start_block, n_blocks, target_leaf,
     #   route, n_acc): every block of the interval is routed, the first
     #   n_acc of them accumulate (n_blocks, or 0 for a pass that only
     #   routes: the caller already holds the histogram)
-    i = pl.program_id(0)
+    # block_axis 1: grid (feature tiles, blocks) as _kernel_segment_routed
+    #   walks it; the slots' split-feature rows, which may lie in another
+    #   tile's columns, then come as an operand of their own (srows_ref,
+    #   [KP, rb] u8)
+    srows_ref = None
+    if block_axis:
+        srows_ref, *rest = rest
+    lid_out_ref, out_ref, acc_ref, hi_ref, lo_ref = rest
+    i = pl.program_id(block_axis)
     KP = slots_ref.shape[0] // _ROUTE_WORDS
 
     @pl.when(i == 0)
@@ -1344,10 +1516,15 @@ def _kernel_segment_lookahead(sref, binsT_ref, w_ref, frow_ref, lid_ref,
         def wfn(c, chunk):
             wc = w_ref[:, pl.ds(c * chunk, chunk)]          # [8, chunk]
             lc = lid_out_ref[:, pl.ds(c * chunk, chunk)]    # [1, chunk]
+            if srows_ref is None:
+                g = _slot_rows_of_block(
+                    slots_ref, KP,
+                    binsT_ref[:, pl.ds(c * chunk, chunk)].astype(jnp.int32))
+            else:
+                g = srows_ref[:, pl.ds(c * chunk, chunk)].astype(jnp.int32)
             masks = _lookahead_masks(
-                slots_ref, KP,
-                binsT_ref[:, pl.ds(c * chunk, chunk)].astype(jnp.int32),
-                lc, (lc == sref[2]).astype(jnp.int32), packed4)
+                slots_ref, KP, g, lc, (lc == sref[2]).astype(jnp.int32),
+                packed4)
             # [8K, chunk]: K masked copies of the channels, as
             # _kernel_frontier builds them
             return jnp.concatenate(
@@ -1372,14 +1549,14 @@ def _kernel_segment_lookahead(sref, binsT_ref, w_ref, frow_ref, lid_ref,
         lo_ref[:] += (h - (s - bb)) + (p - bb)
         hi_ref[:] = s
 
-    @pl.when(i == pl.num_programs(0) - 1)
+    @pl.when(i == pl.num_programs(block_axis) - 1)
     def _():
         out_ref[:] = hi_ref[:] + lo_ref[:]
 
 
 @functools.partial(jax.jit,
                    static_argnames=("num_bins", "block_rows", "interpret",
-                                    "packed4", "onehot_build"))
+                                    "packed4", "onehot_build", "tile_rows"))
 def _histogram_segment_lookahead(binsT: jax.Array, w8: jax.Array,
                                  leaf_id: jax.Array, start_block: jax.Array,
                                  n_blocks: jax.Array, target_leaf: jax.Array,
@@ -1388,7 +1565,8 @@ def _histogram_segment_lookahead(binsT: jax.Array, w8: jax.Array,
                                  block_rows: int = 0,
                                  interpret: bool | None = None,
                                  packed4: bool = False,
-                                 onehot_build: str = "iota"):
+                                 onehot_build: str = "iota",
+                                 tile_rows: int = 0):
     F, n = binsT.shape
     F_log = 2 * F if packed4 else F
     assert w8.dtype != jnp.int32, "lookahead lane sets ride the f32 stream"
@@ -1419,6 +1597,59 @@ def _histogram_segment_lookahead(binsT: jax.Array, w8: jax.Array,
     slots_op = jnp.broadcast_to(
         words[:, :, None], (_ROUTE_WORDS, KP, chunk)).reshape(
             _ROUTE_WORDS * KP, chunk)
+
+    if tile_rows:
+        # grid (feature tiles, blocks), as _histogram_segment_routed_tiled
+        # walks it, plus the slots' split-feature rows as a [KP, n] operand
+        # (row 0 for slot 0 and the padding, which match no row)
+        T = tile_rows
+        T_log = 2 * T if packed4 else T
+        # (one dynamic slice a slot, as ``frow`` is taken: a gather of
+        # rows costs a temporary half the table's size on the chip)
+        srows = jnp.concatenate(
+            [lax.dynamic_slice(binsT, (r, 0), (1, n))
+             for r in [0] + list(slots[:, 2].astype(jnp.int32))
+             + [0] * (KP - K)])
+
+        im_row, im_tile = _tile_index_maps(max_blocks)
+        tile_shape = (T_log * num_bins, K * och)
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(F // T, grid_n),
+            in_specs=[
+                pl.BlockSpec((T, block_rows), im_tile),
+                pl.BlockSpec((NUM_CHANNELS, block_rows), im_row),
+                pl.BlockSpec((1, block_rows), im_row),
+                pl.BlockSpec((1, block_rows), im_row),
+                pl.BlockSpec((_ROUTE_WORDS * KP, chunk),
+                             lambda t, i, s: (0, 0)),
+                pl.BlockSpec((KP, block_rows), im_row),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, block_rows), im_row),
+                pl.BlockSpec(tile_shape, lambda t, i, s: (t, 0)),
+            ],
+            scratch_shapes=[pltpu.VMEM(tile_shape, jnp.float32)] * 3,
+        )
+        with jax.named_scope("tile_walk"):
+            lid_out, hist = pl.pallas_call(
+                functools.partial(_kernel_segment_lookahead,
+                                  num_bins=num_bins, K=K, packed4=packed4,
+                                  onehot_build=onehot_build, block_axis=1),
+                out_shape=[jax.ShapeDtypeStruct((1, n), jnp.int32),
+                           jax.ShapeDtypeStruct((F_log * num_bins, K * och),
+                                                jnp.float32)],
+                grid_spec=grid_spec,
+                input_output_aliases={4: 0},
+                compiler_params=pltpu.CompilerParams(
+                    vmem_limit_bytes=fused_vmem_limit(
+                        F, num_bins, 1, block_rows, packed4, targets_k=K)),
+                interpret=interpret,
+                name="_histogram_segment_routed",
+            )(scalars, binsT, w8, frow, leaf_id.reshape(1, -1), slots_op,
+              srows)
+        return lid_out[0], hist.reshape(F_log, num_bins, K, och).transpose(
+            2, 0, 1, 3)
 
     def im_data(i, s):
         return (0, jnp.minimum(s[0] + i, max_blocks - 1))
@@ -1467,7 +1698,8 @@ def histogram_segment_lookahead(binsT: jax.Array, w8: jax.Array,
                                 n_acc: jax.Array, num_bins: int,
                                 block_rows: int = 0,
                                 interpret: bool | None = None,
-                                packed4: bool = False):
+                                packed4: bool = False,
+                                feature_tile_cols: int | None = None):
     """``histogram_segment_routed`` with K = 1 + len(slots) lane sets:
     returns ``(leaf_id', [K, F, B, 8])``.
 
@@ -1482,11 +1714,14 @@ def histogram_segment_lookahead(binsT: jax.Array, w8: jax.Array,
     Sums are f32 a block and an error-free (hi, lo) pair across blocks
     (``_kernel_segment_lookahead``), so lane set 0 agrees with the routed
     kernel to f32 rounding, not bit for bit; f32 channel stream only.
+    Wide tables go tile by tile as in ``histogram_segment_routed``, each
+    tile keeping its own (block sum, hi, lo) triple.
     """
     return _histogram_segment_lookahead(
         binsT, w8, leaf_id, jnp.asarray(start_block, jnp.int32),
         jnp.asarray(n_blocks, jnp.int32), target_leaf, route, slots, n_acc,
-        num_bins, block_rows, interpret, packed4, onehot_build_mode())
+        num_bins, block_rows, interpret, packed4, onehot_build_mode(),
+        _tile_rows(binsT, num_bins, packed4, feature_tile_cols))
 
 
 def _kernel_frontier_routed(sref, binsT_ref, w_ref, frows_ref, lid_ref,
@@ -1679,10 +1914,15 @@ _FUSED_VMEM_CAP = 64 * 1024 * 1024  # ceiling for the auto-sized limit
 
 @functools.lru_cache(maxsize=None)
 def _fused_vmem_est_cached(F_phys: int, num_bins: int, K: int, KT: int,
-                           block_rows: int, packed4: bool) -> int:
+                           block_rows: int, packed4: bool,
+                           lane_padded: bool = False) -> int:
     F_log = 2 * F_phys if packed4 else F_phys
     streams = block_rows * (F_phys + K + 2 * NUM_CHANNELS + 8)
-    out = F_log * num_bins * KT * NUM_CHANNELS * 4
+    # a feature tile is sized by its accumulator as VMEM holds it, the
+    # channel lanes padded to 128 (``supported``)
+    width = max(KT * NUM_CHANNELS, _LANES) if lane_padded else (
+        KT * NUM_CHANNELS)
+    out = F_log * num_bins * width * 4
     return 2 * (3 * streams + 3 * out)
 
 
@@ -1703,8 +1943,14 @@ def _fused_vmem_est(F_phys: int, num_bins: int, K: int = 1,
     F_log = 2 * F_phys if packed4 else F_phys
     if block_rows <= 0:
         block_rows = pick_block_rows(F_log, num_bins)
+    # a pass holds one feature tile's rows at a time (all of them where
+    # the table is taken whole)
+    tile = feature_tile(F_log, num_bins)
+    tiled = tile < F_log
+    if tiled:
+        F_phys = tile // 2 if packed4 else tile
     return _fused_vmem_est_cached(F_phys, num_bins, K, targets_k or K,
-                                  block_rows, bool(packed4))
+                                  block_rows, bool(packed4), tiled)
 
 
 def fused_vmem_limit(F_phys: int, num_bins: int, K: int = 1,

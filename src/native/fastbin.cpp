@@ -149,6 +149,18 @@ namespace {
 // bound sets (u16 datasets) keep a branchless binary search.
 constexpr int64_t kQChunk = 2048;
 constexpr int64_t kLinearMax = 128;
+// Rows of a chunk: a chunk's input rows are walked once a column, so
+// they have to stay in cache between columns.  2048 rows of 28 floats
+// are 229 KB; 2048 rows of 2000 floats would be 16 MB and every strided
+// read a miss (72 s for 1.1M x 2000, builder's chip run, PR 30), so wide
+// tables take fewer rows a chunk, the same bytes.
+constexpr int64_t kQChunkBytes = 256 * 1024;
+
+inline int64_t chunk_rows(int64_t f_total, int64_t value_bytes) {
+    const int64_t fit = kQChunkBytes / std::max<int64_t>(
+        1, f_total * value_bytes);
+    return std::max<int64_t>(32, std::min(kQChunk, fit));
+}
 
 template <typename T, typename OutT>
 void quantize_rows(const T* data, int64_t n, int64_t f_total,
@@ -157,8 +169,9 @@ void quantize_rows(const T* data, int64_t n, int64_t f_total,
                    const int32_t* missing_type, const int32_t* num_bin,
                    OutT* out) {
     double buf[kQChunk];
-    for (int64_t c0 = 0; c0 < n; c0 += kQChunk) {
-        int64_t c = std::min(kQChunk, n - c0);
+    const int64_t rows = chunk_rows(f_total, sizeof(T));
+    for (int64_t c0 = 0; c0 < n; c0 += rows) {
+        int64_t c = std::min(rows, n - c0);
         for (int64_t j = 0; j < n_used; ++j) {
             const T* col = data + c0 * f_total + feat_idx[j];
             const double* ub = bounds_flat + bounds_off[j];
@@ -219,8 +232,9 @@ void quantize_rows_f32_thr(const float* data, int64_t n, int64_t f_total,
                            const int32_t* missing_type,
                            const int32_t* num_bin, uint8_t* out) {
     float buf[kQChunk];
-    for (int64_t c0 = 0; c0 < n; c0 += kQChunk) {
-        int64_t c = std::min(kQChunk, n - c0);
+    const int64_t rows = chunk_rows(f_total, sizeof(float));
+    for (int64_t c0 = 0; c0 < n; c0 += rows) {
+        int64_t c = std::min(rows, n - c0);
         for (int64_t j = 0; j < n_used; ++j) {
             const float* col = data + c0 * f_total + feat_idx[j];
             const float* thr = thr_flat + bounds_off[j];

@@ -104,19 +104,21 @@ def _all(sh):
         sh.bins, sh.w8)
 
 
-def _segment_routed(sh):
+def _segment_routed(sh, tile_rows=0):
     return _compile(
         lambda b, w, l, s0, nb, t, r: ph._histogram_segment_routed(
-            b, w, l, s0, nb, t, r, sh.B, sh.rb, interpret=False),
+            b, w, l, s0, nb, t, r, sh.B, sh.rb, interpret=False,
+            tile_rows=tile_rows),
         sh.bins, sh.w8, sh.leaf_id, sh.i32, sh.i32, sh.i32, sh.route)
 
 
-def _segment_lookahead(sh):
+def _segment_lookahead(sh, tile_rows=0):
     K = ph.lookahead_width(sh.F, sh.B, sh.rb, False)
     assert K >= 2
     return _compile(
         lambda b, w, l, s0, nb, t, r, sl, na: ph._histogram_segment_lookahead(
-            b, w, l, s0, nb, t, r, sl, na, sh.B, sh.rb, interpret=False),
+            b, w, l, s0, nb, t, r, sl, na, sh.B, sh.rb, interpret=False,
+            tile_rows=tile_rows),
         sh.bins, sh.w8, sh.leaf_id, sh.i32, sh.i32, sh.i32, sh.route,
         sh.s((K - 1, ph._ROUTE_WORDS), jnp.int32), sh.i32)
 
@@ -168,16 +170,42 @@ def test_supported_agrees_with_compiler(one_chip, F, B):
     refuses, and the widest 256-bin shape `supported()` admits: it sizes
     the accumulator as Mosaic lays it out (lanes padded to 128), so it
     says no where the kernel cannot compile and the XLA grower is
-    selected instead of a crash on the chip."""
+    selected instead of a crash on the chip.  (Feature tiles are admitted
+    at 64 bins alone: below.)"""
     sh = _Shapes(one_chip, F, B, 2_270_000)
     try:
         _segment_dyn(sh)
-        _all(sh)
+        _segment_routed(sh)
         compiles = True
     except Exception as e:  # noqa: BLE001 — the compiler's refusal
         assert "vmem" in str(e).lower(), e
         compiles = False
     assert ph.supported(F, B, jnp.uint8) == compiles
+
+
+def _tiled(kernel):
+    def build(sh):
+        return kernel(sh, tile_rows=ph.feature_tile(sh.F, sh.B))
+    build.__name__ = kernel.__name__
+    return build
+
+
+@pytest.mark.parametrize("F,B,rows,tiles", [(2000, 64, 1_100_000, 16)],
+                         ids=["epsilon"])
+@pytest.mark.parametrize("kernel", [_segment_routed, _segment_lookahead],
+                         ids=lambda f: f.__name__.lstrip("_"))
+def test_tiled_kernel_compiles_at_wide_shapes(one_chip, kernel, F, B, rows,
+                                              tiles):
+    """The benchmark's 2000 x 64 table (16 tiles of 128 columns, blocks of
+    8192 rows): the routed and the eight-lane-set kernels compile tile by
+    tile within the VMEM limit they ask for, over a table padded to whole
+    tiles."""
+    assert ph.feature_tiles(F, B) == tiles
+    tile = ph.feature_tile(F, B)
+    sh = _Shapes(one_chip, tiles * tile, B, rows)
+    assert sh.rb == ph.pick_block_rows(F, B, rows)
+    assert ph.lookahead_width(F, B, sh.rb, False) == 8
+    assert "tpu_custom_call" in _tiled(kernel)(sh).as_text()
 
 
 @pytest.mark.parametrize("F,B,rows", SUITE_WIDTHS,
