@@ -497,6 +497,15 @@ def _unpack_w8_words(words):
         [ch6, jnp.zeros((NUM_CHANNELS - 6, ch6.shape[1]), jnp.bfloat16)])
 
 
+def _pinned_row(table, i):
+    """``(table[i], table)`` for a per-leaf table in the split loop's
+    carry, with the read pinned ahead of every write of the table: the
+    row is materialised here and the writes go to the barrier's table
+    (why: ``grow``'s comment on the epoch loops)."""
+    return lax.optimization_barrier(
+        (lax.dynamic_index_in_dim(table, i, 0, keepdims=False), table))
+
+
 def _lookahead_pending(st: _SegState, leaf, lo, hi) -> jax.Array:
     """[L] f32: the cached gain of every leaf whose smaller-child
     histogram a pass over blocks [lo, hi) for ``leaf``'s split may fill,
@@ -746,6 +755,7 @@ def make_grow_tree_segment(num_bins: int, params: GrowerParams,
             histogram is scanned, so the side picked here (do_split's own
             ``Cl <= Cr``) is the side do_split will want, and the rows
             are a function of the data alone."""
+            look_row, look_hist = _pinned_row(st.look_hist, leaf)
             hit = st.look_ok[leaf]
             pending = jnp.where(hit, NEG_INF,
                                 _lookahead_pending(st, leaf, lo, hi))
@@ -762,10 +772,10 @@ def make_grow_tree_segment(num_bins: int, params: GrowerParams,
                 st.binsT, st.w8, st.leaf_id, lo, hi - lo, smaller, route,
                 slots, blk, B, rb, packed4=p.packed4)
             hists = unpack_hist(outs[:, :G_cols])
-            hist_small = jnp.where(hit, st.look_hist[leaf], hists[0])
+            hist_small = jnp.where(hit, look_row, hists[0])
             put = jnp.where(live, cand, L)          # L: dropped
             st = st._replace(
-                look_hist=st.look_hist.at[put].set(hists[1:], mode="drop"),
+                look_hist=look_hist.at[put].set(hists[1:], mode="drop"),
                 # the entry of ``leaf`` was its own split's and is spent:
                 # the child that keeps the id starts without one
                 look_ok=(st.look_ok.at[put].set(True, mode="drop")
@@ -846,6 +856,7 @@ def make_grow_tree_segment(num_bins: int, params: GrowerParams,
             if p.use_cegb_coupled:
                 st = st._replace(feat_used=st.feat_used.at[f].set(1.0))
 
+            leaf_hist = st.leaf_hist
             if comm.no_subtract:
                 # voting-parallel: each call's election masks differ, so
                 # parent-minus-smaller is invalid (CommHooks doc) — build
@@ -865,7 +876,7 @@ def make_grow_tree_segment(num_bins: int, params: GrowerParams,
                 # a pass that only routed accumulated over no grid step
                 grid_blk = (jnp.where(blk > 0, grid_of(blk), 0)
                             if look_k > 1 else grid_of(blk))
-                hist_parent = st.leaf_hist[leaf]
+                hist_parent, leaf_hist = _pinned_row(leaf_hist, leaf)
                 hist_large = hist_parent - hist_small
                 hist_left = jnp.where(smaller_is_left, hist_small,
                                       hist_large)
@@ -879,7 +890,7 @@ def make_grow_tree_segment(num_bins: int, params: GrowerParams,
             st = st._replace(scanned_since=st.scanned_since + blk_u,
                              scanned_total=st.scanned_total + blk,
                              grid_total=st.grid_total + grid_blk)
-            leaf_hist = (st.leaf_hist.at[leaf].set(hist_left)
+            leaf_hist = (leaf_hist.at[leaf].set(hist_left)
                          .at[new_leaf].set(hist_right))
 
             depth_child = st.tree.leaf_depth[leaf] + 1
@@ -956,6 +967,14 @@ def make_grow_tree_segment(num_bins: int, params: GrowerParams,
         # round-4 v5e profiler trace.  With the split work in
         # the loop PREDICATE instead of a cond, nothing is copied; the
         # compact cond now executes once per epoch (~#compactions/tree).
+        # The same class of copy, inside do_split: a row read from a table
+        # in the split loop's carry (leaf_hist[leaf], look_hist[leaf]) is
+        # taken before any write of that table and pinned (_pinned_row).
+        # A read the compiler can sink into a writer costs two copies of
+        # the table a split, and a relayout each way where the scatter
+        # keeps its own layout: four copies of 392 MB a split at 2000
+        # columns x 64 bins, 1.77 s of a 5.35 s iteration (ledger, PR 30;
+        # tests/test_tpu_compile.py holds the loop to none).
         limit_blocks = min(max(1, int(COMPACT_WASTE * max_blocks)),
                            2**31 - 1)   # compared against an i32 counter
 

@@ -198,15 +198,31 @@ def _parse(bst):
     return reference.parse_model(bst.model_to_string())
 
 
-def test_wide_model_is_the_onehot_growers(tiles_of_96):
+@pytest.mark.parametrize("against", ["onehot", "plain_reads"])
+def test_wide_model_is_the_onehot_growers(tiles_of_96, against, monkeypatch):
     """300 columns x 64 bins x 31 leaves over 4 feature tiles and 6 row
     blocks: the segment grower's trees are `models/grower.py`'s, split for
     split.  Leaf values agree to 5e-5, not bit for bit: the two growers sum
     a leaf's gradients in different orders (bf16 hi+lo channels through the
     MXU a block at a time, against one float32 one-hot product), which
     moves -G/H in its last float32 digits and, from the second tree on,
-    the gradients with it."""
+    the gradients with it.  ``plain_reads``: against the segment grower
+    that reads its two per-leaf table rows unpinned, the model is the same
+    text: `grower_seg._pinned_row` moves bytes, no value."""
     X, y = _wide_rows()
+    if against == "plain_reads":
+        texts = []
+        for plain in (False, True):
+            if plain:
+                monkeypatch.setattr(grower_seg, "_pinned_row",
+                                    lambda table, i: (table[i], table))
+            p = dict(WIDE, tpu_histogram_backend="pallas")
+            bst = lgb.train(p, lgb.Dataset(X, y, params=dict(p)),
+                            num_boost_round=4, verbose_eval=False)
+            assert bst.gbdt._use_segment and bst.gbdt._bins_row_multiple == 96
+            texts.append(bst.model_to_string())
+        assert texts[0] == texts[1]
+        return
     TELEMETRY.reset()
     models = {}
     for backend in ("pallas", "onehot"):

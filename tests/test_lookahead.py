@@ -117,13 +117,18 @@ class _Case:
         return (jax.tree_util.tree_map(np.asarray, tree), np.asarray(lid),
                 np.asarray(stats))
 
-    def grower(self, mp, variant, waste=None):
+    def grower(self, mp, variant, waste=None, plain_reads=False):
         """A grower of this case's shape, traced under the patches:
         ``look`` the program as built, ``nofill`` the same program whose
         slots are never filled, ``k1`` one lane set (the program before
-        lookahead).  ``waste`` replaces COMPACT_WASTE (1e6: never)."""
+        lookahead).  ``waste`` replaces COMPACT_WASTE (1e6: never);
+        ``plain_reads`` takes ``leaf_hist[leaf]`` and ``look_hist[leaf]``
+        by plain indexing where they are used, unpinned (the program
+        before ``_pinned_row``)."""
         if waste is not None:
             mp.setattr(gs, "COMPACT_WASTE", waste)
+        if plain_reads:
+            mp.setattr(gs, "_pinned_row", lambda table, i: (table[i], table))
         if variant == "nofill":
             mp.setattr(gs, "_lookahead_pending",
                        lambda st, leaf, lo, hi: jnp.full(
@@ -173,18 +178,25 @@ def _case(cases, shape):
     return cases[shape]
 
 
-@pytest.mark.parametrize("shape", SHAPES)
-def test_no_compaction_bit_identical_to_unfilled(cases, shape, monkeypatch):
+@pytest.mark.parametrize("shape,against",
+                         [(s, "nofill") for s in SHAPES]
+                         + [("binary", "plain_reads"),
+                            ("chain", "plain_reads")])
+def test_no_compaction_bit_identical_to_unfilled(cases, shape, against,
+                                                 monkeypatch):
     """(a), (d): compaction disabled, more open leaves than lane sets:
     trees and leaf ids bit for bit those of the program that never fills a
     slot, on every seed; hits + scans = splits, filled >= hits.  A child
     that reuses its parent's id and took the parent's entry, a wrong
-    side, a wrong membership: each would show here."""
+    side, a wrong membership: each would show here.  ``plain_reads``: and
+    bit for bit, counters too, those of the same program with the two
+    table rows read unpinned: the pin moves bytes, no value."""
     case = _case(cases, shape)
     with monkeypatch.context() as mp:
         look = case.grower(mp, "look", waste=1e6)
     with monkeypatch.context() as mp:
-        ref = case.grower(mp, "nofill", waste=1e6)
+        ref = (case.grower(mp, "nofill", waste=1e6) if against == "nofill"
+               else case.grower(mp, "look", waste=1e6, plain_reads=True))
     total_hits = 0
     for seed in SEEDS:
         a, b = case.grow(look, seed), case.grow(ref, seed)
@@ -192,7 +204,10 @@ def test_no_compaction_bit_identical_to_unfilled(cases, shape, monkeypatch):
         sa, sb = a[2], b[2]
         assert sa[SORTS] == 0 and sb[SORTS] == 0
         assert sa[SPLITS] == sb[SPLITS] == int(a[0].num_leaves) - 1
-        assert sb[HITS] == sb[FILLED] == sb[ROUTE_ONLY] == 0
+        if against == "nofill":
+            assert sb[HITS] == sb[FILLED] == sb[ROUTE_ONLY] == 0
+        else:
+            np.testing.assert_array_equal(sa, sb)
         # every pass covers all blocks here: the root's, then one a scan
         scans = sa[SCANNED] // sa[MAXB] - 1
         assert sa[HITS] + scans == sa[SPLITS]
@@ -233,15 +248,76 @@ def test_compaction_same_tree(cases, shape, waste, monkeypatch):
         assert sorts > 0, "no compaction fell between fill and use"
 
 
-def test_one_lane_set_builds_the_program_of_before(cases, monkeypatch):
+@pytest.mark.parametrize("waste", [1e6, 0.5], ids=["never", "often"])
+def test_hit_reads_and_refills_against_a_numpy_follower(cases, waste,
+                                                        monkeypatch):
+    """One loop body holds the read of ``look_hist[leaf]`` (used when the
+    leaf is a hit) and the scatter of the pass's lookahead rows (a miss
+    refills other leaves' rows; a hit accumulates nothing, so its scatter
+    drops every row).  With channels that float32 sums exactly, every
+    leaf's and node's hessian sum and count and every leaf's value are
+    those numpy takes from the returned partition: a row read after the
+    scatter wrote it, a stale row or another leaf's would give children
+    whose recorded sums are not their rows'."""
+    case = _case(cases, "binary")
+    with monkeypatch.context() as mp:
+        look = case.grower(mp, "look", waste=waste)
+    rng = np.random.RandomState(7)
+    g = np.zeros(case.npad, np.float32)
+    h = np.zeros(case.npad, np.float32)
+    m = np.zeros(case.npad, np.float32)
+    g[:case.n] = np.clip(np.round(4 * (0.5 - case.y + 0.4 * rng.normal(
+        size=case.n))), -8, 8) / 4
+    h[:case.n] = rng.choice([0.5, 1.0], case.n)
+    m[:case.n] = 1.0
+    tree, lid, stats = look(case.bins, jnp.asarray(g), jnp.asarray(h),
+                            jnp.asarray(m), case.bst.fmeta, case.fmask,
+                            jax.random.PRNGKey(0))
+    tree = jax.tree_util.tree_map(np.asarray, tree)
+    lid, stats = np.asarray(lid)[:case.n], np.asarray(stats)
+    nl = int(tree.num_leaves)
+    assert stats[SPLITS] == nl - 1 > 16
+    assert 0 < stats[HITS] < stats[SPLITS] and stats[FILLED] > stats[HITS]
+    assert (stats[SORTS] > 0) == (waste < 1e6)
+    G = np.bincount(lid, weights=g[:case.n], minlength=nl)
+    H = np.bincount(lid, weights=h[:case.n], minlength=nl)
+    C = np.bincount(lid, minlength=nl)
+    np.testing.assert_array_equal(tree.leaf_weight[:nl], H.astype(np.float32))
+    np.testing.assert_array_equal(tree.leaf_count[:nl], C)
+    np.testing.assert_allclose(tree.leaf_value[:nl], -G / H, rtol=1e-6)
+
+    def sums(child):
+        if child < 0:
+            return H[~child], C[~child]
+        (hl, cl), (hr, cr) = (sums(tree.left_child[child]),
+                              sums(tree.right_child[child]))
+        assert tree.internal_weight[child] == np.float32(hl + hr)
+        assert tree.internal_count[child] == cl + cr
+        return hl + hr, cl + cr
+
+    assert sums(0) == (H.sum(), case.n)
+
+
+@pytest.mark.parametrize("plain_reads", [False, True],
+                         ids=["pinned", "plain_reads"])
+def test_one_lane_set_builds_the_program_of_before(cases, plain_reads,
+                                                   monkeypatch):
     """A shape where one lane set fills the budget runs no lookahead: its
-    counters stay 0 and the kernel is the routed one."""
+    counters stay 0 and the kernel is the routed one.  Its ``look_hist``
+    has no rows and is never read; ``leaf_hist[leaf]`` pinned or read
+    plainly, the bits are the same."""
     case = _case(cases, "binary")
     with monkeypatch.context() as mp:
         k1 = case.grower(mp, "k1")
-    _, _, stats = case.grow(k1, 3)
-    assert stats[SPLITS] > 0
-    assert stats[HITS] == stats[FILLED] == stats[ROUTE_ONLY] == 0
+    a = case.grow(k1, 3)
+    assert a[2][SPLITS] > 0
+    assert a[2][HITS] == a[2][FILLED] == a[2][ROUTE_ONLY] == 0
+    if plain_reads:
+        with monkeypatch.context() as mp:
+            plain = case.grower(mp, "k1", plain_reads=True)
+        b = case.grow(plain, 3)
+        _assert_same_bits(a, b)
+        np.testing.assert_array_equal(a[2], b[2])
 
 
 def test_packed_stream_runs_no_lookahead(cases, monkeypatch):

@@ -11,6 +11,8 @@ happen while any module is imported (the `on-chip-measurement` guide,
 section 2).
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -215,3 +217,106 @@ def test_lookahead_kernel_compiles_at_suite_widths(one_chip, F, B, rows):
     with that many compiles within the VMEM limit it asks for."""
     assert "tpu_custom_call" in _segment_lookahead(
         _Shapes(one_chip, F, B, rows)).as_text()
+
+
+def _computations(text):
+    """{name: instruction lines} of an optimized HLO module's text."""
+    comps, cur = {}, None
+    for line in text.splitlines():
+        m = re.match(r"(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$", line)
+        if m:
+            cur = comps.setdefault(m.group(1), [])
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None:
+            cur.append(line)
+    return comps
+
+
+_INSTR = re.compile(r"\s*(?:ROOT )?%([\w.\-]+) = (.*?) ([\w\-]+)\(")
+
+
+def _split_loop_findings(text, table):
+    """(whole-table copies, carry layouts) of the split loop in a compiled
+    grower's text: the `while` body that holds the segment kernel's call
+    and is itself inside a `while` body (the epoch loop).  A copy is an
+    instruction there, or inside a fusion called from there, whose opcode
+    is `copy` or `transpose` and whose result is `table` (an `f32[L,G,B,3]`
+    type without layout); a scatter that cannot update in place shows as
+    the `copy` the compiler inserts in front of it.  The layouts are those
+    of the tables in the body's parameter and in its root tuple."""
+    comps = _computations(text)
+    body_of = {}
+    for name, lines in comps.items():
+        for line in lines:
+            m = re.search(r" while\(.*body=%?([\w.\-]+)", line)
+            if m:
+                body_of[m.group(1)] = name
+    loops = [b for b, parent in body_of.items() if parent in body_of
+             and any("tpu_custom_call" in line for line in comps[b])]
+    assert len(loops) == 1, loops
+    found = []
+
+    def walk(lines, via=""):
+        for line in lines:
+            m = _INSTR.match(line)
+            if not m:
+                continue
+            name, result, op = m.groups()
+            if table not in result:
+                continue
+            if op in ("copy", "transpose"):
+                found.append(via + line.strip()[:160])
+            elif op == "fusion":
+                called = re.search(r"calls=%?([\w.\-]+)", line).group(1)
+                walk(comps[called], via=f"{name} > ")
+
+    lines = comps[loops[0]]
+    walk(lines)
+    carried = re.compile(re.escape(table) + r"\{[\d,]+")
+    layouts = [carried.findall(line) for line in lines
+               if " parameter(0)" in line or line.lstrip().startswith("ROOT")]
+    return found, layouts
+
+
+@pytest.mark.parametrize("F,rows", [(2000, 65_536), (28, 262_144)],
+                         ids=["epsilon", "higgs"])
+def test_split_loop_copies_no_histogram_table(one_chip, monkeypatch, F, rows):
+    """The strict segment grower at 255 leaves x 64 bins, 16 feature tiles
+    and one: the optimized split loop holds no copy, transpose or
+    scatter-with-copy of a whole per-leaf histogram table (392 MB each at
+    2000 columns; rows cut, the tables do not depend on them), and the
+    tables keep one layout from the loop's parameter to its root tuple.
+    Before the reads of `leaf_hist[leaf]` and `look_hist[leaf]` were pinned
+    (`grower_seg._pinned_row`) the 2000-column loop held four such copies
+    and the 28-column one two: a third of `epsilon63-train`'s iteration.
+    The compaction outside the loop is steered onto its gather path, whose
+    two-operand sort compiles in seconds where the 12-operand one takes
+    minutes; the split loop's body is the same either way."""
+    from lightgbm_tpu.models import grower_seg
+    from lightgbm_tpu.models.grower import GrowerParams
+    from lightgbm_tpu.ops.split import FeatureMeta, SplitParams
+    monkeypatch.setattr(ph, "_interpret_default", lambda: False)
+    monkeypatch.setenv("LIGHTGBM_TPU_FUSED_ROUTE", "1")
+    monkeypatch.setattr(grower_seg, "_MAX_SORT_OPERANDS", 0)
+    B, L = 64, 255
+    tiles = ph.feature_tiles(F, B)
+    sh = _Shapes(one_chip, tiles * ph.feature_tile(F, B) if tiles > 1 else F,
+                 B, rows)
+    assert sh.rb == ph.pick_block_rows(F, B)      # the cells' block rows
+    params = GrowerParams(
+        num_leaves=L, num_columns=F, hist_backend="pallas",
+        split=SplitParams(min_sum_hessian_in_leaf=100.0, has_cat=False))
+    grow = grower_seg.make_grow_tree_segment(B, params, sh.rb,
+                                             wrap=lambda g: g)
+    per_row = sh.s((sh.n,), jnp.float32)
+    per_col = FeatureMeta(*(sh.s((F,), dt) for dt in (
+        jnp.int32, jnp.int32, jnp.int32, jnp.bool_, jnp.int32, jnp.float32)))
+    text = _compile(grow, sh.bins, per_row, per_row, per_row, per_col,
+                    sh.s((F,), jnp.float32), sh.s((2,), jnp.uint32)).as_text()
+    assert ph.fused_route_decisions["segment"]
+    copies, layouts = _split_loop_findings(text, f"f32[{L},{F},{B},3]")
+    assert not copies, "\n".join(copies)
+    # leaf_hist and look_hist, at the parameter and at the root
+    assert len(layouts) == 2 and len(layouts[0]) == 2, layouts
+    assert layouts[0] == layouts[1], layouts
