@@ -186,9 +186,8 @@ def run_child(config: str, n_rows: int, warmup: int, measure: int) -> None:
               "tpu_boost_chunk": int(os.environ.get(
                   "LIGHTGBM_TPU_BOOST_CHUNK", "0"))}
     params.update(extra.get("params", {}))
-    # fused-K ladder hook: pins the frontier batch width, same knob
-    # perf_probe.py exposes, so K∈{4,8,16} A/B cells measure the width
-    # they name
+    # pins the frontier batch width, so that K∈{4,8,16} A/B cells
+    # measure the width they name
     fk = int(os.environ.get("LIGHTGBM_TPU_FRONTIER_K", "0") or 0)
     if fk > 0:
         params["tpu_frontier_width"] = fk

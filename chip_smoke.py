@@ -249,15 +249,13 @@ def phase_train(run: Run):
 
 
 def phase_self_checks(run: Run) -> None:
-    """Every kernel variant's self-check, non-interpret on the chip."""
-    from lightgbm_tpu.ops.pallas_histogram import (DEFAULT_PATH_CHECKS,
-                                                   kernel_self_checks)
+    """The default path's kernel self-checks, non-interpret on the chip."""
+    from lightgbm_tpu.ops.pallas_histogram import kernel_self_checks
     results = kernel_self_checks()
     for name, err in results.items():
-        kind = "default path" if name in DEFAULT_PATH_CHECKS else "opt-in"
-        say(f"kernel self-check: {'ok' if err is None else 'FAIL'} {name} "
-            f"[{kind}]" + ("" if err is None else f" ({err})"))
-    bad = [n for n in DEFAULT_PATH_CHECKS if results[n] is not None]
+        say(f"kernel self-check: {'ok' if err is None else 'FAIL'} {name}"
+            + ("" if err is None else f" ({err})"))
+    bad = [n for n, err in results.items() if err is not None]
     if bad:
         raise AssertionError(f"default-path kernel self-checks failed: "
                              f"{bad}")

@@ -34,7 +34,7 @@ from ..utils.phase import GLOBAL_TIMER as _PHASES
 from ..utils.telemetry import HEALTH, TELEMETRY
 from .grower import (GrowerParams, _pack_tree_device, fetch_tree_arrays,
                      fetch_tree_chunk, make_grow_tree, unpack_tree_buffers)
-from .grower_seg import (SEG_STATS_SLOTS, print_seg_stats,
+from .grower_seg import (print_seg_stats, seg_stats_columns,
                          seg_stats_enabled)
 from .tree import Tree
 
@@ -83,42 +83,30 @@ def _record_seg_stats(rows: np.ndarray, trees: int,
                       block_rows: int) -> None:
     """A grower's counters, [*, SEG_STATS_SLOTS] over ``trees`` trees
     (one row a tree, or one a device and tree under the data-parallel
-    wrappers), into the ``seg/*`` and ``hist/*`` telemetry counters."""
-    rows = rows.reshape(-1, SEG_STATS_SLOTS)
-    TELEMETRY.counter_add("seg/scanned_blocks", int(rows[:, 0].sum()))
-    TELEMETRY.counter_add("seg/compactions", int(rows[:, 1].sum()))
-    TELEMETRY.counter_add("seg/grid_steps", int(rows[:, 2].sum()))
+    wrappers), into the ``seg/*`` telemetry counters and gauges."""
+    c = seg_stats_columns(rows)
+    TELEMETRY.counter_add("seg/scanned_blocks", int(c.scanned_blocks.sum()))
+    TELEMETRY.counter_add("seg/compactions", int(c.compactions.sum()))
+    TELEMETRY.counter_add("seg/grid_steps", int(c.grid_steps.sum()))
     # what turns blocks into rows and totals into per-tree figures
     TELEMETRY.counter_add("seg/trees", int(trees))
     TELEMETRY.gauge_set("seg/block_rows", int(block_rows))
     # the strict grower's shape facts: feature tiles a pass walks (1: the
     # table whole) and the bytes of its per-leaf histogram tables
-    if rows[:, 13].max():
-        TELEMETRY.gauge_set("seg/feature_tiles", int(rows[:, 13].max()))
+    if c.feature_tiles.max():
+        TELEMETRY.gauge_set("seg/feature_tiles", int(c.feature_tiles.max()))
         TELEMETRY.gauge_set("seg/leaf_hist_bytes",
-                            1024 * int(rows[:, 14].max()))
-    # quantization / staging counters stay 0 on paths that never
-    # quantize or stage — record only live events so trace_report's
-    # hist section renders n/a instead of misleading zero rates
-    if rows[:, 5].sum():
-        TELEMETRY.counter_add("hist/fused_k_rounds",
-                              int(rows[:, 5].sum()))
-    if rows[:, 6].sum():
-        TELEMETRY.counter_add("hist/quant_rescales", len(rows))
-        TELEMETRY.counter_add("hist/quant_clips", int(rows[:, 6].sum()))
-    if rows[:, 8].sum():
-        TELEMETRY.counter_add("hist/stage_hits", int(rows[:, 7].sum()))
-        TELEMETRY.counter_add("hist/stage_lookups",
-                              int(rows[:, 8].sum()))
+                            1024 * int(c.leaf_hist_kib.max()))
     # the strict grower's splits and its lookahead lane sets (0 on the
     # paths that run none: a hit share of 0, not a missing one)
-    if rows[:, 9].sum():
-        TELEMETRY.counter_add("seg/splits", int(rows[:, 9].sum()))
-        TELEMETRY.counter_add("seg/lookahead_hits", int(rows[:, 10].sum()))
+    if c.splits.sum():
+        TELEMETRY.counter_add("seg/splits", int(c.splits.sum()))
+        TELEMETRY.counter_add("seg/lookahead_hits",
+                              int(c.lookahead_hits.sum()))
         TELEMETRY.counter_add("seg/lookahead_filled",
-                              int(rows[:, 11].sum()))
+                              int(c.lookahead_filled.sum()))
         TELEMETRY.counter_add("seg/route_only_blocks",
-                              int(rows[:, 12].sum()))
+                              int(c.route_only_blocks.sum()))
 
 
 def _stack_seg_stats(stats_l):

@@ -32,11 +32,11 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..core.binning import MISSING_NAN, MISSING_ZERO
+from ..core.binning import MISSING_ZERO
 from ..ops.histogram import histogram_chunked
 from ..ops.split import (NEG_INF, FeatureMeta, SplitParams, best_split,
                          expand_group_hist, leaf_gain, leaf_output,
-                         reconstruct_feature_column)
+                         reconstruct_feature_column, routed_left)
 
 
 class GrowerParams(NamedTuple):
@@ -224,24 +224,6 @@ class _GrowState(NamedTuple):
     tree: TreeArrays
 
 
-def _bit_test(bitset_row: jax.Array, idx: jax.Array) -> jax.Array:
-    """bitset_row u32[8], idx i32 -> bool."""
-    word = bitset_row[idx // 32]
-    return ((word >> (idx % 32).astype(jnp.uint32)) & 1).astype(bool)
-
-
-def routed_left(fcol, threshold, default_left, is_cat, cat_bitset,
-                missing_type, default_bin, num_bin):
-    """Which side each row goes (numerical <=threshold with missing routing,
-    categorical bitset membership)."""
-    fcol = fcol.astype(jnp.int32)
-    is_missing = (((missing_type == MISSING_ZERO) & (fcol == default_bin))
-                  | ((missing_type == MISSING_NAN) & (fcol == num_bin - 1)))
-    num_left = jnp.where(is_missing, default_left, fcol <= threshold)
-    cat_left = _bit_test(cat_bitset, jnp.clip(fcol, 0, 255))
-    return jnp.where(is_cat, cat_left, num_left)
-
-
 def _node_feature_mask(base_mask, key, step, p: GrowerParams):
     if p.feature_fraction_bynode >= 1.0:
         return base_mask
@@ -368,20 +350,6 @@ def make_grow_tree(num_bins: int, params: GrowerParams,
     L = p.num_leaves
     B = num_bins
     sp = p.split
-    # packed int16 accumulator stream: resolved ONCE at build time (env
-    # inside the jitted grow would poison the jit cache) — self-check
-    # gated with automatic fallback to the f32 channel path.  The plain
-    # grower quantizes per LEAF inside leaf_histogram_pallas, so the
-    # rescale scales are naturally per-leaf.
-    packed_acc = False
-    qbits = 8
-    if p.feature_major:
-        from ..ops.pallas_histogram import (packed_acc_bits,
-                                            packed_acc_decisions,
-                                            packed_acc_enabled)
-        packed_acc = packed_acc_enabled()
-        qbits = packed_acc_bits()
-        packed_acc_decisions["plain"] = packed_acc
 
     def hist_of(bins, grad, hess, member, G, H, C, fmeta):
         hist_bins = bins
@@ -398,8 +366,7 @@ def make_grow_tree(num_bins: int, params: GrowerParams,
         if p.feature_major:
             from ..ops.pallas_histogram import leaf_histogram_pallas
             out = leaf_histogram_pallas(hist_bins, grad, hess, member, B,
-                                        p.row_chunk, packed4=p.packed4,
-                                        packed_acc=packed_acc, bits=qbits)
+                                        p.row_chunk, packed4=p.packed4)
             if p.num_columns:
                 out = out[: p.num_columns]
         else:

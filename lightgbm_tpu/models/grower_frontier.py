@@ -35,109 +35,26 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-import os as _os
-
-from ..ops.pallas_histogram import (_segment_buckets, frontier_width,
-                                    fused_packed_optin,
+from ..ops.pallas_histogram import (frontier_width,
                                     fused_route_decisions,
-                                    fused_route_policy, gate_self_check,
+                                    fused_route_policy,
                                     histogram_frontier,
-                                    histogram_frontier_fusedk,
                                     histogram_frontier_routed, null_route,
                                     pack_channels, pack_route,
-                                    packed_acc_bits, packed_acc_decisions,
-                                    packed_acc_enabled,
-                                    quantize_pack_channels,
-                                    segment_grid_size, unpack_hist,
-                                    unpack_hist_packed)
+                                    route_kernel_available, unpack_hist)
 from ..ops.split import (NEG_INF, FeatureMeta, best_split,
                          expand_group_hist)
-from .grower import (GrowerParams, _node_feature_mask, mono_handoff)
-from .grower_seg import (COMPACT_WASTE, SEG_STATS_SLOTS, _COMPACT_MUT,
-                         _SegState, _unpermute, apply_route, compact_state,
-                         cond_narrow, fresh_state, stripe_histogram)
-
-# build-time decision, keyed "frontier" — benches read whether the
-# round-carry stage actually ran (env gate + self-check + serial-only
-# make the bare env value misleading)
-hist_stage_decisions: dict = {}
-
-_HIST_STAGE_CHECK: bool | None = None
-
-
-def hist_stage_enabled() -> bool:
-    """Whether frontier rounds should keep the round's parent/child
-    histograms in the small ``[2K, G, B, 3]`` carry stage instead of
-    gather/scatter against the full ``[L, G, B, 3]`` leaf_hist twice per
-    round (``LIGHTGBM_TPU_HIST_STAGE``).
-
-    Default OFF — no variant flips to default without a v5e number.
-    ``1/on`` runs the one-shot bit-identity self-check (staged vs
-    unstaged grow of the same tree) and falls back when it fails;
-    ``force`` bypasses the check for on-chip A/B plumbing.  Serial-only
-    either way: the distributed wrappers keep the direct carry."""
-    global _HIST_STAGE_CHECK
-    env = _os.environ.get("LIGHTGBM_TPU_HIST_STAGE", "").lower()
-    if env in ("", "0", "off", "false"):
-        return False
-    if env == "force":
-        return True
-    if _HIST_STAGE_CHECK is None:
-        _HIST_STAGE_CHECK = gate_self_check("hist-stage",
-                                            _hist_stage_self_check)
-    return _HIST_STAGE_CHECK
-
-
-def _hist_stage_self_check() -> bool:
-    """Round-carry staging must be BIT-identical: grow the same tree
-    staged and unstaged (explicit ``hist_stage=`` overrides, so the env
-    gate is bypassed and no recursion happens) and compare every tree
-    array and the returned leaf_id exactly."""
-    import numpy as np
-
-    from ..ops.split import SplitParams
-
-    rng = np.random.default_rng(23)
-    n, F, B, L, rb, k = 1024, 4, 16, 8, 256, 3
-    binsT = jnp.asarray(rng.integers(0, B, (F, n)), jnp.uint8)
-    grad = jnp.asarray(
-        (-(np.asarray(binsT)[0] >= B // 2).astype(np.float32)
-         - 0.5 * (np.asarray(binsT)[1] % 3 == 0)
-         + 0.1 * rng.standard_normal(n)), jnp.float32)
-    hess = jnp.ones(n, jnp.float32)
-    member = jnp.asarray((rng.random(n) < 0.9).astype(np.float32))
-    fmeta = FeatureMeta(
-        num_bin=jnp.full(F, B, jnp.int32),
-        missing_type=jnp.zeros(F, jnp.int32),
-        default_bin=jnp.zeros(F, jnp.int32),
-        is_cat=jnp.zeros(F, bool),
-        monotone=jnp.zeros(F, jnp.int32),
-        penalty=jnp.ones(F, jnp.float32))
-    fmask = jnp.ones(F, jnp.float32)
-    key = jax.random.PRNGKey(0)
-    params = GrowerParams(num_leaves=L, hist_backend="pallas",
-                          split=SplitParams(min_data_in_leaf=2.0))
-
-    outs = []
-    for staged in (False, True):
-        grow = make_grow_tree_frontier(B, params, rb, batch_k=k,
-                                       hist_stage=staged)
-        outs.append(grow(binsT, grad, hess, member, fmeta, fmask, key))
-    (tree_a, lid_a, _), (tree_b, lid_b, _) = outs
-    if not np.array_equal(np.asarray(lid_a), np.asarray(lid_b)):
-        return False
-    for fa, fb in zip(jax.tree_util.tree_leaves(tree_a),
-                      jax.tree_util.tree_leaves(tree_b)):
-        if not np.array_equal(np.asarray(fa), np.asarray(fb)):
-            return False
-    return True
+from .grower import (CommHooks, GrowerParams, _node_feature_mask,
+                     mono_handoff)
+from .grower_seg import (COMPACT_WASTE, _COMPACT_MUT, _SegState, _unpermute,
+                         apply_route, compact_state, cond_narrow,
+                         fresh_state, seg_stats_vector, stripe_histogram)
 
 
 def make_grow_tree_frontier(num_bins: int, params: GrowerParams,
                             block_rows: int, batch_k: int = 0,
                             gain_ratio: float = 0.0,
-                            comm=None, wrap=None, hist_stage=None,
-                            fused_k=None):
+                            comm=None, wrap=None):
     """Build the jitted frontier-batched grower.
 
     Same call contract as make_grow_tree_segment:
@@ -149,7 +66,6 @@ def make_grow_tree_frontier(num_bins: int, params: GrowerParams,
     [K, G, B, 3] batch in one collective, ``merge_split_batch`` merges
     all 2K children's SplitInfos by max gain in one all_gather.
     """
-    from .grower import CommHooks
     p = params
     L = p.num_leaves
     B = num_bins
@@ -161,49 +77,13 @@ def make_grow_tree_frontier(num_bins: int, params: GrowerParams,
     # a ratio above 1 would gate out even the round-best leaf and hang
     # the growth loop; config validates, this clamp guards direct callers
     gain_ratio = min(max(float(gain_ratio), 0.0), 1.0)
-    # packed int16 accumulator stream (build-time decision — env inside
-    # the jitted grow would poison the jit cache).  One quantize per
-    # TREE; every unpack happens BEFORE the batch collectives, so
-    # distributed reductions only ever see real-unit histograms.
-    packed_acc = packed_acc_enabled()
-    qbits = packed_acc_bits()
-    packed_acc_decisions["frontier"] = packed_acc
-    # serial := no distributed hooks.  Both the round-carry stage and
-    # the fused-K pass require it: the wrappers' reduce/stripe hooks
-    # read the full carry / per-child batches.
-    serial = (comm.reduce_hist_batch is None and comm.column_block is None
-              and not comm.no_subtract)
-    # fused route+histogram tiers (fused_route_policy): "fusedk" folds
-    # the round's K route updates AND all 2K children's histograms into
-    # ONE pass (LIGHTGBM_TPU_FUSED_K) — no parent gather, no
-    # subtraction trick, so the arithmetic bit-matches the no_subtract
-    # path; "k1" is the legacy K==1 fused route.  Feature-parallel
-    # stripes keep the unfused pair — the histogram scans a column
-    # slice, the route needs the full matrix.  The packed stream keeps
-    # the unfused pair unless LIGHTGBM_TPU_FUSED_PACKED opts the
-    # combined variant in for A/B (docs/KERNELS.md).  An explicit
-    # ``fused_k=`` (tests, self-checks) bypasses the env gate.
-    packed_ok = not packed_acc or fused_packed_optin()
-    fused_tier = fused_route_policy(K, p.num_columns or 64, B, rb,
-                                    p.packed4)
-    if fused_k is None:
-        fused_k = fused_tier == "fusedk"
-    fused_k = bool(fused_k) and serial and packed_ok
-    fused_route = (fused_tier == "k1" and not fused_k
-                   and comm.column_block is None and packed_ok)
-    fused_route_decisions["frontier"] = ("fusedk" if fused_k
-                                         else fused_route)
-    # round-carry leaf-hist staging: serial-only (the distributed
-    # wrappers' reduce/stripe hooks read the full carry); an explicit
-    # ``hist_stage=`` (the self-check) bypasses the env gate.  Under
-    # fused-K there is nothing to stage — no round ever reads leaf_hist
-    # (both children come from data), so the staging cond would only
-    # add latency.
-    if hist_stage is None:
-        hist_stage = hist_stage_enabled()
-    hist_stage = bool(hist_stage) and serial and not fused_k
-    hist_stage_decisions["frontier"] = hist_stage
-    from ..ops.pallas_histogram import route_kernel_available
+    # fused route+histogram (fused_route_policy): auto keeps it to
+    # K == 1.  Feature-parallel stripes keep the unfused pair — the
+    # histogram scans a column slice, the route needs the full matrix.
+    fused_route = (fused_route_policy(K, p.num_columns or 64, B, rb,
+                                      p.packed4) == "k1"
+                   and comm.column_block is None)
+    fused_route_decisions["frontier"] = fused_route
     route_kernel = route_kernel_available()
 
     def _one_scan(st, hist, g, h, c, depth, fmeta, fmask, key, step,
@@ -255,12 +135,7 @@ def make_grow_tree_frontier(num_bins: int, params: GrowerParams,
         if fpad:
             binsT = jnp.pad(binsT, ((0, fpad), (0, 0)))
 
-        if packed_acc:
-            w8, qscales, qclips = quantize_pack_channels(
-                grad, hess, member, bits=qbits)
-        else:
-            w8 = pack_channels(grad, hess, member)
-            qscales, qclips = None, jnp.int32(0)
+        w8 = pack_channels(grad, hess, member)
         G0 = jnp.sum(grad * member)
         H0 = jnp.sum(hess * member)
         C0 = jnp.sum(member)
@@ -268,11 +143,10 @@ def make_grow_tree_frontier(num_bins: int, params: GrowerParams,
             G0, H0, C0 = (comm.reduce_stats(G0), comm.reduce_stats(H0),
                           comm.reduce_stats(C0))
         all_blocks = jnp.arange(max_blocks, dtype=jnp.int32)
-        # grid-step accounting (same rule as histogram_frontier's dispatch)
-        bucket_arr = jnp.asarray(_segment_buckets(max_blocks), jnp.int32)
-
+        # grid-step accounting: histogram_frontier's grid is the union's
+        # blocks, one masked step where it is empty
         def grid_of(nb):
-            return segment_grid_size(bucket_arr, nb)
+            return jnp.maximum(nb, 1)
 
         def hist_batch(st: _SegState, targets, block_list, n_blocks,
                        routes=None, fmeta=None):
@@ -298,24 +172,9 @@ def make_grow_tree_frontier(num_bins: int, params: GrowerParams,
                 out = histogram_frontier(st.binsT, st.w8, st.leaf_id,
                                          block_list, n_blocks, targets, B,
                                          rb, packed4=p.packed4)
-            h = (unpack_hist_packed(out[:, :G_cols], qscales)
-                 if packed_acc else unpack_hist(out[:, :G_cols]))
+            h = unpack_hist(out[:, :G_cols])
             if comm.reduce_hist_batch is not None:
                 h = comm.reduce_hist_batch(h, fmeta)
-            return st, h
-
-        def hist_batch_fusedk(st: _SegState, targets2, block_list,
-                              n_blocks, routes):
-            """[2K] child targets (-1 = skip) -> (st, [2K, G, B, 3]):
-            ONE pass applies the round's K routes and accumulates every
-            child's histogram from the updated ids (serial-only; the
-            decision block guarantees no distributed hooks here)."""
-            lid, out = histogram_frontier_fusedk(
-                st.binsT, st.w8, st.leaf_id, block_list, n_blocks,
-                targets2, routes, B, rb, K, packed4=p.packed4)
-            st = st._replace(leaf_id=lid)
-            h = (unpack_hist_packed(out[:, :G_cols], qscales)
-                 if packed_acc else unpack_hist(out[:, :G_cols]))
             return st, h
 
         def apply_split(st: _SegState, leaf, new_leaf, node):
@@ -330,7 +189,7 @@ def make_grow_tree_frontier(num_bins: int, params: GrowerParams,
             bitset = st.best_cat_bitset[leaf]
 
             lo, hi = st.leaf_lo[leaf], st.leaf_hi[leaf]
-            if not (fused_route or fused_k):
+            if not fused_route:
                 # routing confined to the parent's inherited block
                 # interval (grower_seg.route_split_windowed); the fused
                 # path routes inside the batched histogram kernel instead
@@ -412,8 +271,7 @@ def make_grow_tree_frontier(num_bins: int, params: GrowerParams,
             )
             return st
 
-        def round_body(carry):
-            st, stage_ids, stage_hist, s_hits, s_looks, fk_rounds = carry
+        def round_body(st: _SegState):
             base = st.num_leaves
             budget = L - base
             gains_top, leaves_top = lax.top_k(st.best_f32[:, 0], K)
@@ -451,37 +309,7 @@ def make_grow_tree_frontier(num_bins: int, params: GrowerParams,
             def apply_one(j, s):
                 return apply_split(s, leaves_top[j], new_leaves[j],
                                    nodes[j])
-            if fused_k:
-                # both children come from data in the fused pass; no
-                # round ever reads leaf_hist, so the [L, G, B, 3]
-                # parent gather vanishes along with the child scatter
-                parent_hist = None
-            elif hist_stage:
-                # round-carry staging: flush LAST round's staged children
-                # into the full carry first (a later round may split a
-                # leaf that left the stage), then look the round's K
-                # parents up in the stage.  Best-first growth mostly
-                # splits just-created children, so the common case reads
-                # the small [2K, G, B, 3] stage instead of gathering from
-                # the [L, G, B, 3] carry — and the cond's outputs are
-                # only the small parent batch, so the miss path costs one
-                # gather, not a carried-copy of the full leaf_hist.
-                st = st._replace(leaf_hist=st.leaf_hist.at[
-                    jnp.where(stage_ids >= 0, stage_ids, L)].set(
-                        stage_hist, mode="drop"))
-                m = ((stage_ids[None, :] == leaves_top[:, None])
-                     & (stage_ids[None, :] >= 0))            # [K, 2K]
-                hit = jnp.any(m, axis=1)
-                pos = jnp.argmax(m, axis=1)
-                all_hit = jnp.all(hit | ~valid)
-                parent_hist = lax.cond(
-                    all_hit,
-                    lambda: stage_hist[jnp.where(hit, pos, 0)],
-                    lambda: st.leaf_hist[leaves_top])       # [K, G, B, 3]
-                s_hits = s_hits + jnp.sum((hit & valid).astype(jnp.int32))
-                s_looks = s_looks + jnp.sum(valid.astype(jnp.int32))
-            else:
-                parent_hist = st.leaf_hist[leaves_top]      # [K, G, B, 3]
+            parent_hist = st.leaf_hist[leaves_top]          # [K, G, B, 3]
             # ``valid`` is prefix-clamped above, so the popcount IS the
             # prefix length
             n_valid = jnp.sum(valid).astype(jnp.int32)
@@ -503,7 +331,7 @@ def make_grow_tree_frontier(num_bins: int, params: GrowerParams,
                                                       mode="drop")
 
             # 3) ONE batched kernel pass for the round's histograms
-            if fused_route or fused_k:
+            if fused_route:
                 # the round's K routes ride the same pass (invalid slots
                 # match nothing); split params still live in the best-*
                 # cache — the scans that overwrite them run in step 4
@@ -519,81 +347,38 @@ def make_grow_tree_frontier(num_bins: int, params: GrowerParams,
                         null_route()))(leaves_top, new_leaves, valid)
             else:
                 routes = None
-            if fused_k:
-                # fused-K: route + ALL 2K children in one data pass.
-                # Left children keep the parent leaf id after routing,
-                # right children take the new id — so the target list is
-                # simply [parents, new_leaves] and no smaller-child /
-                # subtraction bookkeeping exists on this path (arithmetic
-                # bit-matches comm.no_subtract, which also accumulates
-                # both children from data).
-                targets2 = jnp.concatenate([
-                    jnp.where(valid, leaves_top, -1),
-                    jnp.where(valid, new_leaves, -1)])
-                st, hists2 = hist_batch_fusedk(st, targets2, block_list,
-                                               n_un, routes)
-                hist_left, hist_right = hists2[:K], hists2[K:]
+            smaller = jnp.where(smaller_is_left, leaves_top, new_leaves)
+            targets = jnp.where(valid, smaller, -1)
+            st, hist_small = hist_batch(st, targets, block_list, n_un,
+                                        routes, fmeta)
+            if comm.no_subtract:
+                # voting-parallel: election masks differ per call, so
+                # the subtraction trick is invalid — batch-histogram
+                # the larger children from data too (routes applied)
+                larger = jnp.where(smaller_is_left, new_leaves,
+                                   leaves_top)
+                targets_l = jnp.where(valid, larger, -1)
+                _, hist_large = hist_batch(st, targets_l, block_list,
+                                           n_un, None, fmeta)
+                scanned = 2 * n_un
+                grid_inc = 2 * grid_of(n_un)
+            else:
+                hist_large = parent_hist - hist_small
                 scanned = n_un
                 grid_inc = grid_of(n_un)
-                fk_rounds = fk_rounds + 1
-            else:
-                smaller = jnp.where(smaller_is_left, leaves_top,
-                                    new_leaves)
-                targets = jnp.where(valid, smaller, -1)
-                st, hist_small = hist_batch(st, targets, block_list, n_un,
-                                            routes, fmeta)
-                if comm.no_subtract:
-                    # voting-parallel: election masks differ per call, so
-                    # the subtraction trick is invalid — batch-histogram
-                    # the larger children from data too (routes applied)
-                    larger = jnp.where(smaller_is_left, new_leaves,
-                                       leaves_top)
-                    targets_l = jnp.where(valid, larger, -1)
-                    _, hist_large = hist_batch(st, targets_l, block_list,
-                                               n_un, None, fmeta)
-                    scanned = 2 * n_un
-                    grid_inc = 2 * grid_of(n_un)
-                else:
-                    hist_large = parent_hist - hist_small
-                    scanned = n_un
-                    grid_inc = grid_of(n_un)
-                sel = smaller_is_left[:, None, None, None]
-                hist_left = jnp.where(sel, hist_small, hist_large)
-                hist_right = jnp.where(sel, hist_large, hist_small)
+            sel = smaller_is_left[:, None, None, None]
+            hist_left = jnp.where(sel, hist_small, hist_large)
+            hist_right = jnp.where(sel, hist_large, hist_small)
             idx_l = jnp.where(valid, leaves_top, L)
             idx_r = jnp.where(valid, new_leaves, L)
-            if fused_k:
-                # children go straight to the step-4 scans; leaf_hist is
-                # never read on this path, so neither of the per-round
-                # [L, G, B, 3] staging copies happens
-                st = st._replace(
-                    scanned_since=st.scanned_since + scanned,
-                    scanned_total=st.scanned_total + scanned,
-                    grid_total=st.grid_total + grid_inc,
-                )
-            elif hist_stage:
-                # the children stay in the stage this round; the flush at
-                # the top of the NEXT round persists them (a fresh stage
-                # entry shadows any stale carry slot until then)
-                stage_ids = jnp.where(
-                    jnp.concatenate([valid, valid]),
-                    jnp.concatenate([leaves_top, new_leaves]),
-                    jnp.int32(-1))
-                stage_hist = jnp.concatenate([hist_left, hist_right])
-                st = st._replace(
-                    scanned_since=st.scanned_since + scanned,
-                    scanned_total=st.scanned_total + scanned,
-                    grid_total=st.grid_total + grid_inc,
-                )
-            else:
-                st = st._replace(
-                    leaf_hist=st.leaf_hist
-                    .at[idx_l].set(hist_left, mode="drop")
-                    .at[idx_r].set(hist_right, mode="drop"),
-                    scanned_since=st.scanned_since + scanned,
-                    scanned_total=st.scanned_total + scanned,
-                    grid_total=st.grid_total + grid_inc,
-                )
+            st = st._replace(
+                leaf_hist=st.leaf_hist
+                .at[idx_l].set(hist_left, mode="drop")
+                .at[idx_r].set(hist_right, mode="drop"),
+                scanned_since=st.scanned_since + scanned,
+                scanned_total=st.scanned_total + scanned,
+                grid_total=st.grid_total + grid_inc,
+            )
 
             # 4) scan all 2K children in one vmapped pass
             leaves2 = jnp.concatenate([idx_l, idx_r])
@@ -615,9 +400,8 @@ def make_grow_tree_frontier(num_bins: int, params: GrowerParams,
             st = _write_scans(st, leaves2, infos, gains)
 
             # 5) adaptive compaction, same rule as the strict grower
-            st = cond_narrow(st.scanned_since >= limit_blocks,
-                             compact, st, _COMPACT_MUT)
-            return st, stage_ids, stage_hist, s_hits, s_looks, fk_rounds
+            return cond_narrow(st.scanned_since >= limit_blocks,
+                               compact, st, _COMPACT_MUT)
 
         limit_blocks = min(max(1, int(COMPACT_WASTE * max_blocks)),
                            2**31 - 1)
@@ -625,21 +409,13 @@ def make_grow_tree_frontier(num_bins: int, params: GrowerParams,
         st = fresh_state(binsT, w8, n, L, G_cols, B, F, max_blocks,
                          G0, H0, C0, fmeta, p)
         if root_hist is None:
-            # all-null routes on the fused paths: same kernel as the
+            # all-null routes on the fused path: same kernel as the
             # round passes, so the root costs no extra Mosaic compile
-            if fused_k:
-                root_targets2 = (jnp.full(2 * K, -1, jnp.int32)
-                                 .at[0].set(0))
-                _, rh = hist_batch_fusedk(st, root_targets2, all_blocks,
-                                          jnp.int32(max_blocks),
-                                          jnp.tile(null_route(), (K, 1)))
-            else:
-                root_targets = jnp.full(K, -1, jnp.int32).at[0].set(0)
-                root_routes = (jnp.tile(null_route(), (K, 1))
-                               if fused_route else None)
-                _, rh = hist_batch(st, root_targets, all_blocks,
-                                   jnp.int32(max_blocks), root_routes,
-                                   fmeta)
+            root_targets = jnp.full(K, -1, jnp.int32).at[0].set(0)
+            root_routes = (jnp.tile(null_route(), (K, 1))
+                           if fused_route else None)
+            _, rh = hist_batch(st, root_targets, all_blocks,
+                               jnp.int32(max_blocks), root_routes, fmeta)
             root_hist = rh[0]
         st = st._replace(leaf_hist=st.leaf_hist.at[0].set(root_hist),
                          scanned_since=jnp.int32(max_blocks),
@@ -657,36 +433,16 @@ def make_grow_tree_frontier(num_bins: int, params: GrowerParams,
         def cond(st):
             return (st.num_leaves < L) & (jnp.max(st.best_f32[:, 0]) > 0.0)
 
-        if hist_stage:
-            # root pre-staged at slot 0 (it is also in leaf_hist[0], so
-            # the first round's flush rewrites identical values)
-            stage_ids0 = jnp.full(2 * K, -1, jnp.int32).at[0].set(0)
-            stage_hist0 = jnp.zeros((2 * K, G_cols, B, 3),
-                                    jnp.float32).at[0].set(root_hist)
-        else:
-            stage_ids0 = jnp.zeros(0, jnp.int32)
-            stage_hist0 = jnp.zeros((0, G_cols, B, 3), jnp.float32)
-        carry = (st, stage_ids0, stage_hist0, jnp.int32(0), jnp.int32(0),
-                 jnp.int32(0))
-        carry = lax.while_loop(lambda c: cond(c[0]), round_body, carry)
-        st, _sid, _shist, s_hits, s_looks, fk_rounds = carry
+        st = lax.while_loop(cond, round_body, st)
         leaf_id_orig = _unpermute(st.order, st.leaf_id)
         # counters as a third jit output with stable arity (no in-jit
         # host callbacks); printing is env-gated at call sites
-        stats = jnp.stack([st.scanned_total, st.num_sorts, st.grid_total,
-                           jnp.int32(max_blocks), jnp.int32(K),
-                           fk_rounds, qclips.astype(jnp.int32),
-                           s_hits, s_looks]
-                          # splits, the strict grower's lookahead
-                          # counters and its feature tiles: none here
-                          + [jnp.int32(0)] * (SEG_STATS_SLOTS - 9))
+        stats = seg_stats_vector(
+            scanned_blocks=st.scanned_total, compactions=st.num_sorts,
+            grid_steps=st.grid_total, max_blocks=max_blocks, batch_k=K)
         return st.tree, leaf_id_orig, stats
 
     if wrap is not None:
         return wrap(grow)
     from ..utils.jitcost import cost_jit
-    # the fused-K label keeps "hist" in it so bench_suite's hist-pass
-    # rollup (and bench_gate's latency gate) see fused rounds
-    label = (f"grow/frontier[fused_hist_k{K}]" if fused_k
-             else "grow/frontier")
-    return cost_jit(label, jax.jit(grow))
+    return cost_jit("grow/frontier", jax.jit(grow))

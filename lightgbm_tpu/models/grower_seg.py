@@ -18,8 +18,8 @@ re-partition per split (data-dependent scatter), so this grower uses
     occupied — descendants only refine within it;
   * each split's smaller-child histogram runs the scalar-prefetched
     pallas segment kernel (ops/pallas_histogram.histogram_segment) over
-    just that confinement interval: DMA and compute scale with the
-    interval, and out-of-range grid steps are skipped for free;
+    just that confinement interval: DMA, compute and the kernel's grid
+    scale with the interval;
   * where the shape leaves lane sets free (``lookahead_width``), that
     pass also fills them with the smaller-child histograms that the
     PENDING best splits of other leaves inside the interval will need,
@@ -43,10 +43,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..ops.pallas_histogram import (NUM_CHANNELS, _segment_buckets,
-                                    bucket_index, empty_lookahead_slots,
-                                    feature_tile, fused_packed_optin,
-                                    fused_route_decisions,
+from ..ops.pallas_histogram import (NUM_CHANNELS, empty_lookahead_slots,
+                                    feature_tile, fused_route_decisions,
                                     fused_route_policy,
                                     histogram_segment,
                                     histogram_segment_lookahead,
@@ -54,16 +52,12 @@ from ..ops.pallas_histogram import (NUM_CHANNELS, _segment_buckets,
                                     lookahead_width, null_route,
                                     pack_channels, pack_lookahead_slots,
                                     pack_route,
-                                    packed_acc_bits, packed_acc_decisions,
-                                    packed_acc_enabled,
-                                    quantize_pack_channels,
                                     route_kernel_available, route_window,
-                                    segment_grid_size, unpack_hist,
-                                    unpack_hist_packed, unpack_nibble)
+                                    unpack_hist, unpack_nibble)
 from ..ops.split import (NEG_INF, FeatureMeta, best_split, expand_group_hist,
-                         reconstruct_feature_column)
+                         reconstruct_feature_column, routed_left)
 from .grower import (CommHooks, GrowerParams, TreeArrays,
-                     _node_feature_mask, mono_handoff, routed_left)
+                     _node_feature_mask, mono_handoff)
 
 # Adaptive compaction: re-sort whenever the histogram kernels have scanned
 # more than COMPACT_WASTE x N rows of confinement intervals since the last
@@ -84,21 +78,46 @@ import os as _os
 COMPACT_WASTE = float(_os.environ.get("LIGHTGBM_TPU_COMPACT_WASTE", "9.0"))
 
 
-# the growers' third jit output: i32 counter vector, one row per device
-# under the data-parallel wrappers.  Fixed width so every grower/wrapper
-# agrees; slots [fused_k_rounds, quant_clips, stage_hits, stage_lookups]
-# stay 0 on paths that don't fuse-K / quantize / stage, [lookahead_hits,
-# lookahead_filled, route_only_blocks] where no lookahead lane sets run, and
-# [feature_tiles, leaf_hist_kib] on the growers that do not report them.
-SEG_STATS_SLOTS = 15
+class SegStats(NamedTuple):
+    """The growers' third jit output, slot by slot: an i32 vector a tree
+    (one row a device under the data-parallel wrappers), fixed in width
+    so every grower and wrapper agrees.  A slot a grower does not report
+    stays 0 (``seg_stats_vector``): the lookahead counters where no lane
+    sets run, the shape facts on the frontier grower."""
+    scanned_blocks: object      # blocks that entered an accumulate
+    compactions: object
+    grid_steps: object          # kernel grid steps of accumulating passes
+    max_blocks: object          # blocks of the whole table
+    batch_k: object             # leaves split a round (1: strict)
+    splits: object
+    lookahead_hits: object      # splits served by a lookahead histogram
+    lookahead_filled: object
+    route_only_blocks: object
+    feature_tiles: object       # tiles a pass walks (1: the table whole)
+    leaf_hist_kib: object       # the per-leaf histogram tables
+
+
+SEG_STATS_SLOTS = len(SegStats._fields)
+
+
+def seg_stats_vector(**slots) -> jax.Array:
+    """[SEG_STATS_SLOTS] i32 from the slots a grower reports, by name."""
+    unknown = set(slots) - set(SegStats._fields)
+    assert not unknown, unknown
+    return jnp.stack([jnp.asarray(slots.get(f, 0), jnp.int32)
+                      for f in SegStats._fields])
+
+
+def seg_stats_columns(stats) -> SegStats:
+    """A grower's counters (one vector, or rows of them a tree and
+    device) as named columns on the host."""
+    import numpy as np
+    return SegStats(*np.asarray(stats).reshape(-1, SEG_STATS_SLOTS).T)
 
 
 def seg_stats_enabled() -> bool:
     """When LIGHTGBM_TPU_SEG_STATS is set, the counters the growers
-    return — [scanned_blocks, compactions, grid_steps, max_blocks, K,
-    fused_k_rounds, quant_clips, stage_hits, stage_lookups, splits,
-    lookahead_hits, lookahead_filled, route_only_blocks, feature_tiles,
-    leaf_hist_kib] — are printed per tree."""
+    return (``SegStats``) are printed per tree."""
     return bool(_os.environ.get("LIGHTGBM_TPU_SEG_STATS"))
 
 
@@ -108,39 +127,30 @@ def print_seg_stats(stats) -> None:
     jax.debug.print).  Accepts one [SEG_STATS_SLOTS] vector or a
     per-device concatenation of them.
 
-    ``grid`` counts the kernel grid steps actually dispatched (the bucket
-    the interval landed in, summed over calls, once for every feature
-    tile that walks it); grid − scanned x tiles is the skipped-step waste
-    the static bucket ladder pays (ops/pallas_histogram._segment_buckets)."""
+    ``grid`` counts the kernel grid steps dispatched by accumulating
+    passes, once for every feature tile that walks the interval."""
     import sys
 
     import numpy as np
 
     rows = np.asarray(stats).reshape(-1, SEG_STATS_SLOTS)
-    for d, (scanned, sorts, grid, max_blocks, k, fkr, clips, shits,
-            slooks, splits, lhits, lfill, ronly, tiles,
-            _hist_kib) in enumerate(rows):
+    for d, r in enumerate(map(SegStats._make, rows)):
         dev = f" dev{d}" if len(rows) > 1 else ""
-        nb = max(int(max_blocks), 1)
+        nb = max(int(r.max_blocks), 1)
         extra = ""
-        if fkr:
-            extra += f", fused-K rounds {int(fkr)}"
-        if clips:
-            extra += f", quant clips {int(clips)}"
-        if slooks:
-            extra += (f", stage hits {int(shits)}/{int(slooks)} "
-                      f"({shits / max(int(slooks), 1):.0%})")
-        if lfill:
-            extra += (f", lookahead {int(lhits)} of {int(splits)} splits "
-                      f"served ({int(lfill)} filled, {int(ronly)} "
-                      f"route-only blocks)")
-        if tiles > 1:
-            extra += f", {int(tiles)} feature tiles a pass"
+        if r.lookahead_filled:
+            extra += (f", lookahead {int(r.lookahead_hits)} of "
+                      f"{int(r.splits)} splits served "
+                      f"({int(r.lookahead_filled)} filled, "
+                      f"{int(r.route_only_blocks)} route-only blocks)")
+        if r.feature_tiles > 1:
+            extra += f", {int(r.feature_tiles)} feature tiles a pass"
         sys.stderr.write(
-            f"seg stats{dev}: scanned {int(scanned)} blocks "
-            f"({scanned / nb:.1f} N-equivalents), "
-            f"grid {int(grid)} steps ({grid / nb:.1f} N-equivalents), "
-            f"{int(sorts)} compactions, K={int(k)}{extra}\n")
+            f"seg stats{dev}: scanned {int(r.scanned_blocks)} blocks "
+            f"({r.scanned_blocks / nb:.1f} N-equivalents), "
+            f"grid {int(r.grid_steps)} steps "
+            f"({r.grid_steps / nb:.1f} N-equivalents), "
+            f"{int(r.compactions)} compactions, K={int(r.batch_k)}{extra}\n")
     sys.stderr.flush()
 
 
@@ -261,6 +271,29 @@ def cond_narrow(pred, fn, st: _SegState, fields) -> _SegState:
     return _put(st, fields, out)
 
 
+def _segment_buckets(max_blocks: int) -> list:
+    """Static window-size ladder of the XLA windowed route: a slice width
+    is static but a leaf's confinement interval is data-dependent, so
+    ``route_split_windowed`` lax.switches between a few widths, each an
+    eighth of the one above, and takes the smallest covering the
+    interval."""
+    buckets = []
+    b = max_blocks
+    while b > 1:
+        buckets.append(b)
+        b = max(1, b // 8)
+    buckets.append(1)
+    return sorted(set(buckets))
+
+
+def bucket_index(bucket_list, n_blocks) -> jax.Array:
+    """Index of the smallest ladder bucket covering an ``n_blocks``-long
+    interval."""
+    nb = jnp.asarray(n_blocks, jnp.int32).reshape(())
+    return jnp.minimum(jnp.sum(jnp.asarray(bucket_list, jnp.int32) < nb),
+                       len(bucket_list) - 1)
+
+
 def route_split_windowed(binsT, leaf_id, fmeta, packed4, rb,
                          f, t, dl, cat, bitset, leaf, new_leaf,
                          lo, n_blk):
@@ -273,9 +306,9 @@ def route_split_windowed(binsT, leaf_id, fmeta, packed4, rb,
     full-N where() pass is pure waste — 254 of them per tree were the
     bulk of the growers' ~0.8 s/iter constant at 10.5M rows (round-4
     micro: route_pass ~51 ms/full-N vs 27 ms for a whole histogram
-    pass).  Like the histogram kernels, the window is picked from the
-    static ``_segment_buckets`` ladder: ``lax.switch`` over a few
-    dynamic-slice widths, smallest bucket covering the interval.  The
+    pass).  The window is picked from the static ``_segment_buckets``
+    ladder: ``lax.switch`` over a few dynamic-slice widths, smallest
+    bucket covering the interval.  The
     window may over-cover (block granularity + bucket rounding + end
     clamping); rows of other leaves inside it fail the ``== leaf`` test
     and pass through unchanged.
@@ -369,24 +402,17 @@ def compact_state(st: _SegState, L: int, rb: int) -> _SegState:
     segments and confinement intervals reset to them.  Shared by the
     strict and frontier growers (identical _SegState layout)."""
     W = st.binsT.shape[0] // 4
-    # packed-accumulator stream: w8 is the [2, N] i32 quantized pair /
-    # bitcast-member words — already sort-payload-shaped, so it rides the
-    # variadic sort directly (2 operands vs the f32 path's 3 halfword
-    # packs) and needs no re-pack after
-    packed_w = st.w8.dtype == jnp.int32
-    wrows = st.w8.shape[0] if packed_w else 3
+    wrows = 3       # the six live channels as halfword pairs
     if W + 2 + wrows <= _MAX_SORT_OPERANDS:
         operands = ((st.leaf_id,)
                     + tuple(_pack_bins_words(st.binsT))
-                    + (tuple(st.w8) if packed_w
-                       else tuple(_pack_w8_words(st.w8)))
+                    + tuple(_pack_w8_words(st.w8))
                     + (st.order,))
         sorted_ops = lax.sort(operands, num_keys=1, is_stable=True)
         lid = sorted_ops[0]
         binsT = _unpack_bins_words(jnp.stack(sorted_ops[1:1 + W]),
                                    st.binsT.dtype)
-        wsorted = jnp.stack(sorted_ops[1 + W:1 + W + wrows])
-        w8 = wsorted if packed_w else _unpack_w8_words(wsorted)
+        w8 = _unpack_w8_words(jnp.stack(sorted_ops[1 + W:1 + W + wrows]))
         order = sorted_ops[1 + W + wrows]
     else:
         # wide-feature path: 2-operand stable sort for the permutation,
@@ -403,16 +429,13 @@ def compact_state(st: _SegState, L: int, rb: int) -> _SegState:
                                            mode="promise_in_bounds")
 
             binsT = move(st.binsT)
-            if packed_w:
-                w8 = move(st.w8)
-            else:
-                # channels 6-7 are structurally zero (pack_channels) —
-                # move only the live ones, refill the rest (same trim
-                # the sort path makes)
-                w8 = jnp.concatenate(
-                    [move(st.w8[:6]),
-                     jnp.zeros((st.w8.shape[0] - 6, st.w8.shape[1]),
-                               st.w8.dtype)])
+            # channels 6-7 are structurally zero (pack_channels) — move
+            # only the live ones, refill the rest (same trim the sort
+            # path makes)
+            w8 = jnp.concatenate(
+                [move(st.w8[:6]),
+                 jnp.zeros((st.w8.shape[0] - 6, st.w8.shape[1]),
+                           st.w8.dtype)])
             order = move(st.order)
     leaves = jnp.arange(L, dtype=jnp.int32)
     starts = jnp.searchsorted(lid, leaves, side="left").astype(jnp.int32)
@@ -542,44 +565,28 @@ def make_grow_tree_segment(num_bins: int, params: GrowerParams,
     L = p.num_leaves
     B = num_bins
     rb = block_rows
-    # packed int16 accumulator stream (build-time decision — env inside
-    # the jitted grow would poison the jit cache).  Quantization is per
-    # TREE here (one stream for the whole grow); the per-leaf rescale
-    # the unpack applies is the shared [2] scales vector.  Distributed-
-    # safe: every unpack happens BEFORE comm.reduce_hist, so collectives
-    # only ever see real-unit histograms.
-    packed_acc = packed_acc_enabled()
-    qbits = packed_acc_bits()
-    packed_acc_decisions["segment"] = packed_acc
     # fused route+histogram: the split's leaf_id update rides the
     # smaller-child histogram pass instead of separate XLA passes over
     # the same blocks (self-checked on the live backend at build time).
     # Feature-parallel stripes (column_block) keep the unfused pair: the
     # histogram scans a column SLICE while the route needs the full
     # matrix (the winning split may live on another shard's stripe).
-    # The packed stream keeps the unfused pair too — packed+fused has no
-    # on-chip number yet (docs/KERNELS.md), so the A/B isolates one
-    # variant at a time — unless LIGHTGBM_TPU_FUSED_PACKED opts the
-    # combined variant in for its own A/B.
     fused_route = (fused_route_policy(1, p.num_columns or 64, B, rb,
                                       p.packed4) == "k1"
-                   and comm.column_block is None
-                   and (not packed_acc or fused_packed_optin()))
+                   and comm.column_block is None)
     fused_route_decisions["segment"] = fused_route
     route_kernel = route_kernel_available()
     # lookahead lane sets (lookahead_split below): the serial learner's
-    # fused split path on the f32 stream only.  Under reduce_hist every
-    # lookahead histogram would cross the wire, voting and the feature
-    # stripes never run the fused split path, and the packed stream
-    # accumulates other lanes.  How many lane sets comes from the shape
+    # fused split path only.  Under reduce_hist every lookahead histogram
+    # would cross the wire, and voting and the feature stripes never run
+    # the fused split path.  How many lane sets comes from the shape
     # (lookahead_width, at trace time): 1 builds today's program.
     lookahead_ok = (fused_route and not comm.no_subtract
-                    and comm.reduce_hist is None and not packed_acc)
+                    and comm.reduce_hist is None)
 
-    def hist_leaf(st: _SegState, leaf, G_cols, fmeta=None, scales=None,
+    def hist_leaf(st: _SegState, leaf, G_cols, fmeta=None,
                   look_k: int = 1):
-        """Returns (hist [G,B,3], blocks scanned).  ``scales`` is the
-        packed stream's [2] rescale vector (None on the f32 path)."""
+        """Returns (hist [G,B,3], blocks scanned)."""
         lo = st.leaf_lo[leaf]
         n_blk = st.leaf_hi[leaf] - lo
         if look_k > 1:
@@ -613,8 +620,7 @@ def make_grow_tree_segment(num_bins: int, params: GrowerParams,
         else:
             out = histogram_segment(st.binsT, st.w8, st.leaf_id, lo,
                                     n_blk, leaf, B, rb, packed4=p.packed4)
-        h = (unpack_hist_packed(out[:G_cols], scales)
-             if scales is not None else unpack_hist(out[:G_cols]))
+        h = unpack_hist(out[:G_cols])
         if comm.reduce_hist is not None:
             h = comm.reduce_hist(h, None, None, None, fmeta)
         return h, n_blk
@@ -712,20 +718,13 @@ def make_grow_tree_segment(num_bins: int, params: GrowerParams,
         if fpad:
             binsT = jnp.pad(binsT, ((0, fpad), (0, 0)))
 
-        # grid-step accounting: the bucket ladder is static, so the grid
-        # size a call dispatched is recomputable from its interval length
-        bucket_arr = jnp.asarray(_segment_buckets(max_blocks), jnp.int32)
-
+        # grid-step accounting: a call's grid is its interval's blocks
+        # (one masked step for an empty interval), once a feature tile
         def grid_of(nb):
-            return n_tiles * segment_grid_size(bucket_arr, nb)
+            return n_tiles * jnp.maximum(nb, 1)
 
         with jax.named_scope("quantize_pack"):
-            if packed_acc:
-                w8, qscales, qclips = quantize_pack_channels(
-                    grad, hess, member, bits=qbits)
-            else:
-                w8 = pack_channels(grad, hess, member)
-                qscales, qclips = None, jnp.int32(0)
+            w8 = pack_channels(grad, hess, member)
         G0 = jnp.sum(grad * member)
         H0 = jnp.sum(hess * member)
         C0 = jnp.sum(member)
@@ -862,17 +861,16 @@ def make_grow_tree_segment(num_bins: int, params: GrowerParams,
                 # parent-minus-smaller is invalid (CommHooks doc) — build
                 # BOTH children from data over the same interval
                 with jax.named_scope("hist_split"):
-                    hist_left, _b1 = hist_leaf(st, leaf, G_cols, fmeta,
-                                                qscales)
+                    hist_left, _b1 = hist_leaf(st, leaf, G_cols, fmeta)
                     hist_right, _b2 = hist_leaf(st, new_leaf, G_cols,
-                                                fmeta, qscales)
+                                                fmeta)
                 blk = _b1 + _b2
                 grid_blk = grid_of(_b1) + grid_of(_b2)
             else:
                 if not fused_route:
                     with jax.named_scope("hist_split"):
                         hist_small, blk = hist_leaf(st, smaller, G_cols,
-                                                    fmeta, qscales)
+                                                    fmeta)
                 # a pass that only routed accumulated over no grid step
                 grid_blk = (jnp.where(blk > 0, grid_of(blk), 0)
                             if look_k > 1 else grid_of(blk))
@@ -997,7 +995,7 @@ def make_grow_tree_segment(num_bins: int, params: GrowerParams,
         if root_hist is None:
             with jax.named_scope("hist_root"):
                 root_hist, root_blk = hist_leaf(st, jnp.int32(0), G_cols,
-                                                fmeta, qscales, look_k)
+                                                fmeta, look_k)
         else:
             # external batched pass: charge the same scan cost so the
             # adaptive-compaction accounting is unchanged
@@ -1015,14 +1013,14 @@ def make_grow_tree_segment(num_bins: int, params: GrowerParams,
         # (stable arity; no host callbacks, so no jax.debug.print, in
         # compiled code) — printing them is gated on
         # LIGHTGBM_TPU_SEG_STATS at the call sites
-        stats = jnp.stack([st.scanned_total, st.num_sorts, st.grid_total,
-                           jnp.int32(max_blocks), jnp.int32(1),
-                           jnp.int32(0), qclips.astype(jnp.int32),
-                           jnp.int32(0), jnp.int32(0), st.num_splits,
-                           st.look_hits, st.look_filled, st.route_only,
-                           jnp.int32(n_tiles),
-                           jnp.int32((st.leaf_hist.size
-                                      + st.look_hist.size) * 4 // 1024)])
+        stats = seg_stats_vector(
+            scanned_blocks=st.scanned_total, compactions=st.num_sorts,
+            grid_steps=st.grid_total, max_blocks=max_blocks, batch_k=1,
+            splits=st.num_splits, lookahead_hits=st.look_hits,
+            lookahead_filled=st.look_filled,
+            route_only_blocks=st.route_only, feature_tiles=n_tiles,
+            leaf_hist_kib=(st.leaf_hist.size + st.look_hist.size)
+            * 4 // 1024)
         return st.tree, leaf_id_orig, stats
 
     if wrap is not None:

@@ -26,34 +26,12 @@ Two kernels share the inner body:
     target_leaf)`` descriptor restricts DMA *and* compute to the blocks of
     one leaf's confinement interval — the TPU equivalent of the reference's
     ordered bins (src/io/ordered_sparse_bin.hpp) whose histogram cost is
-    proportional to the leaf, not the dataset.  Out-of-range grid steps
-    re-map to the last in-range block, so the pipeline issues no new DMA
-    for them, and ``pl.when`` skips their compute.
+    proportional to the leaf, not the dataset.  The grid is the traced
+    interval length, so no step falls outside it.
 
 The 8 weight channels are ``[g_hi, g_lo, h_hi, h_lo, member, 0, 0, 0]``;
 ``unpack_hist`` folds a kernel output ``[F, B, 8]`` back to the
 ``[F, B, 3]`` (sum_grad, sum_hess, count) layout the split scan consumes.
-
-Two env-gated variant fronts ride the same kernels (docs/KERNELS.md has
-the full catalogue and measured verdicts):
-
-  * ``LIGHTGBM_TPU_PACKED_ACC``: a packed int16 accumulator stream
-    (``quantize_pack_channels``) — grad/hess stochastically rounded to
-    int16 and packed into ONE i32 lane, halving both the weight-stream
-    HBM DMA and the accumulator channel width (the arxiv 1806.11248 /
-    1706.08359 lever).  Kernels detect the i32 dtype (it is part of the
-    jit avals, so no new static args) and widen to ``PACKED_CHANNELS``
-    bf16 lanes in VMEM; ``unpack_hist_packed`` rescales at unpack.  The
-    count channel stays exact.
-  * ``LIGHTGBM_TPU_ONEHOT_BUILD``: alternative one-hot constructions
-    (``gather``: row-gather from an eye tile; ``twolevel``: two half-
-    width compares multiplied) — bit-identical to the iota build by
-    construction (same matmul, same accumulation order).
-
-Both are auto-gated by one-shot self-checks on the live backend
-(``gate_self_check``: a mismatch falls back to the f32 / iota path with a
-warning, a lowering failure on TPU raises), and neither flips to default
-without a v5e number.
 """
 
 from __future__ import annotations
@@ -66,20 +44,13 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-import os as _os
-
 NUM_CHANNELS = 8
-# channel width of the packed-accumulator stream once widened in VMEM:
-# [g_q, h_q, member, 0] — half the 8-channel hi/lo path
-PACKED_CHANNELS = 4
 DEFAULT_BLOCK_ROWS = 16384
 # inner sub-chunk of a row block: the one-hot [fblk*B, CHUNK] lives in
-# VMEM only for the duration of one matmul.  Env-tunable (read at
-# import) for on-chip inner-loop sweeps: the build is ~5x off its VPU
-# bound and these two shape the materialized tile.
-CHUNK = int(_os.environ.get("LIGHTGBM_TPU_ONEHOT_CHUNK", "512"))
+# VMEM only for the duration of one matmul
+CHUNK = 512
 # feature sub-block: keep fblk*B*CHUNK*2B (one-hot) around 2MB
-_FBLK_BIN_BUDGET = int(_os.environ.get("LIGHTGBM_TPU_FBLK_BINS", "2048"))
+_FBLK_BIN_BUDGET = 2048
 # VMEM working-set budget for auto block sizing (bytes, of ~16MB/core)
 _VMEM_BUDGET = 10 * 1024 * 1024
 # Mosaic's default scoped-VMEM limit per kernel on v5e (the unfused
@@ -228,97 +199,8 @@ def unpack_hist(out: jax.Array) -> jax.Array:
     return jnp.stack([g, h, c], axis=-1)
 
 
-def packed_acc_bits() -> int:
-    """Quantization width for the packed accumulator
-    (``LIGHTGBM_TPU_PACKED_BITS``, default 8, clamped to [2, 15]).
-
-    8 bits is the exactness sweet spot: quantized ints up to +-127 are
-    EXACT in the bf16 lanes the MXU contracts (8 mantissa bits), so the
-    only error is the stochastic rounding itself.  Widths above 8 trade
-    that in-matmul exactness for resolution (bf16 rounds ints > 256) —
-    the self-check bound still holds but the verdict belongs on-chip."""
-    try:
-        bits = int(_os.environ.get("LIGHTGBM_TPU_PACKED_BITS", "8"))
-    except ValueError:
-        bits = 8
-    return max(2, min(bits, 15))
-
-
-def quantize_pack_channels(grad: jax.Array, hess: jax.Array,
-                           member: jax.Array, key=None, bits: int = 8):
-    """[N] f32 grad/hess/member -> ``([2, N] i32, [2] f32 scales, clips)``
-    packed weight stream for the packed-accumulator kernels.
-
-    Row 0 packs the stochastically-rounded int16 pair — grad*member in
-    the high halfword, hess*member in the low — so the weight stream is
-    8 bytes/row instead of 16; row 1 carries the member bits (f32
-    bitcast) so the count channel stays exact.  ``scales`` rescales the
-    summed quantized lanes back to real units at unpack: quantization is
-    per CALL, so the rescale is per tree (segment/frontier growers, one
-    quantize per grow) or per leaf (plain grower).  Stochastic rounding
-    keeps every per-bin sum unbiased; ``clips`` counts saturated lanes
-    (|q| == qmax, the rows quantized at the coarsest step) for the
-    ``hist/quant_clips`` telemetry counter.
-    """
-    gm = grad * member
-    hm = hess * member
-    qmax = float(2 ** (bits - 1) - 1)
-    gscale = jnp.maximum(jnp.max(jnp.abs(gm)), 1e-30) / qmax
-    hscale = jnp.maximum(jnp.max(jnp.abs(hm)), 1e-30) / qmax
-    if key is None:
-        # deterministic data-derived key: the rounding only needs per-row
-        # uniforms decorrelated from the values, and deriving the fold
-        # from the gradient bits gives fresh draws every tree without
-        # threading a PRNG key through the growers
-        seed = jnp.sum(lax.bitcast_convert_type(
-            gm[:8].astype(jnp.float32), jnp.int32).astype(jnp.uint32))
-        key = jax.random.fold_in(jax.random.PRNGKey(0x517CC1B7), seed)
-    kg, kh = jax.random.split(key)
-
-    def _q(x, scale, k):
-        t = x / scale
-        fl = jnp.floor(t)
-        up = jax.random.uniform(k, t.shape) < (t - fl)
-        return jnp.clip(fl + up.astype(jnp.float32),
-                        -qmax, qmax).astype(jnp.int32)
-
-    gq = _q(gm, gscale, kg)
-    hq = _q(hm, hscale, kh)
-    clips = (jnp.sum((jnp.abs(gq) >= qmax).astype(jnp.int32))
-             + jnp.sum((jnp.abs(hq) >= qmax).astype(jnp.int32)))
-    w2 = jnp.stack([
-        (gq << 16) | (hq & 0xFFFF),
-        lax.bitcast_convert_type(member.astype(jnp.float32), jnp.int32)])
-    return w2, jnp.stack([gscale, hscale]), clips
-
-
-def unpack_hist_packed(out: jax.Array, scales: jax.Array) -> jax.Array:
-    """[..., B, PACKED_CHANNELS] packed-accumulator sums -> [..., B, 3]
-    real-unit (sum_grad, sum_hess, count); ``scales`` is
-    quantize_pack_channels's [2] rescale pair."""
-    g = out[..., 0] * scales[0]
-    h = out[..., 1] * scales[1]
-    return jnp.stack([g, h, out[..., 2]], axis=-1)
-
-
-def _packed_wrows(wb: jax.Array) -> jax.Array:
-    """[2, chunk] i32 packed stream block -> [PACKED_CHANNELS, chunk]
-    bf16 rows [g_q, h_q, member, 0] for the shared matmul.
-
-    Arithmetic shifts sign-extend the int16 halves (v5e-safe: plain i32
-    VPU ops, no narrow iota/compare); i32 -> f32 -> bf16 are supported
-    single-step converts, and the member lane takes the same f32 -> bf16
-    rounding as pack_channels so counts match the 8-channel path
-    bitwise."""
-    wq = wb[0:1]
-    gq = (wq >> 16).astype(jnp.float32).astype(jnp.bfloat16)
-    hq = ((wq << 16) >> 16).astype(jnp.float32).astype(jnp.bfloat16)
-    m = lax.bitcast_convert_type(wb[1:2], jnp.float32).astype(jnp.bfloat16)
-    return jnp.concatenate([gq, hq, m, jnp.zeros_like(m)], axis=0)
-
-
 def _accumulate_block(binsT_ref, wfn, acc_ref, num_bins, packed4=False,
-                      onehot_build="iota", unroll=1):
+                      unroll=1):
     """Shared inner body: one [F, rb] bin block into the [F*B, 8]
     accumulator, one combined-one-hot matmul per (chunk, fblock).
 
@@ -336,20 +218,9 @@ def _accumulate_block(binsT_ref, wfn, acc_ref, num_bins, packed4=False,
     half the HBM bin-stream DMA for narrow-bin datasets; unpacking is two
     VPU ops per block.
 
-    ``onehot_build`` picks the one-hot construction (the measured ~18 ms
-    VPU bound of the 12.4 ms/pass baseline).  All three builds produce
-    the SAME [nf*B, chunk] matrix feeding the SAME dot_general, so the
-    f32 accumulation order — and therefore the output bits — cannot
-    differ:
-
-      * ``iota``  — compare-vs-broadcasted-iota (the baseline);
-      * ``gather``— one eye(B) bf16 tile built in VMEM, one row-gather
-        of the chunk's bin indices, one sublane transpose (nf*chunk
-        gather rows instead of nf*B*chunk compares);
-      * ``twolevel`` — split the bin index into high/low halves and
-        multiply two half-width compare one-hots (nf*(Bh+Bl)*chunk
-        compares instead of nf*B*chunk; power-of-two B only, falls
-        back to iota statically otherwise).
+    The one-hot is an i32 compare against a broadcasted iota: the only
+    compare width the v5e's VPU offers (16-bit iota and compares do not
+    lower; docs/KERNELS.md, rejected variants).
     """
     Fp, rb = binsT_ref.shape
     F = Fp * 2 if packed4 else Fp
@@ -357,80 +228,19 @@ def _accumulate_block(binsT_ref, wfn, acc_ref, num_bins, packed4=False,
     fblk = max(1, _fblk(B) // (2 if packed4 else 1))
     chunk = _pick_chunk(rb)
 
-    # LIGHTGBM_TPU_ONEHOT_DTYPE picks the compare dtype for the one-hot
-    # build — the kernel's measured bound (~18 ms of the ~27 ms full-N
-    # pass at i32).  v5e VERDICT (2026-08-01 on-chip): narrow compares
-    # are DEAD on this hardware — u8 iota doesn't lower, 16-bit iota is
-    # "not supported by hardware", and even with the i32-iota+downcast
-    # construction below both i16 and bf16 fail Mosaic compile with
-    # "Target does not support this comparison".  i32 is the default
-    # and the only mode known to compile on v5e; the narrow paths stay
-    # for backends whose VPU does support them.
-    import os as _os
-    _env = _os.environ.get("LIGHTGBM_TPU_ONEHOT_DTYPE", "")
-    if _env == "u8":
-        # no u8 iota on Mosaic and no u8 vector compare on v5e — route
-        # to i16 (itself v5e-dead but the nearest requested intent)
-        # instead of crashing deep in kernel compilation
-        from ..utils.log import log_warning
-        log_warning("LIGHTGBM_TPU_ONEHOT_DTYPE=u8 does not lower on "
-                    "this backend; using i16")
-        _env = "i16"
-    cmp_dtype = {"bf16": jnp.bfloat16, "i16": jnp.int16}.get(
-        _env, jnp.int32)
-
-    build = onehot_build
-    if build == "twolevel" and (B & (B - 1) or B < 4):
-        build = "iota"   # two-level needs a power-of-two bin count
-
     def one_chunk(c, carry):
         wc = wfn(c, chunk)                                  # [8, chunk]
         for p0 in range(0, Fp, fblk):
             np_ = min(fblk, Fp - p0)
             b = binsT_ref[p0:p0 + np_, pl.ds(c * chunk, chunk)]
             if packed4:
-                # unpack nibbles in integer space (bitwise ops are not
-                # defined for the bf16 compare dtype), then cast
                 bi = b.astype(jnp.int32)
                 b = jnp.stack([bi & 15, bi >> 4], axis=1).reshape(
                     2 * np_, chunk)
             nf = b.shape[0]
-            if build == "gather":
-                eye = jnp.eye(B, dtype=jnp.bfloat16)
-                oh = jnp.take(eye, b.astype(jnp.int32).reshape(-1),
-                              axis=0)                  # [nf*chunk, B]
-                onehot = oh.reshape(nf, chunk, B).transpose(
-                    0, 2, 1).reshape(nf * B, chunk)
-            elif build == "twolevel":
-                s = (B.bit_length() - 1) // 2
-                Bl = 1 << s
-                Bh = B // Bl
-                bi = b.astype(jnp.int32)
-                ih = lax.broadcasted_iota(jnp.int32, (nf, Bh, chunk), 1)
-                il = lax.broadcasted_iota(jnp.int32, (nf, Bl, chunk), 1)
-                oh_hi = ((bi >> s)[:, None, :] == ih).astype(jnp.bfloat16)
-                oh_lo = ((bi & (Bl - 1))[:, None, :] == il).astype(
-                    jnp.bfloat16)
-                onehot = (oh_hi[:, :, None, :]
-                          * oh_lo[:, None, :, :]).reshape(nf * B, chunk)
-            else:
-                # narrow compare dtypes: v5e has no 16-bit iota ("16-bit
-                # iota not supported by hardware") and no direct u8->bf16
-                # convert — build both sides from i32/f32 with supported
-                # single-step converts
-                iota32 = lax.broadcasted_iota(jnp.int32, (nf, B, chunk), 1)
-                if cmp_dtype == jnp.bfloat16:
-                    b = b.astype(jnp.int32).astype(jnp.float32).astype(
-                        jnp.bfloat16)
-                    iota = iota32.astype(jnp.float32).astype(jnp.bfloat16)
-                elif cmp_dtype == jnp.int16:
-                    b = b.astype(jnp.int32).astype(jnp.int16)
-                    iota = iota32.astype(jnp.int16)
-                else:
-                    b = b.astype(cmp_dtype)
-                    iota = iota32
-                onehot = (b[:, None, :] == iota).astype(
-                    jnp.bfloat16).reshape(nf * B, chunk)
+            iota = lax.broadcasted_iota(jnp.int32, (nf, B, chunk), 1)
+            onehot = (b.astype(jnp.int32)[:, None, :] == iota).astype(
+                jnp.bfloat16).reshape(nf * B, chunk)
             f0 = (2 * p0 if packed4 else p0)
             acc_ref[f0 * B:(f0 + nf) * B] += lax.dot_general(
                 onehot, wc, dimension_numbers=(((1,), (1,)), ((), ())),
@@ -449,12 +259,10 @@ def _accumulate_block(binsT_ref, wfn, acc_ref, num_bins, packed4=False,
     lax.fori_loop(0, n_chunks // unroll, body, 0)
 
 
-def _kernel_all(binsT_ref, w_ref, out_ref, acc_ref, *, num_bins, packed4,
-                onehot_build="iota"):
+def _kernel_all(binsT_ref, w_ref, out_ref, acc_ref, *, num_bins, packed4):
     # w_ref may carry MULTIPLE 8-channel sets ([8*C, rb]): the matmul
     # output widens to 8*C and each set accumulates independently — used
     # to histogram all C class-trees' roots in one pass (multiclass).
-    # An i32 w_ref is the packed-accumulator stream ([2, rb] -> 4 lanes).
     i = pl.program_id(0)
 
     @pl.when(i == 0)
@@ -462,11 +270,9 @@ def _kernel_all(binsT_ref, w_ref, out_ref, acc_ref, *, num_bins, packed4,
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
     def wfn(c, chunk):
-        wc = w_ref[:, pl.ds(c * chunk, chunk)]
-        return _packed_wrows(wc) if w_ref.dtype == jnp.int32 else wc
+        return w_ref[:, pl.ds(c * chunk, chunk)]
 
-    _accumulate_block(binsT_ref, wfn, acc_ref, num_bins, packed4,
-                      onehot_build)
+    _accumulate_block(binsT_ref, wfn, acc_ref, num_bins, packed4)
 
     @pl.when(i == pl.num_programs(0) - 1)
     def _():
@@ -474,7 +280,7 @@ def _kernel_all(binsT_ref, w_ref, out_ref, acc_ref, *, num_bins, packed4,
 
 
 def _kernel_segment(sref, binsT_ref, w_ref, lid_ref, out_ref, acc_ref, *,
-                    num_bins, packed4, onehot_build="iota"):
+                    num_bins, packed4):
     # sref: prefetched [3] i32 = (start_block, n_blocks, target_leaf)
     i = pl.program_id(0)
 
@@ -486,13 +292,10 @@ def _kernel_segment(sref, binsT_ref, w_ref, lid_ref, out_ref, acc_ref, *,
     def _():
         def wfn(c, chunk):
             wc = w_ref[:, pl.ds(c * chunk, chunk)]
-            if w_ref.dtype == jnp.int32:
-                wc = _packed_wrows(wc)
             lc = lid_ref[:, pl.ds(c * chunk, chunk)]
             return wc * (lc == sref[2]).astype(jnp.bfloat16)
 
-        _accumulate_block(binsT_ref, wfn, acc_ref, num_bins, packed4,
-                          onehot_build)
+        _accumulate_block(binsT_ref, wfn, acc_ref, num_bins, packed4)
 
     @pl.when(i == pl.num_programs(0) - 1)
     def _():
@@ -535,51 +338,7 @@ def gate_self_check(name: str, check) -> bool:
 
 @functools.partial(jax.jit,
                    static_argnames=("num_bins", "block_rows", "interpret",
-                                    "packed4", "onehot_build"))
-def _histogram_all(binsT: jax.Array, w8: jax.Array, num_bins: int,
-                   block_rows: int = 0,
-                   interpret: bool | None = None,
-                   packed4: bool = False,
-                   onehot_build: str = "iota") -> jax.Array:
-    F, n = binsT.shape
-    F_log = 2 * F if packed4 else F
-    CH = int(w8.shape[0])
-    if w8.dtype == jnp.int32:
-        # packed-accumulator stream: single channel set only (the
-        # multiclass batched-roots path keeps the f32 channels)
-        assert CH == 2, CH
-        C, och = 1, PACKED_CHANNELS
-    else:
-        assert CH % NUM_CHANNELS == 0, CH
-        C = CH // NUM_CHANNELS
-        och = CH
-    if block_rows <= 0:
-        block_rows = pick_block_rows(F_log, num_bins)
-    if interpret is None:
-        interpret = _interpret_default()
-    assert n % block_rows == 0, (n, block_rows)
-    out = pl.pallas_call(
-        functools.partial(_kernel_all, num_bins=num_bins, packed4=packed4,
-                          onehot_build=onehot_build),
-        out_shape=jax.ShapeDtypeStruct((F_log * num_bins, och),
-                                       jnp.float32),
-        grid=(n // block_rows,),
-        in_specs=[
-            pl.BlockSpec((F, block_rows), lambda i: (0, i)),
-            pl.BlockSpec((CH, block_rows), lambda i: (0, i)),
-        ],
-        out_specs=pl.BlockSpec((F_log * num_bins, och),
-                               lambda i: (0, 0)),
-        scratch_shapes=[pltpu.VMEM((F_log * num_bins, och), jnp.float32)],
-        interpret=interpret,
-    )(binsT, w8)
-    if C == 1:
-        return out.reshape(F_log, num_bins, och)
-    # [F*B, C*8] -> [C, F, B, 8]
-    return out.reshape(F_log, num_bins, C, NUM_CHANNELS).transpose(
-        2, 0, 1, 3)
-
-
+                                    "packed4"))
 def histogram_all(binsT: jax.Array, w8: jax.Array, num_bins: int,
                   block_rows: int = 0,
                   interpret: bool | None = None,
@@ -589,158 +348,67 @@ def histogram_all(binsT: jax.Array, w8: jax.Array, num_bins: int,
 
     ``w8`` may stack C independent 8-channel sets (multiclass batched
     roots: every class-tree's root histogram in ONE pass — C x fewer
-    full-data scans, and 8*C output columns fill more of the MXU tile),
-    or be the [2, Npad] i32 packed-accumulator stream
-    (quantize_pack_channels; output [F, B, PACKED_CHANNELS], rescale via
-    unpack_hist_packed).  Npad must be a multiple of ``block_rows``; pad
-    rows must carry zero weight channels (the bin values there may be
-    anything).  With ``packed4`` the bins hold two <=16-bin features per
-    byte and F here means PHYSICAL rows; the output has 2F logical
-    features.  The one-hot build (LIGHTGBM_TPU_ONEHOT_BUILD) is resolved
-    HERE, outside the jitted dispatch, so an env change can never be
-    masked by a stale jit cache entry.
+    full-data scans, and 8*C output columns fill more of the MXU tile).
+    Npad must be a multiple of ``block_rows``; pad rows must carry zero
+    weight channels (the bin values there may be anything).  With
+    ``packed4`` the bins hold two <=16-bin features per byte and F here
+    means PHYSICAL rows; the output has 2F logical features.
     """
-    return _histogram_all(binsT, w8, num_bins, block_rows, interpret,
-                          packed4, onehot_build_mode())
-
-
-def _segment_buckets(max_blocks: int) -> list:
-    """Static grid-size ladder for histogram_segment.
-
-    A pallas grid is static, but a leaf's confinement interval is data-
-    dependent: one kernel sized for max_blocks pays a skipped-but-not-free
-    grid step for every block outside the interval, which dominates late-
-    tree splits (intervals of a few blocks under a 300+-step grid burned
-    >1s/iter at 10.5M rows).  Instead the caller lax.switches between a
-    few size variants and runs the smallest one that covers the interval.
-
-    Every variant is a separate Mosaic compile on the backend, so the
-    ladder step trades per-iter skipped-step waste against compile
-    warmup; LIGHTGBM_TPU_BUCKET_STEP (default 8) tunes it on-chip.
-    """
-    import os
-    step = max(2, int(os.environ.get("LIGHTGBM_TPU_BUCKET_STEP", "8")))
-    buckets = []
-    b = max_blocks
-    while b > 1:
-        buckets.append(b)
-        b = max(1, b // step)
-    buckets.append(1)
-    return sorted(set(buckets))
-
-
-def bucket_index(bucket_list, n_blocks) -> jax.Array:
-    """Index of the smallest ladder bucket covering an ``n_blocks``-long
-    interval — THE smallest-covering rule.  Shared by the kernels'
-    ``lax.switch`` dispatch, ``segment_grid_size`` accounting, and the
-    growers' windowed routing so the three can never drift."""
-    nb = jnp.asarray(n_blocks, jnp.int32).reshape(())
-    return jnp.minimum(jnp.sum(jnp.asarray(bucket_list, jnp.int32) < nb),
-                       len(bucket_list) - 1)
-
-
-def segment_grid_size(bucket_arr: jax.Array, n_blocks) -> jax.Array:
-    """Grid steps the bucketed dispatch runs for an ``n_blocks``-long
-    interval — the same smallest-covering-bucket rule histogram_segment
-    and histogram_frontier apply (``bucket_arr`` is
-    ``jnp.asarray(_segment_buckets(max_blocks))``).  Lives here so the
-    growers' seg-stats grid accounting can never drift from the actual
-    dispatch."""
-    if dyn_grid_enabled():
-        # dynamic grids are sized exactly to the interval (min 1 step)
-        return jnp.maximum(jnp.asarray(n_blocks, jnp.int32), 1)
-    return bucket_arr[bucket_index(bucket_arr, n_blocks)]
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("num_bins", "block_rows", "grid_blocks",
-                                    "interpret", "packed4", "onehot_build"))
-def _histogram_segment_fixed(binsT: jax.Array, w8: jax.Array,
-                             leaf_id: jax.Array, start_block: jax.Array,
-                             n_blocks: jax.Array, target_leaf: jax.Array,
-                             num_bins: int, block_rows: int,
-                             grid_blocks: int,
-                             interpret: bool | None = None,
-                             packed4: bool = False,
-                             onehot_build: str = "iota") -> jax.Array:
-    """One static-grid variant; grid_blocks must be >= n_blocks."""
     F, n = binsT.shape
     F_log = 2 * F if packed4 else F
-    CHW = int(w8.shape[0])
-    och = PACKED_CHANNELS if w8.dtype == jnp.int32 else NUM_CHANNELS
+    CH = int(w8.shape[0])
+    assert CH % NUM_CHANNELS == 0, CH
+    C = CH // NUM_CHANNELS
+    if block_rows <= 0:
+        block_rows = pick_block_rows(F_log, num_bins)
     if interpret is None:
         interpret = _interpret_default()
-    max_blocks = n // block_rows
-    scalars = jnp.stack([start_block, n_blocks, target_leaf]).astype(
-        jnp.int32)
-
-    def im_data(i, s):
-        blk = jnp.minimum(s[0] + jnp.minimum(i, jnp.maximum(s[1] - 1, 0)),
-                          max_blocks - 1)
-        return (0, blk)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(grid_blocks,),
-        in_specs=[
-            pl.BlockSpec((F, block_rows), im_data),
-            pl.BlockSpec((CHW, block_rows), im_data),
-            pl.BlockSpec((1, block_rows), im_data),
-        ],
-        out_specs=pl.BlockSpec((F_log * num_bins, och),
-                               lambda i, s: (0, 0)),
-        scratch_shapes=[pltpu.VMEM((F_log * num_bins, och),
-                                   jnp.float32)],
-    )
+    assert n % block_rows == 0, (n, block_rows)
     out = pl.pallas_call(
-        functools.partial(_kernel_segment, num_bins=num_bins,
-                          packed4=packed4, onehot_build=onehot_build),
-        out_shape=jax.ShapeDtypeStruct((F_log * num_bins, och),
+        functools.partial(_kernel_all, num_bins=num_bins, packed4=packed4),
+        out_shape=jax.ShapeDtypeStruct((F_log * num_bins, CH),
                                        jnp.float32),
-        grid_spec=grid_spec,
+        grid=(n // block_rows,),
+        in_specs=[
+            pl.BlockSpec((F, block_rows), lambda i: (0, i)),
+            pl.BlockSpec((CH, block_rows), lambda i: (0, i)),
+        ],
+        out_specs=pl.BlockSpec((F_log * num_bins, CH),
+                               lambda i: (0, 0)),
+        scratch_shapes=[pltpu.VMEM((F_log * num_bins, CH), jnp.float32)],
         interpret=interpret,
-    )(scalars, binsT, w8, leaf_id.reshape(1, -1))
-    return out.reshape(F_log, num_bins, och)
-
-
-# Mosaic accepts traced grid dims (validated on a v5e 2026-07-31: strict
-# 10.5M probe 1.53 s/iter dyn vs 1.81-1.91 ladder; compiled for a
-# described v5e by tests/test_tpu_compile.py), so exact grids are the
-# default — one kernel compile instead of a bucket ladder, zero skipped
-# steps.  LIGHTGBM_TPU_DYN_GRID=0 restores the ladder.
-_DYN_GRID_DEFAULT = True
-
-
-def dyn_grid_enabled() -> bool:
-    """LIGHTGBM_TPU_DYN_GRID=1 dispatches segment/frontier histograms on
-    a DYNAMIC pallas grid sized exactly to the interval: one Mosaic
-    compile instead of a bucket-ladder of variants (less compile warmup)
-    and zero skipped grid steps.  =0 forces the bucket ladder."""
-    import os
-    env = os.environ.get("LIGHTGBM_TPU_DYN_GRID", "")
-    if env == "1":
-        return True
-    if env == "0":
-        return False
-    return _DYN_GRID_DEFAULT
+    )(binsT, w8)
+    if C == 1:
+        return out.reshape(F_log, num_bins, CH)
+    # [F*B, C*8] -> [C, F, B, 8]
+    return out.reshape(F_log, num_bins, C, NUM_CHANNELS).transpose(
+        2, 0, 1, 3)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("num_bins", "block_rows", "interpret",
-                                    "packed4", "onehot_build"))
-def _histogram_segment_dyn(binsT: jax.Array, w8: jax.Array,
-                           leaf_id: jax.Array, start_block: jax.Array,
-                           n_blocks: jax.Array, target_leaf: jax.Array,
-                           num_bins: int, block_rows: int,
-                           interpret: bool | None = None,
-                           packed4: bool = False,
-                           onehot_build: str = "iota") -> jax.Array:
-    """Dynamic-grid variant: the grid is the traced interval length, so
-    every step is in-range (no remapping, no skipped steps)."""
+                                    "packed4"))
+def histogram_segment(binsT: jax.Array, w8: jax.Array, leaf_id: jax.Array,
+                      start_block: jax.Array, n_blocks: jax.Array,
+                      target_leaf: jax.Array, num_bins: int,
+                      block_rows: int = 0,
+                      interpret: bool | None = None,
+                      packed4: bool = False) -> jax.Array:
+    """Histogram of one leaf, scanning only its confinement blocks.
+
+    ``leaf_id`` is [Npad] i32 row->leaf; rows outside the leaf (or padding,
+    which must carry zero weights) contribute nothing.  DMA, compute AND
+    grid length are proportional to ``n_blocks``, not N: the grid is the
+    traced interval length (Mosaic accepts traced grid dims), so every
+    step is in range and one kernel is compiled.  An empty interval runs
+    one masked step and returns zeros.  Returns [F, B, 8] (logical
+    features when ``packed4``).
+    """
     F, n = binsT.shape
     F_log = 2 * F if packed4 else F
-    CHW = int(w8.shape[0])
-    och = PACKED_CHANNELS if w8.dtype == jnp.int32 else NUM_CHANNELS
+    if block_rows <= 0:
+        block_rows = pick_block_rows(F_log, num_bins)
+    assert n % block_rows == 0, (n, block_rows)
     if interpret is None:
         interpret = _interpret_default()
     max_blocks = n // block_rows
@@ -753,73 +421,26 @@ def _histogram_segment_dyn(binsT: jax.Array, w8: jax.Array,
     def im_data(i, s):
         return (0, jnp.minimum(s[0] + i, max_blocks - 1))
 
+    acc_shape = (F_log * num_bins, NUM_CHANNELS)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(grid_n,),
         in_specs=[
             pl.BlockSpec((F, block_rows), im_data),
-            pl.BlockSpec((CHW, block_rows), im_data),
+            pl.BlockSpec((NUM_CHANNELS, block_rows), im_data),
             pl.BlockSpec((1, block_rows), im_data),
         ],
-        out_specs=pl.BlockSpec((F_log * num_bins, och),
-                               lambda i, s: (0, 0)),
-        scratch_shapes=[pltpu.VMEM((F_log * num_bins, och),
-                                   jnp.float32)],
+        out_specs=pl.BlockSpec(acc_shape, lambda i, s: (0, 0)),
+        scratch_shapes=[pltpu.VMEM(acc_shape, jnp.float32)],
     )
     out = pl.pallas_call(
         functools.partial(_kernel_segment, num_bins=num_bins,
-                          packed4=packed4, onehot_build=onehot_build),
-        out_shape=jax.ShapeDtypeStruct((F_log * num_bins, och),
-                                       jnp.float32),
+                          packed4=packed4),
+        out_shape=jax.ShapeDtypeStruct(acc_shape, jnp.float32),
         grid_spec=grid_spec,
         interpret=interpret,
     )(scalars, binsT, w8, leaf_id.reshape(1, -1))
-    return out.reshape(F_log, num_bins, och)
-
-
-def histogram_segment(binsT: jax.Array, w8: jax.Array, leaf_id: jax.Array,
-                      start_block: jax.Array, n_blocks: jax.Array,
-                      target_leaf: jax.Array, num_bins: int,
-                      block_rows: int = 0,
-                      interpret: bool | None = None,
-                      packed4: bool = False) -> jax.Array:
-    """Histogram of one leaf, scanning only its confinement blocks.
-
-    ``leaf_id`` is [Npad] i32 row->leaf; rows outside the leaf (or padding,
-    which must carry zero weights) contribute nothing.  DMA, compute AND
-    grid length are proportional to ``n_blocks``, not N: the call
-    dispatches to the smallest static-grid variant covering the interval
-    (``_segment_buckets``).  Returns [F, B, 8] (logical features when
-    ``packed4``).
-    """
-    F, n = binsT.shape
-    if block_rows <= 0:
-        block_rows = pick_block_rows(2 * F if packed4 else F, num_bins)
-    assert n % block_rows == 0, (n, block_rows)
-    max_blocks = n // block_rows
-    ob = onehot_build_mode()
-    if dyn_grid_enabled():
-        return _histogram_segment_dyn(binsT, w8, leaf_id,
-                                      jnp.asarray(start_block, jnp.int32),
-                                      jnp.asarray(n_blocks, jnp.int32),
-                                      target_leaf, num_bins, block_rows,
-                                      interpret, packed4, ob)
-    buckets = _segment_buckets(max_blocks)
-    if len(buckets) == 1:
-        return _histogram_segment_fixed(binsT, w8, leaf_id, start_block,
-                                        n_blocks, target_leaf, num_bins,
-                                        block_rows, buckets[0], interpret,
-                                        packed4, ob)
-    n_blocks = jnp.asarray(n_blocks, jnp.int32)
-    idx = bucket_index(buckets, n_blocks)
-    branches = [
-        (lambda gb: lambda b, w, l, s0, nb, tl: _histogram_segment_fixed(
-            b, w, l, s0, nb, tl, num_bins, block_rows, gb, interpret,
-            packed4, ob))(gb)
-        for gb in buckets
-    ]
-    return jax.lax.switch(idx, branches, binsT, w8, leaf_id, start_block,
-                          n_blocks, target_leaf)
+    return out.reshape(F_log, num_bins, NUM_CHANNELS)
 
 
 _FRONTIER_K = 16   # leaves per batched kernel call: 8 channels x 16 = 128
@@ -853,7 +474,7 @@ def channel_set_capacity(num_features: int, num_bins: int,
 
 
 def _kernel_frontier(sref, binsT_ref, w_ref, lid_ref, out_ref, acc_ref, *,
-                     num_bins, K, packed4, onehot_build="iota"):
+                     num_bins, K, packed4):
     """K-leaf batched histogram: one [F*B, 8K] accumulator, the one-hot
     matmul's output dim carries K leaves' channel sets — the structural
     fix for the 8-wide output that capped MXU utilization at ~6%
@@ -875,8 +496,6 @@ def _kernel_frontier(sref, binsT_ref, w_ref, lid_ref, out_ref, acc_ref, *,
     def _():
         def wfn(c, chunk):
             wc = w_ref[:, pl.ds(c * chunk, chunk)]          # [8, chunk]
-            if w_ref.dtype == jnp.int32:
-                wc = _packed_wrows(wc)   # packed stream -> [4, chunk]
             lc = lid_ref[:, pl.ds(c * chunk, chunk)]        # [1, chunk]
             # K is static, so the target loads unroll into K SCALAR reads
             # (Mosaic rejects vector loads from SMEM — sref[2:2+K] lowers
@@ -888,8 +507,7 @@ def _kernel_frontier(sref, binsT_ref, w_ref, lid_ref, out_ref, acc_ref, *,
                 rows.append(mask * wc)                      # [8, chunk]
             return jnp.concatenate(rows, axis=0)            # [8K, chunk]
 
-        _accumulate_block(binsT_ref, wfn, acc_ref, num_bins, packed4,
-                          onehot_build)
+        _accumulate_block(binsT_ref, wfn, acc_ref, num_bins, packed4)
 
     @pl.when(i == pl.num_programs(0) - 1)
     def _():
@@ -897,115 +515,8 @@ def _kernel_frontier(sref, binsT_ref, w_ref, lid_ref, out_ref, acc_ref, *,
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("num_bins", "block_rows", "grid_blocks",
-                                    "K", "interpret", "packed4",
-                                    "onehot_build"))
-def _histogram_frontier_fixed(binsT: jax.Array, w8: jax.Array,
-                              leaf_id: jax.Array, block_list: jax.Array,
-                              n_blocks: jax.Array, targets: jax.Array,
-                              num_bins: int, block_rows: int,
-                              grid_blocks: int, K: int,
-                              interpret: bool | None = None,
-                              packed4: bool = False,
-                              onehot_build: str = "iota") -> jax.Array:
-    F, n = binsT.shape
-    F_log = 2 * F if packed4 else F
-    CHW = int(w8.shape[0])
-    och = PACKED_CHANNELS if w8.dtype == jnp.int32 else NUM_CHANNELS
-    if interpret is None:
-        interpret = _interpret_default()
-    max_blocks = n // block_rows
-    bl = jnp.pad(block_list.astype(jnp.int32),
-                 (0, max(0, grid_blocks - block_list.shape[0])))[:grid_blocks]
-    scalars = jnp.concatenate([
-        jnp.stack([n_blocks.astype(jnp.int32), jnp.int32(0)]),
-        targets.astype(jnp.int32), bl])
-
-    def im_data(i, s):
-        # out-of-range grid steps re-read the last in-range block (no new
-        # DMA); pl.when skips their compute
-        idx = jnp.minimum(i, jnp.maximum(s[0] - 1, 0))
-        return (0, jnp.minimum(s[2 + K + idx], max_blocks - 1))
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(grid_blocks,),
-        in_specs=[
-            pl.BlockSpec((F, block_rows), im_data),
-            pl.BlockSpec((CHW, block_rows), im_data),
-            pl.BlockSpec((1, block_rows), im_data),
-        ],
-        out_specs=pl.BlockSpec((F_log * num_bins, K * och),
-                               lambda i, s: (0, 0)),
-        scratch_shapes=[pltpu.VMEM((F_log * num_bins, K * och),
-                                   jnp.float32)],
-    )
-    out = pl.pallas_call(
-        functools.partial(_kernel_frontier, num_bins=num_bins, K=K,
-                          packed4=packed4, onehot_build=onehot_build),
-        out_shape=jax.ShapeDtypeStruct((F_log * num_bins, K * och),
-                                       jnp.float32),
-        grid_spec=grid_spec,
-        interpret=interpret,
-    )(scalars, binsT, w8, leaf_id.reshape(1, -1))
-    # [F*B, K*8] -> [K, F, B, 8]
-    return out.reshape(F_log, num_bins, K, och).transpose(
-        2, 0, 1, 3)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("num_bins", "block_rows", "K",
-                                    "interpret", "packed4", "onehot_build"))
-def _histogram_frontier_dyn(binsT: jax.Array, w8: jax.Array,
-                            leaf_id: jax.Array, block_list: jax.Array,
-                            n_blocks: jax.Array, targets: jax.Array,
-                            num_bins: int, block_rows: int, K: int,
-                            interpret: bool | None = None,
-                            packed4: bool = False,
-                            onehot_build: str = "iota") -> jax.Array:
-    """Dynamic-grid frontier variant: grid == union size, one compile."""
-    F, n = binsT.shape
-    F_log = 2 * F if packed4 else F
-    CHW = int(w8.shape[0])
-    och = PACKED_CHANNELS if w8.dtype == jnp.int32 else NUM_CHANNELS
-    if interpret is None:
-        interpret = _interpret_default()
-    max_blocks = n // block_rows
-    grid_n = jnp.clip(n_blocks, 1, max_blocks).astype(jnp.int32)
-    bl = block_list.astype(jnp.int32)[:max_blocks]
-    scalars = jnp.concatenate([
-        jnp.stack([n_blocks.astype(jnp.int32), jnp.int32(0)]),
-        targets.astype(jnp.int32), bl])
-
-    def im_data(i, s):
-        idx = jnp.minimum(i, jnp.maximum(s[0] - 1, 0))
-        return (0, jnp.minimum(s[2 + K + idx], max_blocks - 1))
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(grid_n,),
-        in_specs=[
-            pl.BlockSpec((F, block_rows), im_data),
-            pl.BlockSpec((CHW, block_rows), im_data),
-            pl.BlockSpec((1, block_rows), im_data),
-        ],
-        out_specs=pl.BlockSpec((F_log * num_bins, K * och),
-                               lambda i, s: (0, 0)),
-        scratch_shapes=[pltpu.VMEM((F_log * num_bins, K * och),
-                                   jnp.float32)],
-    )
-    out = pl.pallas_call(
-        functools.partial(_kernel_frontier, num_bins=num_bins, K=K,
-                          packed4=packed4, onehot_build=onehot_build),
-        out_shape=jax.ShapeDtypeStruct((F_log * num_bins, K * och),
-                                       jnp.float32),
-        grid_spec=grid_spec,
-        interpret=interpret,
-    )(scalars, binsT, w8, leaf_id.reshape(1, -1))
-    return out.reshape(F_log, num_bins, K, och).transpose(
-        2, 0, 1, 3)
-
-
+                   static_argnames=("num_bins", "block_rows", "interpret",
+                                    "packed4"))
 def histogram_frontier(binsT: jax.Array, w8: jax.Array, leaf_id: jax.Array,
                        block_list: jax.Array, n_blocks: jax.Array,
                        targets: jax.Array, num_bins: int,
@@ -1017,37 +528,53 @@ def histogram_frontier(binsT: jax.Array, w8: jax.Array, leaf_id: jax.Array,
     ``block_list`` [M] i32 lists the row blocks to scan (union of the K
     leaves' confinement intervals; entries past ``n_blocks`` are ignored);
     ``targets`` [K] i32 are the leaf ids (-1 entries produce zero
-    histograms — masks never match, since real leaf ids are >= 0).
-    Returns [K, F, B, 8] (logical features when ``packed4``).
+    histograms — masks never match, since real leaf ids are >= 0).  The
+    grid is the traced union size (one compile); ``n_blocks`` 0 runs one
+    masked step and returns zeros.  Returns [K, F, B, 8] (logical
+    features when ``packed4``).
     """
     F, n = binsT.shape
     K = int(targets.shape[0])
+    F_log = 2 * F if packed4 else F
     if block_rows <= 0:
-        block_rows = pick_block_rows(2 * F if packed4 else F, num_bins)
+        block_rows = pick_block_rows(F_log, num_bins)
     assert n % block_rows == 0, (n, block_rows)
+    if interpret is None:
+        interpret = _interpret_default()
     max_blocks = n // block_rows
-    ob = onehot_build_mode()
-    if dyn_grid_enabled():
-        return _histogram_frontier_dyn(binsT, w8, leaf_id, block_list,
-                                       jnp.asarray(n_blocks, jnp.int32),
-                                       targets, num_bins, block_rows, K,
-                                       interpret, packed4, ob)
-    cap = min(int(block_list.shape[0]), max_blocks)
-    buckets = _segment_buckets(cap)
     n_blocks = jnp.asarray(n_blocks, jnp.int32)
-    if len(buckets) == 1:
-        return _histogram_frontier_fixed(
-            binsT, w8, leaf_id, block_list, n_blocks, targets, num_bins,
-            block_rows, buckets[0], K, interpret, packed4, ob)
-    idx = jnp.sum(jnp.asarray(buckets, jnp.int32) < n_blocks)
-    branches = [
-        (lambda gb: lambda b, w, l, bl, nb, tg: _histogram_frontier_fixed(
-            b, w, l, bl, nb, tg, num_bins, block_rows, gb, K, interpret,
-            packed4, ob))(gb)
-        for gb in buckets
-    ]
-    return jax.lax.switch(idx, branches, binsT, w8, leaf_id, block_list,
-                          n_blocks, targets)
+    grid_n = jnp.clip(n_blocks, 1, max_blocks).astype(jnp.int32)
+    bl = block_list.astype(jnp.int32)[:max_blocks]
+    scalars = jnp.concatenate([
+        jnp.stack([n_blocks, jnp.int32(0)]),
+        targets.astype(jnp.int32), bl])
+
+    def im_data(i, s):
+        idx = jnp.minimum(i, jnp.maximum(s[0] - 1, 0))
+        return (0, jnp.minimum(s[2 + K + idx], max_blocks - 1))
+
+    acc_shape = (F_log * num_bins, K * NUM_CHANNELS)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(grid_n,),
+        in_specs=[
+            pl.BlockSpec((F, block_rows), im_data),
+            pl.BlockSpec((NUM_CHANNELS, block_rows), im_data),
+            pl.BlockSpec((1, block_rows), im_data),
+        ],
+        out_specs=pl.BlockSpec(acc_shape, lambda i, s: (0, 0)),
+        scratch_shapes=[pltpu.VMEM(acc_shape, jnp.float32)],
+    )
+    out = pl.pallas_call(
+        functools.partial(_kernel_frontier, num_bins=num_bins, K=K,
+                          packed4=packed4),
+        out_shape=jax.ShapeDtypeStruct(acc_shape, jnp.float32),
+        grid_spec=grid_spec,
+        interpret=interpret,
+    )(scalars, binsT, w8, leaf_id.reshape(1, -1))
+    # [F*B, K*8] -> [K, F, B, 8]
+    return out.reshape(F_log, num_bins, K, NUM_CHANNELS).transpose(
+        2, 0, 1, 3)
 
 
 # ---------------------------------------------------------------------------
@@ -1066,10 +593,11 @@ def histogram_frontier(binsT: jax.Array, w8: jax.Array, leaf_id: jax.Array,
 # traffic per call, noise next to the pass itself.  leaf_id is
 # an aliased input/output: blocks outside the interval are never written and
 # keep their values; the route update is idempotent (rows moved to new_leaf
-# stop matching leaf), so out-of-range grid-step remapping to the last
-# in-range block stays correct even when a revisited block re-reads
-# post-write data.  Reference analog: routing rides the partition work the
-# histogram already pays for (src/treelearner/data_partition.hpp:111).
+# stop matching leaf), so a block visited again (by a later feature tile,
+# or by the one step an empty interval still runs) stays correct even when
+# it re-reads post-write data.  Reference analog: routing rides the
+# partition work the histogram already pays for
+# (src/treelearner/data_partition.hpp:111).
 # ---------------------------------------------------------------------------
 
 _ROUTE_WORDS = 19  # leaf,new_leaf,row,col,thr,dl,cat,mt,dbin,nbf,off + 8 bitset
@@ -1153,8 +681,7 @@ def _route_block_ids(sref, o: int, frow, lid, packed4: bool):
 
 def _kernel_segment_routed(sref, binsT_ref, w_ref, frow_ref, lid_ref,
                            lid_out_ref, out_ref, acc_ref, *,
-                           num_bins, packed4, onehot_build="iota",
-                           block_axis=0):
+                           num_bins, packed4, block_axis=0):
     # sref: [3 + _ROUTE_WORDS] = (start_block, n_blocks, target_leaf, route)
     # block_axis 1: grid (feature tiles, blocks), one tile's columns in
     # binsT_ref / acc_ref / out_ref, every tile walking the same blocks
@@ -1164,10 +691,9 @@ def _kernel_segment_routed(sref, binsT_ref, w_ref, frow_ref, lid_ref,
     def _():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    # 1) route this block — unconditional: skipped steps revisit an
-    # in-range block and the update is idempotent (so is a later tile's:
-    # it finds the block routed, or routes the ids it prefetched before
-    # the write landed, to the same values)
+    # 1) route this block — unconditional: the update is idempotent (so
+    # is a later tile's: it finds the block routed, or routes the ids it
+    # prefetched before the write landed, to the same values)
     lid_out_ref[...] = _route_block_ids(sref, 3, frow_ref[...],
                                         lid_ref[...], packed4)
 
@@ -1176,13 +702,10 @@ def _kernel_segment_routed(sref, binsT_ref, w_ref, frow_ref, lid_ref,
     def _():
         def wfn(c, chunk):
             wc = w_ref[:, pl.ds(c * chunk, chunk)]
-            if w_ref.dtype == jnp.int32:
-                wc = _packed_wrows(wc)
             lc = lid_out_ref[:, pl.ds(c * chunk, chunk)]
             return wc * (lc == sref[2]).astype(jnp.bfloat16)
 
-        _accumulate_block(binsT_ref, wfn, acc_ref, num_bins, packed4,
-                          onehot_build)
+        _accumulate_block(binsT_ref, wfn, acc_ref, num_bins, packed4)
 
     @pl.when(i == pl.num_programs(block_axis) - 1)
     def _():
@@ -1222,7 +745,7 @@ def _tile_rows(binsT: jax.Array, num_bins: int, packed4: bool,
 
 @functools.partial(jax.jit,
                    static_argnames=("num_bins", "block_rows", "interpret",
-                                    "packed4", "onehot_build", "tile_rows"))
+                                    "packed4", "tile_rows"))
 def _histogram_segment_routed(binsT: jax.Array, w8: jax.Array,
                               leaf_id: jax.Array, start_block: jax.Array,
                               n_blocks: jax.Array, target_leaf: jax.Array,
@@ -1230,12 +753,9 @@ def _histogram_segment_routed(binsT: jax.Array, w8: jax.Array,
                               block_rows: int = 0,
                               interpret: bool | None = None,
                               packed4: bool = False,
-                              onehot_build: str = "iota",
                               tile_rows: int = 0):
     F, n = binsT.shape
     F_log = 2 * F if packed4 else F
-    CHW = int(w8.shape[0])
-    och = PACKED_CHANNELS if w8.dtype == jnp.int32 else NUM_CHANNELS
     if block_rows <= 0:
         block_rows = pick_block_rows(F_log, num_bins)
     assert n % block_rows == 0, (n, block_rows)
@@ -1244,8 +764,7 @@ def _histogram_segment_routed(binsT: jax.Array, w8: jax.Array,
     if tile_rows:
         return _histogram_segment_routed_tiled(
             binsT, w8, leaf_id, start_block, n_blocks, target_leaf, route,
-            num_bins, block_rows, interpret, packed4, onehot_build,
-            tile_rows)
+            num_bins, block_rows, interpret, packed4, tile_rows)
     max_blocks = n // block_rows
     grid_n = jnp.clip(n_blocks, 1, max_blocks).astype(jnp.int32)
     scalars = jnp.concatenate([
@@ -1262,23 +781,23 @@ def _histogram_segment_routed(binsT: jax.Array, w8: jax.Array,
         grid=(grid_n,),
         in_specs=[
             pl.BlockSpec((F, block_rows), im_data),
-            pl.BlockSpec((CHW, block_rows), im_data),
+            pl.BlockSpec((NUM_CHANNELS, block_rows), im_data),
             pl.BlockSpec((1, block_rows), im_data),
             pl.BlockSpec((1, block_rows), im_data),
         ],
         out_specs=[
             pl.BlockSpec((1, block_rows), im_data),
-            pl.BlockSpec((F_log * num_bins, och),
+            pl.BlockSpec((F_log * num_bins, NUM_CHANNELS),
                          lambda i, s: (0, 0)),
         ],
-        scratch_shapes=[pltpu.VMEM((F_log * num_bins, och),
+        scratch_shapes=[pltpu.VMEM((F_log * num_bins, NUM_CHANNELS),
                                    jnp.float32)],
     )
     lid_out, hist = pl.pallas_call(
         functools.partial(_kernel_segment_routed, num_bins=num_bins,
-                          packed4=packed4, onehot_build=onehot_build),
+                          packed4=packed4),
         out_shape=[jax.ShapeDtypeStruct((1, n), jnp.int32),
-                   jax.ShapeDtypeStruct((F_log * num_bins, och),
+                   jax.ShapeDtypeStruct((F_log * num_bins, NUM_CHANNELS),
                                         jnp.float32)],
         grid_spec=grid_spec,
         # alias indices include the scalar operand: input 4 is leaf_id
@@ -1295,13 +814,13 @@ def _histogram_segment_routed(binsT: jax.Array, w8: jax.Array,
         # breakdown): pinned, so renaming this function cannot move it
         name="_histogram_segment_routed",
     )(scalars, binsT, w8, frow, leaf_id.reshape(1, -1))
-    return lid_out[0], hist.reshape(F_log, num_bins, och)
+    return lid_out[0], hist.reshape(F_log, num_bins, NUM_CHANNELS)
 
 
 def _histogram_segment_routed_tiled(binsT, w8, leaf_id, start_block,
                                     n_blocks, target_leaf, route, num_bins,
                                     block_rows, interpret, packed4,
-                                    onehot_build, tile_rows):
+                                    tile_rows):
     """``_histogram_segment_routed`` over a table too wide for one
     accumulator: grid (feature tiles, blocks).  Each tile accumulates its
     ``tile_rows`` bin rows over the whole interval into its own
@@ -1316,8 +835,6 @@ def _histogram_segment_routed_tiled(binsT, w8, leaf_id, start_block,
     n_tiles = F // T
     F_log = 2 * F if packed4 else F
     T_log = 2 * T if packed4 else T
-    CHW = int(w8.shape[0])
-    och = PACKED_CHANNELS if w8.dtype == jnp.int32 else NUM_CHANNELS
     max_blocks = n // block_rows
     grid_n = jnp.clip(n_blocks, 1, max_blocks).astype(jnp.int32)
     scalars = jnp.concatenate([
@@ -1331,23 +848,24 @@ def _histogram_segment_routed_tiled(binsT, w8, leaf_id, start_block,
         grid=(n_tiles, grid_n),
         in_specs=[
             pl.BlockSpec((T, block_rows), im_tile),
-            pl.BlockSpec((CHW, block_rows), im_row),
+            pl.BlockSpec((NUM_CHANNELS, block_rows), im_row),
             pl.BlockSpec((1, block_rows), im_row),
             pl.BlockSpec((1, block_rows), im_row),
         ],
         out_specs=[
             pl.BlockSpec((1, block_rows), im_row),
-            pl.BlockSpec((T_log * num_bins, och), lambda t, i, s: (t, 0)),
+            pl.BlockSpec((T_log * num_bins, NUM_CHANNELS),
+                         lambda t, i, s: (t, 0)),
         ],
-        scratch_shapes=[pltpu.VMEM((T_log * num_bins, och), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((T_log * num_bins, NUM_CHANNELS),
+                                   jnp.float32)],
     )
     with jax.named_scope("tile_walk"):
         lid_out, hist = pl.pallas_call(
             functools.partial(_kernel_segment_routed, num_bins=num_bins,
-                              packed4=packed4, onehot_build=onehot_build,
-                              block_axis=1),
+                              packed4=packed4, block_axis=1),
             out_shape=[jax.ShapeDtypeStruct((1, n), jnp.int32),
-                       jax.ShapeDtypeStruct((F_log * num_bins, och),
+                       jax.ShapeDtypeStruct((F_log * num_bins, NUM_CHANNELS),
                                             jnp.float32)],
             grid_spec=grid_spec,
             input_output_aliases={4: 0},
@@ -1358,7 +876,7 @@ def _histogram_segment_routed_tiled(binsT, w8, leaf_id, start_block,
             # the routed segment pass, tile by tile: the trace keeps its name
             name="_histogram_segment_routed",
         )(scalars, binsT, w8, frow, leaf_id.reshape(1, -1))
-    return lid_out[0], hist.reshape(F_log, num_bins, och)
+    return lid_out[0], hist.reshape(F_log, num_bins, NUM_CHANNELS)
 
 
 def histogram_segment_routed(binsT: jax.Array, w8: jax.Array,
@@ -1375,16 +893,14 @@ def histogram_segment_routed(binsT: jax.Array, w8: jax.Array,
     ``route`` is a [_ROUTE_WORDS] i32 descriptor (pack_route /
     null_route).  Returns ``(leaf_id', [F, B, 8] hist)`` where the ids
     are post-route over the whole array (blocks outside the interval
-    keep their values via input/output aliasing); a [2, Npad] i32
-    ``w8`` runs the packed-accumulator stream ([F, B, 4] output).
-    Dynamic-grid only — callers needing the bucket ladder use the
-    unfused pair.  A table wider than one accumulator holds
+    keep their values via input/output aliasing).  A table wider than
+    one accumulator holds
     (``feature_tile``) is walked tile by tile, its bin rows padded to
     whole tiles by the caller.
     """
     return _histogram_segment_routed(
         binsT, w8, leaf_id, start_block, n_blocks, target_leaf, route,
-        num_bins, block_rows, interpret, packed4, onehot_build_mode(),
+        num_bins, block_rows, interpret, packed4,
         _tile_rows(binsT, num_bins, packed4, feature_tile_cols))
 
 
@@ -1485,7 +1001,7 @@ def _lookahead_masks(slots_ref, KP: int, g, lc, first, packed4: bool):
 
 def _kernel_segment_lookahead(sref, binsT_ref, w_ref, frow_ref, lid_ref,
                               slots_ref, *rest, num_bins, K, packed4,
-                              onehot_build="iota", block_axis=0):
+                              block_axis=0):
     # sref: [4 + _ROUTE_WORDS] = (start_block, n_blocks, target_leaf,
     #   route, n_acc): every block of the interval is routed, the first
     #   n_acc of them accumulate (n_blocks, or 0 for a pass that only
@@ -1541,7 +1057,7 @@ def _kernel_segment_lookahead(sref, binsT_ref, w_ref, frow_ref, lid_ref,
         # exact zero: s == hi, err == 0.
         acc_ref[:] = jnp.zeros_like(acc_ref)
         _accumulate_block(binsT_ref, wfn, acc_ref, num_bins, packed4,
-                          onehot_build, unroll=_LOOKAHEAD_UNROLL)
+                          unroll=_LOOKAHEAD_UNROLL)
         p = acc_ref[:]
         h = hi_ref[:]
         s = h + p
@@ -1556,7 +1072,7 @@ def _kernel_segment_lookahead(sref, binsT_ref, w_ref, frow_ref, lid_ref,
 
 @functools.partial(jax.jit,
                    static_argnames=("num_bins", "block_rows", "interpret",
-                                    "packed4", "onehot_build", "tile_rows"))
+                                    "packed4", "tile_rows"))
 def _histogram_segment_lookahead(binsT: jax.Array, w8: jax.Array,
                                  leaf_id: jax.Array, start_block: jax.Array,
                                  n_blocks: jax.Array, target_leaf: jax.Array,
@@ -1565,11 +1081,9 @@ def _histogram_segment_lookahead(binsT: jax.Array, w8: jax.Array,
                                  block_rows: int = 0,
                                  interpret: bool | None = None,
                                  packed4: bool = False,
-                                 onehot_build: str = "iota",
                                  tile_rows: int = 0):
     F, n = binsT.shape
     F_log = 2 * F if packed4 else F
-    assert w8.dtype != jnp.int32, "lookahead lane sets ride the f32 stream"
     och = NUM_CHANNELS
     K = 1 + int(slots.shape[0])
     assert K * och <= _LANES, K
@@ -1635,7 +1149,7 @@ def _histogram_segment_lookahead(binsT: jax.Array, w8: jax.Array,
             lid_out, hist = pl.pallas_call(
                 functools.partial(_kernel_segment_lookahead,
                                   num_bins=num_bins, K=K, packed4=packed4,
-                                  onehot_build=onehot_build, block_axis=1),
+                                  block_axis=1),
                 out_shape=[jax.ShapeDtypeStruct((1, n), jnp.int32),
                            jax.ShapeDtypeStruct((F_log * num_bins, K * och),
                                                 jnp.float32)],
@@ -1673,7 +1187,7 @@ def _histogram_segment_lookahead(binsT: jax.Array, w8: jax.Array,
     )
     lid_out, hist = pl.pallas_call(
         functools.partial(_kernel_segment_lookahead, num_bins=num_bins,
-                          K=K, packed4=packed4, onehot_build=onehot_build),
+                          K=K, packed4=packed4),
         out_shape=[jax.ShapeDtypeStruct((1, n), jnp.int32),
                    jax.ShapeDtypeStruct(acc_shape, jnp.float32)],
         grid_spec=grid_spec,
@@ -1713,28 +1227,23 @@ def histogram_segment_lookahead(binsT: jax.Array, w8: jax.Array,
     ``n_blocks``, or 0 for a call that only routes and returns zeros.
     Sums are f32 a block and an error-free (hi, lo) pair across blocks
     (``_kernel_segment_lookahead``), so lane set 0 agrees with the routed
-    kernel to f32 rounding, not bit for bit; f32 channel stream only.
+    kernel to f32 rounding, not bit for bit.
     Wide tables go tile by tile as in ``histogram_segment_routed``, each
     tile keeping its own (block sum, hi, lo) triple.
     """
     return _histogram_segment_lookahead(
         binsT, w8, leaf_id, jnp.asarray(start_block, jnp.int32),
         jnp.asarray(n_blocks, jnp.int32), target_leaf, route, slots, n_acc,
-        num_bins, block_rows, interpret, packed4, onehot_build_mode(),
+        num_bins, block_rows, interpret, packed4,
         _tile_rows(binsT, num_bins, packed4, feature_tile_cols))
 
 
 def _kernel_frontier_routed(sref, binsT_ref, w_ref, frows_ref, lid_ref,
                             lid_out_ref, out_ref, acc_ref, *, num_bins, K,
-                            packed4, onehot_build="iota", n_targets=0):
+                            packed4):
     # frows_ref: [K, rb] — the K split features' bin-row blocks
-    # sref: [2 + KT + K*_ROUTE_WORDS + n_grid] =
-    #   (n_blocks, pad, targets[KT], routes[K*19], block_list[n_grid])
-    # KT (n_targets) decouples the histogram width from the route count:
-    # the round-pass fusion histograms the K smaller children (KT == K),
-    # the fused-K kernel histograms ALL 2K children of the K routes
-    # (KT == 2K) so no parent gather / subtraction survives the round
-    KT = n_targets or K
+    # sref: [2 + K + K*_ROUTE_WORDS + n_grid] =
+    #   (n_blocks, pad, targets[K], routes[K*19], block_list[n_grid])
     i = pl.program_id(0)
 
     @pl.when(i == 0)
@@ -1747,26 +1256,23 @@ def _kernel_frontier_routed(sref, binsT_ref, w_ref, frows_ref, lid_ref,
     lid = lid_ref[...]
     frows = frows_ref[...]
     for k in range(K):
-        lid = _route_block_ids(sref, 2 + KT + k * _ROUTE_WORDS,
+        lid = _route_block_ids(sref, 2 + K + k * _ROUTE_WORDS,
                                frows[k:k + 1], lid, packed4)
     lid_out_ref[...] = lid
 
-    # 2) batched accumulate of the KT targets from the UPDATED ids
+    # 2) batched accumulate of the K targets from the UPDATED ids
     @pl.when(i < sref[0])
     def _():
         def wfn(c, chunk):
             wc = w_ref[:, pl.ds(c * chunk, chunk)]
-            if w_ref.dtype == jnp.int32:
-                wc = _packed_wrows(wc)
             lc = lid_out_ref[:, pl.ds(c * chunk, chunk)]
             rows = []
-            for k in range(KT):
+            for k in range(K):
                 mask = (lc == sref[2 + k]).astype(jnp.bfloat16)
                 rows.append(mask * wc)
             return jnp.concatenate(rows, axis=0)
 
-        _accumulate_block(binsT_ref, wfn, acc_ref, num_bins, packed4,
-                          onehot_build)
+        _accumulate_block(binsT_ref, wfn, acc_ref, num_bins, packed4)
 
     @pl.when(i == pl.num_programs(0) - 1)
     def _():
@@ -1775,24 +1281,26 @@ def _kernel_frontier_routed(sref, binsT_ref, w_ref, frows_ref, lid_ref,
 
 @functools.partial(jax.jit,
                    static_argnames=("num_bins", "block_rows", "K",
-                                    "interpret", "packed4", "onehot_build",
-                                    "n_targets"))
-def _histogram_frontier_routed(binsT: jax.Array, w8: jax.Array,
-                               leaf_id: jax.Array, block_list: jax.Array,
-                               n_blocks: jax.Array, targets: jax.Array,
-                               routes: jax.Array, num_bins: int,
-                               block_rows: int = 0, K: int = 0,
-                               interpret: bool | None = None,
-                               packed4: bool = False,
-                               onehot_build: str = "iota",
-                               n_targets: int = 0):
+                                    "interpret", "packed4"))
+def histogram_frontier_routed(binsT: jax.Array, w8: jax.Array,
+                              leaf_id: jax.Array, block_list: jax.Array,
+                              n_blocks: jax.Array, targets: jax.Array,
+                              routes: jax.Array, num_bins: int,
+                              block_rows: int = 0, K: int = 0,
+                              interpret: bool | None = None,
+                              packed4: bool = False):
+    """Frontier variant: apply K splits' routes and histogram the K
+    target leaves in one pass over the union block list.
+
+    ``routes`` is [K, _ROUTE_WORDS] i32 (invalid slots: null_route()).
+    The K split features' bin rows are pre-sliced into one [K, n]
+    operand (see the fused-route header comment).  Returns
+    ``(leaf_id', [K, F, B, 8])``.
+    """
     F, n = binsT.shape
     K = K or int(routes.shape[0])
-    KT = n_targets or K
-    assert int(targets.shape[0]) == KT, (targets.shape, KT)
+    assert int(targets.shape[0]) == K, (targets.shape, K)
     F_log = 2 * F if packed4 else F
-    CHW = int(w8.shape[0])
-    och = PACKED_CHANNELS if w8.dtype == jnp.int32 else NUM_CHANNELS
     if block_rows <= 0:
         block_rows = pick_block_rows(F_log, num_bins)
     assert n % block_rows == 0, (n, block_rows)
@@ -1805,7 +1313,7 @@ def _histogram_frontier_routed(binsT: jax.Array, w8: jax.Array,
         jnp.stack([n_blocks.astype(jnp.int32), jnp.int32(0)]),
         targets.astype(jnp.int32), routes.astype(jnp.int32).reshape(-1),
         bl])
-    blk_base = 2 + KT + K * _ROUTE_WORDS
+    blk_base = 2 + K + K * _ROUTE_WORDS
     # the K split features' physical bin rows (routes[:, 2]), pre-sliced
     # into one [K, n] operand (whole-sublane block: Mosaic-legal)
     frows = jnp.take(binsT, routes[:, 2].astype(jnp.int32), axis=0,
@@ -1820,93 +1328,38 @@ def _histogram_frontier_routed(binsT: jax.Array, w8: jax.Array,
         grid=(grid_n,),
         in_specs=[
             pl.BlockSpec((F, block_rows), im_data),
-            pl.BlockSpec((CHW, block_rows), im_data),
+            pl.BlockSpec((NUM_CHANNELS, block_rows), im_data),
             pl.BlockSpec((K, block_rows), im_data),
             pl.BlockSpec((1, block_rows), im_data),
         ],
         out_specs=[
             pl.BlockSpec((1, block_rows), im_data),
-            pl.BlockSpec((F_log * num_bins, KT * och),
+            pl.BlockSpec((F_log * num_bins, K * NUM_CHANNELS),
                          lambda i, s: (0, 0)),
         ],
-        scratch_shapes=[pltpu.VMEM((F_log * num_bins, KT * och),
+        scratch_shapes=[pltpu.VMEM((F_log * num_bins, K * NUM_CHANNELS),
                                    jnp.float32)],
     )
     lid_out, hist = pl.pallas_call(
         functools.partial(_kernel_frontier_routed, num_bins=num_bins, K=K,
-                          packed4=packed4, onehot_build=onehot_build,
-                          n_targets=KT),
+                          packed4=packed4),
         out_shape=[jax.ShapeDtypeStruct((1, n), jnp.int32),
                    jax.ShapeDtypeStruct((F_log * num_bins,
-                                         KT * och), jnp.float32)],
+                                         K * NUM_CHANNELS), jnp.float32)],
         grid_spec=grid_spec,
         # inputs: scalars, binsT, w8, frows, leaf_id
         input_output_aliases={4: 0},
         # see _histogram_segment_routed: the K frow rows + lid streams
         # exceed the 16 MB default scoped-vmem limit at K=16 production
-        # shapes — auto-sized from the computed need (the fused-K call
-        # carries a KT == 2K wide accumulator, so the limit follows KT)
+        # shapes — auto-sized from the computed need
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=fused_vmem_limit(F, num_bins, K, block_rows,
-                                              packed4, targets_k=KT)),
+                                              packed4)),
         interpret=interpret,
         name="_histogram_frontier_routed",     # as the trace shows it
     )(scalars, binsT, w8, frows, leaf_id.reshape(1, -1))
-    return lid_out[0], hist.reshape(F_log, num_bins, KT,
-                                    och).transpose(2, 0, 1, 3)
-
-
-def histogram_frontier_routed(binsT: jax.Array, w8: jax.Array,
-                              leaf_id: jax.Array, block_list: jax.Array,
-                              n_blocks: jax.Array, targets: jax.Array,
-                              routes: jax.Array, num_bins: int,
-                              block_rows: int = 0, K: int = 0,
-                              interpret: bool | None = None,
-                              packed4: bool = False):
-    """Frontier variant: apply K splits' routes and histogram the K
-    target leaves in one pass over the union block list.
-
-    ``routes`` is [K, _ROUTE_WORDS] i32 (invalid slots: null_route()).
-    The K split features' bin rows are pre-sliced into one [K, n]
-    operand (see the fused-route header comment).  Returns
-    ``(leaf_id', [K, F, B, 8])`` ([K, F, B, 4] for a packed i32 ``w8``).
-    """
-    return _histogram_frontier_routed(binsT, w8, leaf_id, block_list,
-                                      n_blocks, targets, routes, num_bins,
-                                      block_rows, K, interpret, packed4,
-                                      onehot_build_mode())
-
-
-def histogram_frontier_fusedk(binsT: jax.Array, w8: jax.Array,
-                              leaf_id: jax.Array, block_list: jax.Array,
-                              n_blocks: jax.Array, targets2: jax.Array,
-                              routes: jax.Array, num_bins: int,
-                              block_rows: int = 0, K: int = 0,
-                              interpret: bool | None = None,
-                              packed4: bool = False):
-    """Frontier-K fusion: apply the round's K routes AND histogram all
-    2K children in ONE pass over the union block list.
-
-    ``routes`` is [K, _ROUTE_WORDS] i32 (invalid slots: null_route());
-    ``targets2`` is [2K] i32 = (left children = the K routed parents,
-    which keep their leaf id, then right children = the K new leaves),
-    -1 skipping a slot.  Returns ``(leaf_id', [2K, F, B, 8])``
-    ([2K, F, B, 4] for a packed i32 ``w8``), child order matching
-    ``targets2`` — so the round needs NO parent histogram: both
-    children come straight off the data pass and the subtraction trick
-    plus both ``[L, G, B, 3]`` leaf_hist staging copies disappear.
-    Bit-identical to the unfused pair (route, then
-    ``histogram_frontier`` over the same 2K targets): the accumulator
-    columns per channel set are independent dot products of the same
-    one-hot blocks in the same chunk order.  Dynamic-grid only, like
-    every fused variant.
-    """
-    K = K or int(routes.shape[0])
-    assert int(targets2.shape[0]) == 2 * K, (targets2.shape, K)
-    return _histogram_frontier_routed(binsT, w8, leaf_id, block_list,
-                                      n_blocks, targets2, routes, num_bins,
-                                      block_rows, K, interpret, packed4,
-                                      onehot_build_mode(), n_targets=2 * K)
+    return lid_out[0], hist.reshape(F_log, num_bins, K,
+                                    NUM_CHANNELS).transpose(2, 0, 1, 3)
 
 
 _FUSED_VMEM_CAP = 64 * 1024 * 1024  # ceiling for the auto-sized limit
@@ -1936,8 +1389,8 @@ def _fused_vmem_est(F_phys: int, num_bins: int, K: int = 1,
     real 17.14 MB (v5e).  Shared by the ``fused_route_fits`` veto and
     the ``fused_vmem_limit`` auto-sizing so the two can never drift.
     ``targets_k`` widens the accumulator term independently of the
-    route count (the fused-K kernel carries 2K channel sets over K
-    routes); default = K, the round-pass fusion.  Memoized per
+    route count (the lookahead kernel carries K lane sets over one
+    route); default = K, the round-pass fusion.  Memoized per
     (K, KT, F, row_block) shape — policy + dispatch consult it on
     every grower build and the shape set per process is tiny."""
     F_log = 2 * F_phys if packed4 else F_phys
@@ -1982,8 +1435,7 @@ def fused_route_fits(F_phys: int, num_bins: int, K: int = 1,
     (measured: K=16, F=28, rb=32768 needs 17.14 MB against Mosaic's
     16 MB default), so the auto policy consults this conservative
     estimate against the auto-limit ceiling; LIGHTGBM_TPU_FUSED_ROUTE=1
-    / LIGHTGBM_TPU_FUSED_K=force bypass it for A/Bs on shapes it
-    vetoes."""
+    bypasses it for A/Bs on shapes it vetoes."""
     est = _fused_vmem_est(F_phys, num_bins, K, block_rows, packed4,
                           targets_k)
     return est <= int(0.9 * _FUSED_VMEM_CAP)
@@ -1991,108 +1443,27 @@ def fused_route_fits(F_phys: int, num_bins: int, K: int = 1,
 
 # build-time decisions, keyed "segment"/"frontier" — benches read this to
 # report the kernel that actually ran (the env gate + fits veto make the
-# bare self-check result misleading).  Values: False, True (K-target
-# round-pass fusion) or the string "fusedk" (2K-children fused-K kernel).
+# bare self-check result misleading).
 fused_route_decisions: dict = {}
-
-
-def fused_packed_optin() -> bool:
-    """``LIGHTGBM_TPU_FUSED_PACKED=1``: allow the fused route+histogram
-    kernels to ride the packed int16-accumulator stream.  Default OFF —
-    the growers force the unfused pair whenever packed_acc is on so the
-    on-chip A/B isolates one variant at a time (docs/KERNELS.md); this
-    opt-in makes the combined variant reachable for its own A/B instead
-    of structurally excluded."""
-    import os
-    return (os.environ.get("LIGHTGBM_TPU_FUSED_PACKED", "").lower()
-            in ("1", "on", "true", "force"))
-
-
-def fused_k_mode() -> str:
-    """Raw ``LIGHTGBM_TPU_FUSED_K`` ladder: '' (off, the default) |
-    'on' (self-check gated) | 'force' ('force' or a trailing '!'
-    bypasses the check for on-chip A/B plumbing)."""
-    import os
-    env = os.environ.get("LIGHTGBM_TPU_FUSED_K", "").lower()
-    if env in ("", "0", "off", "false"):
-        return ""
-    if env == "force" or env.endswith("!"):
-        return "force"
-    return "on"
-
-
-def fused_k_enabled() -> bool:
-    """Whether the frontier grower may use the fused-K kernel
-    (``histogram_frontier_fusedk``): route + ALL-2K-children histogram
-    in one pass, no parent gather / subtraction.
-
-    Default OFF — no variant flips to default without a v5e number
-    (the expected win — the route passes' ~0.07-0.2 s/iter plus one of
-    the two 0.17 s/iter leaf_hist staging copies — lands in PERF_NOTES
-    round 7 first).  ``1/on`` runs the one-shot bit-identity self-check
-    vs the unfused pair on the live backend, memoized, with clean
-    fallback; ``force``/trailing '!' bypasses.  Dynamic-grid only,
-    like every fused variant."""
-    global _FUSED_K_CHECK
-    mode = fused_k_mode()
-    if not mode:
-        return False
-    if not dyn_grid_enabled():
-        return False
-    if mode == "force":
-        return True
-    if _FUSED_K_CHECK is None:
-        _FUSED_K_CHECK = gate_self_check("fused-K", _fused_k_self_check)
-    return _FUSED_K_CHECK
-
-
-def _fused_k_fallback(reason: str) -> None:
-    """Requested-but-vetoed fused-K build: count it so A/B drivers can
-    tell a measured off leg from a silently fallen-back force leg."""
-    import sys
-    try:
-        from ..utils.telemetry import TELEMETRY
-        TELEMETRY.counter_add("hist/fused_k_fallbacks", 1)
-    except Exception:
-        pass
-    sys.stderr.write(f"fused-K requested but fell back: {reason}\n")
 
 
 def fused_route_policy(K: int, F_log: int, num_bins: int,
                        block_rows: int, packed4: bool) -> str:
     """The growers' single dispatch policy for the fused route+histogram
     kernels.  Returns a tier: "off" | "k1" (K-target round-pass fusion,
-    the kernel the unfused pair's targets match) | "fusedk" (2K-children
-    fused-K kernel, frontier K > 1).
+    the kernel the unfused pair's targets match).
 
-    LIGHTGBM_TPU_FUSED_K (off by default) owns the K > 1 tier: 'on'
-    self-checks + consults the vmem fit at the 2K-wide carry, 'force'
-    bypasses both, and a requested-but-vetoed build counts a
-    ``hist/fused_k_fallbacks`` event before falling through to the
-    LIGHTGBM_TPU_FUSED_ROUTE handling below.
-
-    LIGHTGBM_TPU_FUSED_ROUTE keeps its meaning: =1 -> the K-target
-    fusion wherever the kernels lower (bypasses the K policy and the
-    vmem fit veto, for A/Bs); =0 -> off.  Auto: K == 1 only — on-chip
-    (v5e, 2026-08-01) the K=16 K-target fusion measured 1.43 s/iter vs
-    1.02-1.04 unfused at the HIGGS shape (K serial in-block route
-    updates plus K frow streams cost more than the ONE union-pass
-    windowed route they replace, and the subtraction still ran) while
-    the K=1 segment fusion won 1.28 vs 1.43 — plus the self-check and
-    the vmem fit estimate.  The fused-K tier is the re-cut that also
-    deletes the parent gather + subtraction; its verdict slot is
-    PERF_NOTES round 7."""
+    LIGHTGBM_TPU_FUSED_ROUTE: =1 -> the K-target fusion wherever the
+    kernels lower (bypasses the K policy and the vmem fit veto, for
+    A/Bs); =0 -> off.  Auto: K == 1 only — on-chip (v5e, 2026-08-01)
+    the K=16 K-target fusion measured 1.43 s/iter vs 1.02-1.04 unfused
+    at the HIGGS shape (K serial in-block route updates plus K frow
+    streams cost more than the ONE union-pass windowed route they
+    replace, and the subtraction still ran) while the K=1 segment
+    fusion won 1.28 vs 1.43 — plus the self-check and the vmem fit
+    estimate."""
     import os
     F_phys = (F_log + 1) // 2 if packed4 else F_log
-    if K > 1 and fused_k_mode():
-        if not fused_k_enabled():
-            _fused_k_fallback("self-check failed or dyn-grid off")
-        elif (fused_k_mode() == "force"
-              or fused_route_fits(F_phys, num_bins, K, block_rows,
-                                  packed4, targets_k=2 * K)):
-            return "fusedk"
-        else:
-            _fused_k_fallback("2K-wide carry fails the vmem fit veto")
     env = os.environ.get("LIGHTGBM_TPU_FUSED_ROUTE", "auto").lower()
     if env in ("0", "off", "false"):
         return "off"
@@ -2127,7 +1498,7 @@ def route_window(binsT: jax.Array, leaf_id: jax.Array,
     full-N leaf_id every call — the v5e trace shows 254 s32[10.5M]
     conditional copies per iteration ≈ 0.18 s/iter at the HIGGS shape.
     Here blocks outside the window are never touched (same aliasing
-    contract as histogram_segment_routed).  Dynamic-grid only."""
+    contract as histogram_segment_routed)."""
     F, n = binsT.shape
     if interpret is None:
         interpret = _interpret_default()
@@ -2167,14 +1538,11 @@ def route_kernel_available() -> bool:
     """Whether the growers should route through the aliased pallas
     window kernel instead of the XLA switch path.  =0/1 forces; auto
     runs a one-shot on-device parity check (numeric + categorical +
-    missing + out-of-window retention) against the XLA route.  Needs
-    the dynamic-grid dispatch."""
+    missing + out-of-window retention) against the XLA route."""
     global _ROUTE_KERNEL_CHECK
     import os
     env = os.environ.get("LIGHTGBM_TPU_ROUTE_KERNEL", "auto").lower()
     if env in ("0", "off", "false"):
-        return False
-    if not dyn_grid_enabled():
         return False
     if env in ("1", "on", "true"):
         return True
@@ -2267,16 +1635,12 @@ def fused_route_available() -> bool:
     ``LIGHTGBM_TPU_FUSED_ROUTE=0/1`` forces; default ("auto") runs a
     one-shot self-check on the live backend — the kernels must lower
     AND reproduce the separate route+histogram pair exactly, including
-    untouched-block retention through the input/output alias.  Requires
-    the dynamic-grid dispatch (the bucket ladder keeps the unfused
-    pair).
+    untouched-block retention through the input/output alias.
     """
     global _FUSED_ROUTE_CHECK
     import os
     env = os.environ.get("LIGHTGBM_TPU_FUSED_ROUTE", "auto").lower()
     if env in ("0", "off", "false"):
-        return False
-    if not dyn_grid_enabled():
         return False
     if env in ("1", "on", "true"):
         return True
@@ -2432,7 +1796,7 @@ def _fused_route_self_check() -> bool:
     if not (np.array_equal(np.asarray(lidk), np.asarray(lid1))
             and np.allclose(np.asarray(hk[0]), np.asarray(h1), atol=1e-5)):
         return _fail("lookahead lane set 0")
-    from ..models.grower import routed_left
+    from .split import routed_left
     empty = empty_lookahead_slots(KL - 1)
     for k, (leaf, side, f, t, dl, cat) in enumerate(live, start=1):
         go = routed_left(binsT[f].astype(jnp.int32), t, dl, cat, bitset,
@@ -2500,356 +1864,21 @@ def _fused_route_self_check() -> bool:
     return True
 
 
-_FUSED_K_CHECK: bool | None = None
-
-
-def _fused_k_self_check() -> bool:
-    """Bit-identity of the fused-K kernel (route + ALL 2K children in
-    one pass) vs the unfused pair: numpy-route the ids, then
-    ``histogram_frontier`` over the SAME 2K targets.  Exact equality is
-    the contract — both kernels concat the same masked channel sets
-    into the same one-hot matmul in the same chunk order, so every
-    accumulator column is the identical f32 dot product.  Legs:
-    numeric zero-missing / NaN-missing / categorical-bitset routes,
-    packed4 nibble rows (both parities), EFB group reconstruction."""
-    import numpy as np
-    rng = np.random.default_rng(11)
-
-    def _fail(leg):
-        import sys
-        sys.stderr.write(f"fused-K self-check FAILED leg: {leg}\n")
-        return False
-
-    F, B, rb, nblk = 4, 16, 512, 6
-    n = rb * nblk
-    binsT = jnp.asarray(rng.integers(0, B, (F, n)), jnp.uint8)
-    grad = jnp.asarray(rng.standard_normal(n), jnp.float32)
-    hess = jnp.asarray(rng.uniform(0.5, 1.5, n), jnp.float32)
-    w8 = pack_channels(grad, hess, jnp.ones(n, jnp.float32))
-    # two leaves confined to blocks [1, 4); leaf 7 elsewhere
-    lid_np = np.full(n, 7, np.int32)
-    lid_np[rb:4 * rb] = np.where(rng.random(3 * rb) < 0.5, 3, 5)
-    lid = jnp.asarray(lid_np)
-    bitset = jnp.asarray(rng.integers(0, 2**32, 8, dtype=np.uint64)
-                         .astype(np.uint32))
-    bl = jnp.asarray([1, 2, 3, 0, 0, 0], jnp.int32)
-    nb = jnp.int32(3)
-
-    class _M:  # minimal FeatureMeta-alike for pack_route
-        feat_group = None
-        feat_offset = None
-        missing_type = jnp.asarray([1, 2, 2, 0], jnp.int32)
-        default_bin = jnp.asarray([3, 0, 0, 0], jnp.int32)
-        num_bin = jnp.full((4,), B, jnp.int32)
-
-    def _np_go_left(f, thr, dl, cat):
-        fcol = np.asarray(binsT[f]).astype(np.int64)
-        mt = int(_M.missing_type[f])
-        miss = ((mt == 1) & (fcol == int(_M.default_bin[f]))
-                | (mt == 2) & (fcol == B - 1))
-        if cat:
-            w = np.asarray(bitset)[np.clip(fcol, 0, 255) // 32]
-            return (w >> (np.clip(fcol, 0, 255) % 32)) & 1 > 0
-        return np.where(miss, dl, fcol <= thr)
-
-    # K=2: route flavor under test on leaf 3 + a plain numeric route on
-    # leaf 5 riding along, so the 2K=4-wide accumulate always runs;
-    # f=0 is the zero-missing branch, f=2 the NaN branch (bin B-1
-    # routed by default_left, here False), f=1 the categorical bitset
-    for f, cat, dl in ((0, False, True), (1, True, True),
-                       (2, False, False)):
-        routes = jnp.stack([
-            pack_route(3, 9, f, B // 2, dl, cat, bitset, _M, False),
-            pack_route(5, 10, 3, B // 3, False, False,
-                       jnp.zeros(8, jnp.uint32), _M, False)])
-        targets2 = jnp.asarray([3, 5, 9, 10], jnp.int32)
-        lid2, hist = histogram_frontier_fusedk(
-            binsT, w8, lid, bl, nb, targets2, routes, B, rb, 2)
-        exp = lid_np.copy()
-        exp[(exp == 3) & ~_np_go_left(f, B // 2, dl, cat)] = 9
-        exp[(exp == 5) & ~_np_go_left(3, B // 3, False, False)] = 10
-        if not np.array_equal(np.asarray(lid2), exp):
-            return _fail(f"lid (f={f}, cat={cat})")
-        ref = histogram_frontier(binsT, w8, jnp.asarray(exp), bl, nb,
-                                 targets2, B, rb)
-        if not np.array_equal(np.asarray(hist), np.asarray(ref)):
-            return _fail(f"hist (f={f}, cat={cat})")
-
-    # packed4: both nibble parities across the K routes
-    bins4 = rng.integers(0, 15, (F, n))
-    packedT = jnp.asarray(pack_bins_4bit(bins4))
-
-    class _M4(_M):
-        num_bin = jnp.full((4,), 15, jnp.int32)
-        missing_type = jnp.zeros(4, jnp.int32)
-        default_bin = jnp.zeros(4, jnp.int32)
-
-    routes4 = jnp.stack([pack_route(3, 9, 1, 7, False, False,
-                                    jnp.zeros(8, jnp.uint32), _M4, True),
-                         pack_route(5, 10, 2, 7, False, False,
-                                    jnp.zeros(8, jnp.uint32), _M4, True)])
-    targets2 = jnp.asarray([3, 5, 9, 10], jnp.int32)
-    lid4, hist4 = histogram_frontier_fusedk(
-        packedT, w8, lid, bl, nb, targets2, routes4, 16, rb, 2,
-        packed4=True)
-    exp4 = lid_np.copy()
-    exp4[(exp4 == 3) & (bins4[1].astype(np.int64) > 7)] = 9
-    exp4[(exp4 == 5) & (bins4[2].astype(np.int64) > 7)] = 10
-    if not np.array_equal(np.asarray(lid4), exp4):
-        return _fail("packed4 lid")
-    ref4 = histogram_frontier(packedT, w8, jnp.asarray(exp4), bl, nb,
-                              targets2, 16, rb, packed4=True)
-    if not np.array_equal(np.asarray(hist4), np.asarray(ref4)):
-        return _fail("packed4 hist")
-
-    # EFB: group column carries feature 1 at offset 6; K=1 keeps the
-    # KT=2 > K corner covered (one route, both children accumulated)
-    class _ME(_M):
-        feat_group = jnp.asarray([0, 0, 1, 1], jnp.int32)
-        feat_offset = jnp.asarray([0, 6, 0, 6], jnp.int32)
-        num_bin = jnp.full((4,), 6, jnp.int32)
-        missing_type = jnp.zeros(4, jnp.int32)
-        default_bin = jnp.zeros(4, jnp.int32)
-
-    routes_e = pack_route(3, 9, 1, 2, False, False,
-                          jnp.zeros(8, jnp.uint32), _ME, False)[None]
-    targets_e = jnp.asarray([3, 9], jnp.int32)
-    lid5, hist5 = histogram_frontier_fusedk(
-        binsT, w8, lid, bl, nb, targets_e, routes_e, B, rb, 1)
-    g = np.asarray(binsT[0]).astype(np.int64)
-    fcol = np.where((g >= 6) & (g < 12), g - 6, 0)
-    exp5 = lid_np.copy()
-    exp5[(exp5 == 3) & (fcol > 2)] = 9
-    if not np.array_equal(np.asarray(lid5), exp5):
-        return _fail("efb lid")
-    ref5 = histogram_frontier(binsT, w8, jnp.asarray(exp5), bl, nb,
-                              targets_e, B, rb)
-    if not np.array_equal(np.asarray(hist5), np.asarray(ref5)):
-        return _fail("efb hist")
-    return True
-
-
-# build-time decisions, keyed "segment"/"frontier"/"plain" — benches and
-# telemetry read this to report whether the packed stream actually ran
-# (the env gate + self-check fallback make the bare env value misleading)
-packed_acc_decisions: dict = {}
-
-_PACKED_ACC_CHECK: bool | None = None
-
-
-def packed_acc_enabled() -> bool:
-    """Whether histogram passes should run the packed int16 accumulator
-    stream (``LIGHTGBM_TPU_PACKED_ACC``).
-
-    Default OFF — no variant flips to default without a v5e number.
-    ``1/on`` runs the one-shot quantization-parity self-check on the
-    live backend and falls back to the f32 channel path when it reports
-    a mismatch (``gate_self_check``: on TPU a check that fails to lower
-    raises instead); ``force`` bypasses the check for on-chip A/B
-    plumbing; ``0/off``/empty disables."""
-    global _PACKED_ACC_CHECK
-    import os
-    env = os.environ.get("LIGHTGBM_TPU_PACKED_ACC", "").lower()
-    if env in ("", "0", "off", "false"):
-        return False
-    if env == "force":
-        return True
-    if _PACKED_ACC_CHECK is None:
-        _PACKED_ACC_CHECK = gate_self_check("packed-acc",
-                                            _packed_acc_self_check)
-    return _PACKED_ACC_CHECK
-
-
-def _packed_acc_self_check() -> bool:
-    """One-shot parity run of the packed-accumulator stream against the
-    f32 channel path on the live backend: count channel EXACT, grad/hess
-    bin sums within the stochastic-rounding bound (scale x (count + 1)
-    per bin), across the all/segment/frontier and packed4 legs — with a
-    fractional-member leg so GOSS/bagging weights stay covered."""
-    import numpy as np
-    rng = np.random.default_rng(13)
-
-    def _fail(leg):
-        import sys
-        sys.stderr.write(f"packed-acc self-check FAILED leg: {leg}\n")
-        return False
-
-    F, B, rb, nblk = 4, 16, 512, 4
-    n = rb * nblk
-    bits = packed_acc_bits()
-    binsT = jnp.asarray(rng.integers(0, B, (F, n)), jnp.uint8)
-    grad = jnp.asarray(rng.standard_normal(n), jnp.float32)
-    hess = jnp.asarray(rng.uniform(0.5, 1.5, n), jnp.float32)
-    # fractional members exercise the f32-bitcast count lane (GOSS)
-    member = jnp.asarray(np.where(rng.random(n) < 0.2, 0.0,
-                                  np.where(rng.random(n) < 0.3, 0.25, 1.0)
-                                  ).astype(np.float32))
-    w8 = pack_channels(grad, hess, member)
-    w2, scales, _clips = quantize_pack_channels(grad, hess, member,
-                                                bits=bits)
-    sc = np.asarray(scales)
-
-    def _bound(leg, got, ref):
-        got, ref = np.asarray(got), np.asarray(ref)
-        if not np.array_equal(got[..., 2], ref[..., 2]):
-            return _fail(f"{leg} count")
-        cnt = ref[..., 2]
-        for ch, s in ((0, sc[0]), (1, sc[1])):
-            if np.any(np.abs(got[..., ch] - ref[..., ch])
-                      > s * (cnt + 1.0) + 1e-4):
-                return _fail(f"{leg} ch{ch} bound")
-        return True
-
-    ref = unpack_hist(histogram_all(binsT, w8, B, rb))
-    got = unpack_hist_packed(histogram_all(binsT, w2, B, rb), scales)
-    if not _bound("all", got, ref):
-        return False
-
-    lid_np = np.full(n, 7, np.int32)
-    lid_np[rb:3 * rb] = np.where(rng.random(2 * rb) < 0.5, 3, 5)
-    lid = jnp.asarray(lid_np)
-    refs = unpack_hist(histogram_segment(
-        binsT, w8, lid, jnp.int32(1), jnp.int32(2), jnp.int32(3), B, rb))
-    gots = unpack_hist_packed(histogram_segment(
-        binsT, w2, lid, jnp.int32(1), jnp.int32(2), jnp.int32(3), B, rb),
-        scales)
-    if not _bound("segment", gots, refs):
-        return False
-
-    targets = jnp.asarray([3, 5], jnp.int32)
-    bl = jnp.arange(nblk, dtype=jnp.int32)
-    reff = unpack_hist(histogram_frontier(
-        binsT, w8, lid, bl, jnp.int32(nblk), targets, B, rb))
-    gotf = unpack_hist_packed(histogram_frontier(
-        binsT, w2, lid, bl, jnp.int32(nblk), targets, B, rb), scales)
-    if not _bound("frontier", gotf, reff):
-        return False
-
-    bins4 = rng.integers(0, 15, (F, n))
-    packedT = jnp.asarray(pack_bins_4bit(bins4))
-    ref4 = unpack_hist(histogram_all(packedT, w8, 16, rb, packed4=True))
-    got4 = unpack_hist_packed(histogram_all(packedT, w2, 16, rb,
-                                            packed4=True), scales)
-    if not _bound("packed4", got4, ref4):
-        return False
-    return True
-
-
-_ONEHOT_BUILD_CHECKS: dict = {}
-
-
-def onehot_build_mode() -> str:
-    """Resolved one-hot construction for the histogram kernels
-    (``LIGHTGBM_TPU_ONEHOT_BUILD``).
-
-    ''/'iota' -> the compare-vs-iota baseline.  'gather'/'twolevel' ->
-    the alternative build, gated by a one-shot BIT-identity self-check
-    against iota on the live backend (all builds feed the same matmul,
-    so identity is the contract — any difference means the build is
-    wrong, and the mode falls back to iota with a warning; a build that
-    does not lower on TPU raises, see ``gate_self_check``).  A trailing
-    '!' ('gather!') bypasses the check for
-    on-chip A/Bs.  Resolved in the NON-jit public wrappers, never
-    inside a jitted dispatcher, so an env change is never masked by a
-    stale jit cache entry."""
-    import os
-    env = os.environ.get("LIGHTGBM_TPU_ONEHOT_BUILD", "").lower()
-    if env in ("", "iota"):
-        return "iota"
-    force = env.endswith("!")
-    mode = env.rstrip("!")
-    if mode not in ("gather", "twolevel"):
-        return "iota"
-    if force:
-        return mode
-    if mode not in _ONEHOT_BUILD_CHECKS:
-        _ONEHOT_BUILD_CHECKS[mode] = gate_self_check(
-            f"one-hot build ({mode})",
-            lambda: _onehot_build_self_check(mode))
-    if not _ONEHOT_BUILD_CHECKS[mode]:
-        return "iota"
-    return mode
-
-
-def _onehot_build_self_check(mode: str) -> bool:
-    """Bit-identity of an alternative one-hot build vs the iota baseline
-    (same [nf*B, chunk] matrix, same dot_general, same accumulation
-    order => bitwise-equal f32 sums) on full/segment/frontier and
-    packed4 legs."""
-    import numpy as np
-    rng = np.random.default_rng(17)
-
-    def _fail(leg):
-        import sys
-        sys.stderr.write(f"one-hot build self-check ({mode}) FAILED "
-                         f"leg: {leg}\n")
-        return False
-
-    F, B, rb, nblk = 4, 16, 512, 4
-    n = rb * nblk
-    binsT = jnp.asarray(rng.integers(0, B, (F, n)), jnp.uint8)
-    grad = jnp.asarray(rng.standard_normal(n), jnp.float32)
-    hess = jnp.asarray(rng.uniform(0.5, 1.5, n), jnp.float32)
-    member = jnp.ones(n, jnp.float32)
-    w8 = pack_channels(grad, hess, member)
-
-    a = _histogram_all(binsT, w8, B, rb, onehot_build="iota")
-    b = _histogram_all(binsT, w8, B, rb, onehot_build=mode)
-    if not np.array_equal(np.asarray(a), np.asarray(b)):
-        return _fail("all")
-
-    lid_np = np.full(n, 7, np.int32)
-    lid_np[rb:3 * rb] = np.where(rng.random(2 * rb) < 0.5, 3, 5)
-    lid = jnp.asarray(lid_np)
-    sa = _histogram_segment_dyn(binsT, w8, lid, jnp.int32(1), jnp.int32(2),
-                                jnp.int32(3), B, rb, onehot_build="iota")
-    sb = _histogram_segment_dyn(binsT, w8, lid, jnp.int32(1), jnp.int32(2),
-                                jnp.int32(3), B, rb, onehot_build=mode)
-    if not np.array_equal(np.asarray(sa), np.asarray(sb)):
-        return _fail("segment")
-
-    targets = jnp.asarray([3, 5], jnp.int32)
-    bl = jnp.arange(nblk, dtype=jnp.int32)
-    fa = _histogram_frontier_dyn(binsT, w8, lid, bl, jnp.int32(nblk),
-                                 targets, B, rb, 2, onehot_build="iota")
-    fb = _histogram_frontier_dyn(binsT, w8, lid, bl, jnp.int32(nblk),
-                                 targets, B, rb, 2, onehot_build=mode)
-    if not np.array_equal(np.asarray(fa), np.asarray(fb)):
-        return _fail("frontier")
-
-    bins4 = rng.integers(0, 15, (F, n))
-    packedT = jnp.asarray(pack_bins_4bit(bins4))
-    pa = _histogram_all(packedT, w8, 16, rb, packed4=True,
-                        onehot_build="iota")
-    pb = _histogram_all(packedT, w8, 16, rb, packed4=True,
-                        onehot_build=mode)
-    if not np.array_equal(np.asarray(pa), np.asarray(pb)):
-        return _fail("packed4")
-    return True
-
-
-# self-checks of the kernels the default path selects; the rest are opt-in
-# variants behind a LIGHTGBM_TPU_* knob
+# self-checks of the kernels the default path selects
 DEFAULT_PATH_CHECKS = ("fused_route", "route_kernel", "score_kernel")
 
 
 def kernel_self_checks() -> dict:
-    """Run every kernel variant self-check on the current backend (CPU:
+    """Run the ``DEFAULT_PATH_CHECKS`` on the current backend (CPU:
     the interpret path; on-chip runs catch lowering drift the interpreter
     cannot).  Returns ``{name: None}`` for a check that passed, else what
     went wrong: ``"mismatch"`` or the exception, whose traceback goes to
     stderr.  No check's failure stops the others."""
-    from ..models.grower_frontier import _hist_stage_self_check
     from .pallas_score import _score_kernel_self_check
     checks = [
         ("fused_route", _fused_route_self_check),
         ("route_kernel", _route_kernel_self_check),
         ("score_kernel", _score_kernel_self_check),
-        ("fused_k", _fused_k_self_check),
-        ("packed_acc", _packed_acc_self_check),
-        ("onehot_gather", lambda: _onehot_build_self_check("gather")),
-        ("onehot_twolevel", lambda: _onehot_build_self_check("twolevel")),
-        ("hist_stage", _hist_stage_self_check),
     ]
     results = {}
     for name, fn in checks:
@@ -2882,19 +1911,9 @@ def run_kernel_self_checks(verbose: bool = True) -> int:
 def leaf_histogram_pallas(binsT: jax.Array, grad: jax.Array,
                           hess: jax.Array, member: jax.Array,
                           num_bins: int, block_rows: int = 0,
-                          packed4: bool = False,
-                          packed_acc: bool = False,
-                          bits: int = 8) -> jax.Array:
+                          packed4: bool = False) -> jax.Array:
     """Drop-in [F, B, 3] leaf histogram matching ops.histogram semantics,
-    computed with the full-data pallas kernel.  ``packed_acc`` runs the
-    quantized int16 stream instead of the 8-channel hi/lo split — the
-    per-call quantize gives this path natural per-leaf scales."""
-    if packed_acc:
-        w2, scales, _clips = quantize_pack_channels(grad, hess, member,
-                                                    bits=bits)
-        return unpack_hist_packed(
-            histogram_all(binsT, w2, num_bins, block_rows,
-                          packed4=packed4), scales)
+    computed with the full-data pallas kernel."""
     w8 = pack_channels(grad, hess, member)
     return unpack_hist(histogram_all(binsT, w8, num_bins, block_rows,
                                      packed4=packed4))

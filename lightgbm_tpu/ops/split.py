@@ -132,6 +132,24 @@ def reconstruct_feature_column(gcol, f, fmeta: FeatureMeta):
     return jnp.where(in_range, gcol - off, fmeta.default_bin[f])
 
 
+def _bit_test(bitset_row: jax.Array, idx: jax.Array) -> jax.Array:
+    """bitset_row u32[8], idx i32 -> bool."""
+    word = bitset_row[idx // 32]
+    return ((word >> (idx % 32).astype(jnp.uint32)) & 1).astype(bool)
+
+
+def routed_left(fcol, threshold, default_left, is_cat, cat_bitset,
+                missing_type, default_bin, num_bin):
+    """Which side each row goes (numerical <=threshold with missing routing,
+    categorical bitset membership)."""
+    fcol = fcol.astype(jnp.int32)
+    is_missing = (((missing_type == MISSING_ZERO) & (fcol == default_bin))
+                  | ((missing_type == MISSING_NAN) & (fcol == num_bin - 1)))
+    num_left = jnp.where(is_missing, default_left, fcol <= threshold)
+    cat_left = _bit_test(cat_bitset, jnp.clip(fcol, 0, 255))
+    return jnp.where(is_cat, cat_left, num_left)
+
+
 def threshold_l1(s, l1):
     return jnp.sign(s) * jnp.maximum(jnp.abs(s) - l1, 0.0)
 

@@ -45,3 +45,18 @@ import pytest  # noqa: E402
 @pytest.fixture
 def rng():
     return np.random.RandomState(42)
+
+
+@pytest.fixture
+def tiles_of_96(monkeypatch):
+    """`feature_tile` says 96 columns past 96: what 2000 x 64 does to the
+    real arithmetic, at a width the CPU trains in seconds."""
+    from lightgbm_tpu.models import grower_seg
+    from lightgbm_tpu.ops import pallas_histogram as ph
+    real = ph.feature_tile
+
+    def small(F, B):
+        return real(F, B) if F <= 96 else 96
+
+    monkeypatch.setattr(ph, "feature_tile", small)
+    monkeypatch.setattr(grower_seg, "feature_tile", small)

@@ -92,3 +92,44 @@ def test_parameters_doc_is_current():
         [sys.executable, os.path.join(repo, "tools", "gen_params_doc.py"),
          "--check"], capture_output=True, text=True)
     assert proc.returncode == 0, proc.stdout + proc.stderr
+
+
+def test_env_knobs_are_the_documented_ones():
+    """Every `LIGHTGBM_TPU_*` name the library reads is described in a file
+    under docs/, and every such name that docs/ or README.md describes as
+    a knob is read somewhere in the repo.  (docs/KERNELS.md's table of
+    deleted variants names their knobs as history.)"""
+    import ast
+    import glob
+    import os
+    import re
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    knob = re.compile(r"LIGHTGBM_TPU_[A-Z0-9_]+")
+
+    def read_in(pattern):
+        names = set()
+        for path in glob.glob(os.path.join(root, pattern), recursive=True):
+            for node in ast.walk(ast.parse(open(path).read())):
+                if (isinstance(node, ast.Constant)
+                        and isinstance(node.value, str)
+                        and knob.fullmatch(node.value)):
+                    names.add(node.value)
+        return names
+
+    def described_in(path):
+        text = open(path).read()
+        if path.endswith("KERNELS.md"):
+            text = text.split("## Rejected variants")[0]
+        return set(knob.findall(text))
+
+    library = read_in("lightgbm_tpu/**/*.py")
+    repo = library | read_in("*.py") | read_in("tools/*.py") \
+        | read_in("benchmark/**/*.py")
+    docs = set().union(*(described_in(p) for p in glob.glob(
+        os.path.join(root, "docs", "*.md"))))
+    readme = described_in(os.path.join(root, "README.md"))
+    assert len(library) == 20, sorted(library)
+    assert not library - docs, f"read, documented nowhere: {library - docs}"
+    assert not (docs | readme) - repo, (
+        f"documented, read nowhere: {(docs | readme) - repo}")

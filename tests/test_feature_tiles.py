@@ -161,19 +161,6 @@ def test_tiled_pass_against_numpy_at_300_columns(entry):
             h[:F, :, :], _numpy_hist(bins, grad, hess, member, B))
 
 
-@pytest.fixture
-def tiles_of_96(monkeypatch):
-    """`feature_tile` says 96 columns past 96: what 2000 x 64 does to the
-    real arithmetic, at a width the CPU trains in seconds."""
-    real = ph.feature_tile
-
-    def small(F, B):
-        return real(F, B) if F <= 96 else 96
-
-    monkeypatch.setattr(ph, "feature_tile", small)
-    monkeypatch.setattr(grower_seg, "feature_tile", small)
-
-
 WIDE = {"objective": "binary", "max_bin": 63, "num_leaves": 31,
         "learning_rate": 0.1, "min_sum_hessian_in_leaf": 5.0, "verbose": -1,
         "tpu_row_chunk": 1024, "tpu_boost_chunk": 2}

@@ -354,3 +354,67 @@ def test_compact_state_sort_vs_gather_exact(rng, monkeypatch):
     # sanity: the layout really is leaf-sorted and a true permutation
     assert np.all(np.diff(np.asarray(by_sort.leaf_id)) >= 0)
     assert np.array_equal(np.sort(np.asarray(by_sort.order)), np.arange(n))
+
+
+def _grid_case(shape, rng):
+    """(X, y, categorical features, tpu_tree_impl, parameters)."""
+    base = dict(num_leaves=31, min_data_in_leaf=5, tpu_row_chunk=256)
+    if shape == "categorical":
+        n = 2500
+        Xc = rng.randint(0, 12, size=n)
+        X = np.column_stack([Xc.astype(np.float64),
+                             rng.normal(size=(n, 3))])
+        y = np.sin(Xc) + X[:, 1] + 0.1 * rng.normal(size=n)
+        return X, y, [0], "segment", dict(
+            base, objective="regression", max_bin=63,
+            min_data_per_group=20, cat_smooth=1.0)
+    if shape == "tiled96":
+        # four feature tiles a pass (test_feature_tiles.py's fixture)
+        X = rng.normal(size=(3000, 300)).astype(np.float32)
+        y = (X[:, :8].sum(axis=1) > 0).astype(np.float64)
+        return X, y, [], "segment", dict(base, objective="binary",
+                                         max_bin=63, tpu_row_chunk=1024)
+    X = rng.normal(size=(3000, 6))
+    y = (X[:, 0] + 0.5 * X[:, 1] - 0.3 * X[:, 2] ** 2
+         + 0.1 * rng.normal(size=3000) > 0).astype(np.float64)
+    return X, y, [], ("frontier" if shape == "frontier_k4" else "segment"), \
+        dict(base, objective="binary",
+             max_bin=15 if shape == "packed4" else 63,
+             **({"tpu_frontier_width": 4} if shape == "frontier_k4"
+                else {}))
+
+
+@pytest.mark.parametrize("shape", ["binary", "categorical", "packed4",
+                                   "tiled96", "frontier_k4"])
+def test_grid_steps_are_scanned_blocks_times_tiles(shape, rng, monkeypatch,
+                                                   request):
+    """What pins `hist_grid_ratio` at 1.0: a kernel's grid is its
+    interval's blocks, so over grown trees the steps that accumulating
+    passes dispatched are the blocks they scanned, once a feature tile.
+    (A pass over an empty interval would dispatch one masked step and
+    count it, `grid_of`; no leaf that is split has one, and a pass that
+    only routes counts neither a block nor a step.)"""
+    from lightgbm_tpu.models import gbdt, grower_seg
+    if shape == "tiled96":
+        request.getfixturevalue("tiles_of_96")
+    seen = []
+    monkeypatch.setattr(gbdt, "print_seg_stats", seen.append)
+    monkeypatch.setenv("LIGHTGBM_TPU_SEG_STATS", "1")
+    X, y, cats, impl, params = _grid_case(shape, rng)
+    cfg = Config(verbosity=-1, tpu_histogram_backend="pallas",
+                 tpu_tree_impl=impl, **params)
+    ds = TpuDataset.from_numpy(X, y, config=cfg, categorical_features=cats)
+    obj = create_objective(cfg)
+    obj.init(ds.metadata, ds.num_data)
+    bst = GBDT(cfg, ds, obj)
+    assert bst.grower_params.packed4 == (shape == "packed4")
+    for _ in range(2):
+        bst.train_one_iter()
+    assert len(seen) == 2
+    for stats in seen:
+        st = grower_seg.SegStats._make(np.asarray(stats))
+        tiles = {"tiled96": 4, "frontier_k4": 0}.get(shape, 1)
+        assert st.feature_tiles == tiles        # the frontier grower: none
+        assert st.batch_k == (4 if shape == "frontier_k4" else 1)
+        assert st.scanned_blocks > st.max_blocks > 1
+        assert st.grid_steps == st.scanned_blocks * max(tiles, 1)

@@ -25,9 +25,11 @@ from lightgbm_tpu.ops import pallas_histogram as ph
 
 RB = 256        # 12-16 blocks at these sizes: intervals that differ
 SEEDS = range(8)
-# stats slots (grower_seg.SEG_STATS_SLOTS)
-SCANNED, SORTS, GRID, MAXB = 0, 1, 2, 3
-SPLITS, HITS, FILLED, ROUTE_ONLY = 9, 10, 11, 12
+# stats slots, by name (grower_seg.SegStats)
+(SCANNED, SORTS, GRID, MAXB, SPLITS, HITS, FILLED, ROUTE_ONLY) = map(
+    gs.SegStats._fields.index,
+    ("scanned_blocks", "compactions", "grid_steps", "max_blocks", "splits",
+     "lookahead_hits", "lookahead_filled", "route_only_blocks"))
 
 
 def _data(shape, rng):
@@ -320,22 +322,6 @@ def test_one_lane_set_builds_the_program_of_before(cases, plain_reads,
         np.testing.assert_array_equal(a[2], b[2])
 
 
-def test_packed_stream_runs_no_lookahead(cases, monkeypatch):
-    """(e): the packed int16 stream keeps the program it built before."""
-    case = _case(cases, "binary")
-    monkeypatch.setenv("LIGHTGBM_TPU_PACKED_ACC", "force")
-    fn = gs.make_grow_tree_segment(case.bst.num_bins,
-                                   case.bst.grower_params, RB)
-    a = case.grow(fn, 1)
-    monkeypatch.setattr(gs, "lookahead_width", lambda *a: 1)
-    fn1 = gs.make_grow_tree_segment(case.bst.num_bins,
-                                    case.bst.grower_params, RB)
-    b = case.grow(fn1, 1)
-    _assert_same_bits(a, b)
-    assert a[2][SPLITS] > 0
-    assert a[2][HITS] == a[2][FILLED] == a[2][ROUTE_ONLY] == 0
-
-
 def test_pending_needs_containment_not_overlap():
     """(c): a neighbour that shares only a boundary block with the pass is
     not filled; a leaf inside the interval is; nor is the leaf being
@@ -414,7 +400,7 @@ def test_kernel_lane_sets(K):
     set 0 of a pass over the rows its (leaf, pending split, side)
     selects, and close to ``histogram_segment`` over them; empty slots
     and a route-only call return zeros."""
-    from lightgbm_tpu.models.grower import routed_left
+    from lightgbm_tpu.ops.split import routed_left
     binsT, w8, lid, bitset, B, rb = _kernel_operands(6)
     route = ph.pack_route(3, 9, 0, B // 2, True, False, bitset, _Meta,
                           False)

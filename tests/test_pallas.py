@@ -11,10 +11,15 @@ import jax.numpy as jnp
 import pytest
 
 from lightgbm_tpu.ops.histogram import histogram_chunked
-from lightgbm_tpu.ops.pallas_histogram import (histogram_all,
+from lightgbm_tpu.ops.pallas_histogram import (empty_lookahead_slots,
+                                               histogram_all,
+                                               histogram_frontier,
                                                histogram_segment,
+                                               histogram_segment_lookahead,
+                                               histogram_segment_routed,
                                                leaf_histogram_pallas,
-                                               pack_channels, unpack_hist)
+                                               null_route, pack_channels,
+                                               unpack_hist)
 
 
 def _ref_hist(bins, g, h, m, B):
@@ -73,6 +78,75 @@ def test_histogram_all_packed4_matches_unpacked(rng):
                                        packed4=True))
     np.testing.assert_allclose(np.asarray(packed)[:f], np.asarray(plain),
                                rtol=1e-6, atol=1e-6)
+
+
+# (start block, blocks) of a 6-block table
+INTERVALS = {"empty": (1, 0), "one-block": (2, 1), "three-of-six": (1, 3),
+             "whole": (0, 6)}
+
+
+@pytest.mark.parametrize("interval", list(INTERVALS))
+@pytest.mark.parametrize("kernel", ["segment", "frontier", "routed",
+                                    "lookahead"])
+def test_interval_kernels_against_numpy(kernel, interval):
+    """Every kernel whose grid is its interval's length, against numpy
+    over the rows of that interval: the plain segment kernel, the
+    frontier kernel (the interval as a block list, two target leaves),
+    the routed kernel under a route that matches nothing and the
+    lookahead kernel with every slot empty (lane set 0; the other lane
+    sets stay zero, the ids come back untouched).  An empty interval
+    runs one masked grid step and returns zeros."""
+    rng = np.random.default_rng(17)
+    n_blk, f, b, rb = 6, 4, 16, 256
+    n = n_blk * rb
+    bins = rng.integers(0, b, size=(n, f)).astype(np.uint8)
+    g = rng.normal(size=n).astype(np.float32)
+    h = rng.uniform(0.1, 1.0, size=n).astype(np.float32)
+    m = (rng.uniform(size=n) < 0.8).astype(np.float32)
+    # three leaves spread over every block
+    lid = rng.choice(np.asarray([3, 5, 7], np.int32), size=n)
+    start, blocks = INTERVALS[interval]
+    inside = (np.arange(n) // rb >= start) & (np.arange(n) // rb
+                                              < start + blocks)
+
+    def want(leaf):
+        sel = inside & (lid == leaf)
+        return _ref_hist(bins[sel], g[sel], h[sel], m[sel], b)
+
+    binsT = jnp.asarray(bins.T.copy())
+    w8 = pack_channels(jnp.asarray(g), jnp.asarray(h), jnp.asarray(m))
+    lid_d = jnp.asarray(lid)
+    s0, nb = jnp.int32(start), jnp.int32(blocks)
+    kw = dict(block_rows=rb, interpret=True)
+    lid_out = None
+    if kernel == "segment":
+        got = {3: histogram_segment(binsT, w8, lid_d, s0, nb, jnp.int32(3),
+                                    b, **kw)}
+    elif kernel == "frontier":
+        block_list = np.zeros(n_blk, np.int32)
+        block_list[:blocks] = np.arange(start, start + blocks)
+        out = histogram_frontier(binsT, w8, lid_d, jnp.asarray(block_list),
+                                 nb, jnp.asarray([3, 5], jnp.int32), b, **kw)
+        got = {3: out[0], 5: out[1]}
+    elif kernel == "routed":
+        lid_out, out = histogram_segment_routed(
+            binsT, w8, lid_d, s0, nb, jnp.int32(3), null_route(), b, **kw)
+        got = {3: out}
+    else:
+        lid_out, out = histogram_segment_lookahead(
+            binsT, w8, lid_d, s0, nb, jnp.int32(3), null_route(),
+            empty_lookahead_slots(7), nb, b, **kw)
+        assert out.shape[0] == 8 and not np.asarray(out[1:]).any()
+        got = {3: out[0]}
+    if lid_out is not None:
+        np.testing.assert_array_equal(np.asarray(lid_out), lid)
+    for leaf, out in got.items():
+        exp = want(leaf)
+        out = np.asarray(unpack_hist(out), np.float64)
+        if not blocks:
+            assert not exp.any() and not out.any()
+        assert np.abs(out[..., 2] - exp[..., 2]).max() < 1e-3   # counts
+        assert np.abs(out - exp).max() < max(1e-6, np.abs(exp).max() * 3e-4)
 
 
 def test_histogram_segment_restricts_to_leaf(rng):

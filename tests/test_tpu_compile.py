@@ -93,16 +93,16 @@ def _compile(fn, *args):
     return jax.jit(fn).lower(*args).compile()
 
 
-def _segment_dyn(sh):
+def _segment_plain(sh):
     return _compile(
-        lambda b, w, l, s0, nb, t: ph._histogram_segment_dyn(
+        lambda b, w, l, s0, nb, t: ph.histogram_segment(
             b, w, l, s0, nb, t, sh.B, sh.rb, interpret=False),
         sh.bins, sh.w8, sh.leaf_id, sh.i32, sh.i32, sh.i32)
 
 
 def _all(sh):
     return _compile(
-        lambda b, w: ph._histogram_all(b, w, sh.B, sh.rb, interpret=False),
+        lambda b, w: ph.histogram_all(b, w, sh.B, sh.rb, interpret=False),
         sh.bins, sh.w8)
 
 
@@ -132,11 +132,11 @@ def _route_window(sh):
         sh.bins, sh.leaf_id, sh.i32, sh.i32, sh.route)
 
 
-def _frontier_dyn(sh):
+def _frontier(sh):
     K = FRONTIER_K
     return _compile(
-        lambda b, w, l, bl, nb, t: ph._histogram_frontier_dyn(
-            b, w, l, bl, nb, t, sh.B, sh.rb, K, interpret=False),
+        lambda b, w, l, bl, nb, t: ph.histogram_frontier(
+            b, w, l, bl, nb, t, sh.B, sh.rb, interpret=False),
         sh.bins, sh.w8, sh.leaf_id, sh.s((sh.n // sh.rb,), jnp.int32),
         sh.i32, sh.s((K,), jnp.int32))
 
@@ -148,9 +148,9 @@ def _score(sh):
         sh.s((255,), jnp.float32))
 
 
-@pytest.mark.parametrize("kernel", [_all, _segment_dyn, _segment_routed,
+@pytest.mark.parametrize("kernel", [_all, _segment_plain, _segment_routed,
                                     _segment_lookahead, _route_window,
-                                    _frontier_dyn, _score],
+                                    _frontier, _score],
                          ids=lambda f: f.__name__.lstrip("_"))
 def test_default_path_kernel_compiles_at_higgs_shape(one_chip, kernel):
     sh = _Shapes(one_chip, *HIGGS)
@@ -162,7 +162,7 @@ def test_default_path_kernel_compiles_at_higgs_shape(one_chip, kernel):
                          ids=["goss", "multiclass_cat", "lambdarank"])
 def test_segment_kernel_compiles_at_suite_widths(one_chip, F, B, rows):
     assert ph.supported(F, B, jnp.uint8)
-    assert "tpu_custom_call" in _segment_dyn(
+    assert "tpu_custom_call" in _segment_plain(
         _Shapes(one_chip, F, B, rows)).as_text()
 
 
@@ -176,7 +176,7 @@ def test_supported_agrees_with_compiler(one_chip, F, B):
     at 64 bins alone: below.)"""
     sh = _Shapes(one_chip, F, B, 2_270_000)
     try:
-        _segment_dyn(sh)
+        _segment_plain(sh)
         _segment_routed(sh)
         compiles = True
     except Exception as e:  # noqa: BLE001 — the compiler's refusal

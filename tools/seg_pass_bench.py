@@ -130,7 +130,7 @@ def main():
     m = jnp.int32(min(nblk, 24))
     lid1, hk = ph.histogram_segment_lookahead(
         binsT, w8, lid, s0, m, tgt, route, sl, m, B, rb)
-    from lightgbm_tpu.models.grower import routed_left
+    from lightgbm_tpu.ops.split import routed_left
     empty = ph.empty_lookahead_slots(K - 1)
     ok = True
     for k in range(1, K):

@@ -91,25 +91,6 @@ def summarize(stats: dict, top: int = 6) -> str:
                      f"{int(seg.get('seg/scanned_blocks', 0))} blocks "
                      f"scanned, {int(seg.get('seg/compactions', 0))} "
                      f"compactions")
-    # histogram variant counters (packed accumulator / round-carry
-    # staging).  n/a-safe: absent entirely on the f32/unstaged paths —
-    # rendering zero rates there would read as "variant ran and did
-    # nothing", so only live counters print
-    hist = {k: v for k, v in counters.items() if k.startswith("hist/")}
-    if hist:
-        parts = []
-        if hist.get("hist/quant_rescales"):
-            parts.append(f"{int(hist['hist/quant_rescales'])} quant "
-                         f"rescales ({int(hist.get('hist/quant_clips', 0))}"
-                         f" saturated lanes)")
-        looks = hist.get("hist/stage_lookups")
-        if looks:
-            hits = int(hist.get("hist/stage_hits", 0))
-            parts.append(f"stage hits {hits}/{int(looks)} "
-                         f"({hits / max(int(looks), 1):.0%})")
-        if parts:
-            lines.append("  histogram variants: " + ", ".join(parts))
-
     network = stats.get("network") or {}
     if network:
         parts = [f"{k}={v.get('calls', 0)}x/"

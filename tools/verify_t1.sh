@@ -49,12 +49,11 @@
 #                                             by bench_gate.py; then the
 #                                             gate's own self-test;
 #                                             writes no repo artifacts)
-#        bash tools/verify_t1.sh --with-kernel-checks (also run every
-#                                             kernel variant self-check —
-#                                             fused route, fused-K
-#                                             route+histogram, packed
-#                                             accumulator, one-hot builds,
-#                                             round-carry staging — on the
+#        bash tools/verify_t1.sh --with-kernel-checks (also run the
+#                                             default path's three kernel
+#                                             self-checks — fused route,
+#                                             route window, score kernel —
+#                                             on the
 #                                             CPU interpret backend so CI
 #                                             catches parity regressions;
 #                                             on-chip runs catch lowering
