@@ -91,6 +91,9 @@ def _record_seg_stats(rows: np.ndarray, trees: int,
     # what turns blocks into rows and totals into per-tree figures
     TELEMETRY.counter_add("seg/trees", int(trees))
     TELEMETRY.gauge_set("seg/block_rows", int(block_rows))
+    # the budget the run compacted under (compaction_budget_blocks)
+    TELEMETRY.gauge_set("seg/compact_budget_blocks",
+                        int(c.compact_budget.max()))
     # the strict grower's shape facts: feature tiles a pass walks (1: the
     # table whole) and the bytes of its per-leaf histogram tables
     if c.feature_tiles.max():

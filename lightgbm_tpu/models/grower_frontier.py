@@ -46,8 +46,9 @@ from ..ops.split import (NEG_INF, FeatureMeta, best_split,
                          expand_group_hist)
 from .grower import (CommHooks, GrowerParams, _node_feature_mask,
                      mono_handoff)
-from .grower_seg import (COMPACT_WASTE, _COMPACT_MUT, _SegState, _unpermute,
-                         apply_route, compact_state, cond_narrow,
+from .grower_seg import (_COMPACT_MUT, _SegState, _unpermute,
+                         apply_route, compact_state,
+                         compaction_budget_blocks, cond_narrow,
                          fresh_state, seg_stats_vector, stripe_histogram)
 
 
@@ -403,8 +404,7 @@ def make_grow_tree_frontier(num_bins: int, params: GrowerParams,
             return cond_narrow(st.scanned_since >= limit_blocks,
                                compact, st, _COMPACT_MUT)
 
-        limit_blocks = min(max(1, int(COMPACT_WASTE * max_blocks)),
-                           2**31 - 1)
+        limit_blocks = compaction_budget_blocks(G_cols, B, n, rb, p.packed4)
 
         st = fresh_state(binsT, w8, n, L, G_cols, B, F, max_blocks,
                          G0, H0, C0, fmeta, p)
@@ -439,7 +439,8 @@ def make_grow_tree_frontier(num_bins: int, params: GrowerParams,
         # host callbacks); printing is env-gated at call sites
         stats = seg_stats_vector(
             scanned_blocks=st.scanned_total, compactions=st.num_sorts,
-            grid_steps=st.grid_total, max_blocks=max_blocks, batch_k=K)
+            grid_steps=st.grid_total, max_blocks=max_blocks,
+            compact_budget=limit_blocks, batch_k=K)
         return st.tree, leaf_id_orig, stats
 
     if wrap is not None:

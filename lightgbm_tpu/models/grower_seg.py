@@ -9,10 +9,12 @@ re-sorting, src/io/ordered_sparse_bin.hpp).  TPUs can't afford a physical
 re-partition per split (data-dependent scatter), so this grower uses
 *epoch compaction*:
 
-  * rows live in a permuted order (``order[pos] -> original row``); at a
-    few leaf-count milestones the whole layout is re-sorted by ``leaf_id``
-    with one ``lax.sort`` (stable, ~N log N but bandwidth-shaped on TPU —
-    measured ~5ms/1M rows for the full payload);
+  * rows live in a permuted order (``order[pos] -> original row``); each
+    time the kernels have accumulated over a budget of blocks
+    (``compaction_budget_blocks``: 2 to 9 tables, by what a compaction
+    costs at the shape against a full pass) the whole layout is
+    re-sorted by ``leaf_id``, with one stable variadic ``lax.sort`` or,
+    for wide tables, an argsort and gathers (``compact_state``);
   * between compactions rows never move, so every leaf's rows stay
     *confined* to the block interval its nearest compacted ancestor
     occupied — descendants only refine within it;
@@ -37,6 +39,8 @@ only — the distributed learners keep the fused grower for now.
 
 from __future__ import annotations
 
+import math
+import os as _os
 from typing import NamedTuple
 
 import jax
@@ -59,23 +63,113 @@ from ..ops.split import (NEG_INF, FeatureMeta, best_split, expand_group_hist,
 from .grower import (CommHooks, GrowerParams, TreeArrays,
                      _node_feature_mask, mono_handoff)
 
-# Adaptive compaction: re-sort whenever the histogram kernels have scanned
-# more than COMPACT_WASTE x N rows of confinement intervals since the last
-# compaction.  Fixed leaf-count milestones (round 2) let waste balloon on
-# skewed trees — best-first growth keeps splitting inside one big segment,
-# so "compact at 4/16/64/256 leaves" could scan 30-40 N-equivalents per
-# tree; the amortized rule bounds scan waste at ~(1 + COMPACT_WASTE/2) x
-# ideal while the number of sorts stays <= total_scanned / (COMPACT_WASTE
-# x N).  Overridable via LIGHTGBM_TPU_COMPACT_WASTE (in N multiples).
-# Default from the v5e sweeps at 10.5M rows (rounds 4-5, 2026-08).  Round
-# 4 (waste=1..6): strict 3.13 / 2.30 / 1.91 / 1.45, frontier 1.28 (3.0)
-# / 1.12 (6.0) — the full-payload sort costs ~136-190 ms in context so
-# fewer sorts win.  Round 5 refined around the knee (frontier, stats
-# on): 6.0 -> 1.017 (2 sorts), 9.0 -> 0.929 (1 sort), 12.0 -> 0.985
-# (scan growth overtakes); strict likewise prefers ~10 (1.42 -> 1.26).
-import os as _os
+# above this many sort operands, compact via argsort + matrix gathers:
+# XLA's variadic TPU sort compile time explodes with operand count
+# (measured on v5e 2026-08-01: 12 operands at 56k rows = 94 s compile;
+# the 39-operand sort a 136-feature dataset produces never finished
+# inside a 70-minute budget and took the whole lambdarank-suite tier
+# with it).  The gather path compiles in seconds; which of the two is
+# cheaper a row depends on the table's width (compaction_unit_costs).
+_MAX_SORT_OPERANDS = 16
 
-COMPACT_WASTE = float(_os.environ.get("LIGHTGBM_TPU_COMPACT_WASTE", "9.0"))
+
+def _sort_operands(bin_rows: int) -> int:
+    """Operands of the compaction's variadic sort for a table of
+    ``bin_rows`` physical bin rows: the key, the bin words (4 rows a
+    word), the six live weight channels as 3 halfword pairs, the order."""
+    return 1 + bin_rows // 4 + 3 + 1
+
+
+# -- the compaction trigger's budget -----------------------------------
+# Adaptive compaction: the layout is re-sorted once the histogram kernels
+# have accumulated over ``compaction_budget_blocks`` blocks of confinement
+# intervals since the last compaction.  Fixed leaf-count milestones let
+# waste balloon on skewed trees (best-first growth keeps splitting inside
+# one big segment); a scanned-blocks budget bounds it whatever the tree.
+# The budget follows from two unit costs of the shape, one full
+# accumulating pass (N) and one compaction (c x N):
+#   * A pass covers the PARENT's confinement interval, so every level of
+#     the tree costs ~1 N of scanning however often one compacts (~11 N a
+#     255-leaf tree at best), and a lookahead lane set is only filled for
+#     a pending leaf whose interval lies wholly inside the pass: right
+#     after a compaction every leaf is alone in its interval, so early
+#     compaction COSTS lookahead hits.  Under ~2 N the first epoch
+#     re-sorts a table of one or two leaves.
+#   * Replayed on the trees of epsilon63-train (tools/compaction_replay.py,
+#     which reproduces the chip's seg/* counters tree for tree; PERF.md
+#     section 6, PR 33) the best budget is ~2.25 N at c = 0.1, 3 N at 0.33,
+#     4.5 N at 1-2, 7 N at 3 and 9 N from 4 up, each optimum flat over a
+#     factor of two: 2.5 + 1.6 c tables, held between 2 and 9.
+#   * 9 N is the knee of the chip sweeps where a compaction costs several
+#     passes (c ~ 7.7 at 36.75M x 28 x 64, the 12-operand sort): one
+#     compaction a tree, and nothing on the chip has timed more.
+# The constants are a TPU v5e's (PERF.md sections 5 and 6; timed alone
+# by tools/seg_pass_bench.py --unit-costs, my chip runs, PR 33).
+_MXU_MACS_PER_NS = 98.5e3       # 197 TFLOP/s in bf16
+# of that, in a full pass with every lane set live: 0.83-0.85 where the
+# table goes whole (2.82 ns a row at 28 x 64, 4.37 at 44, 4.73 at 48),
+# 0.92 in 16 feature tiles (185.5 ns a row at 2000 x 64)
+_PASS_MXU_SHARE = 0.87
+# the variadic sort with its word packing, a row and operand at 2**25
+# rows; it grows as log2(rows)**2 (20.7 ns a row at 12 operands and
+# 36.77M rows in higgs63-train's trace, 22.8 at 16 operands and 10.5M)
+_SORT_NS_ROW_OPERAND = 1.707
+# the gathers that follow the two-operand sort, alone on the chip: a row
+# (49.6 ns at 48 columns and 10.5M rows, all but 3.5 of them here) and a
+# byte of it (64.5 ns a row at 2,064 bytes and 1.1M rows)
+_GATHER_NS_ROW = 46.0
+_GATHER_NS_BYTE = 0.008
+_BUDGET_BASE_N = 2.5
+_BUDGET_N_PER_COST = 1.6
+_BUDGET_MIN_N, _BUDGET_MAX_N = 2.0, 9.0
+
+
+def table_bin_rows(columns: int, num_bins: int, packed4: bool) -> int:
+    """Physical bin rows of the table as ``grow`` holds it: padded to
+    whole sort words, or to whole feature tiles where a pass walks tiles."""
+    tile = feature_tile(columns, num_bins)
+    phys = (columns + 1) // 2 if packed4 else columns
+    unit = (tile // 2 if packed4 else tile) if tile < columns else 4
+    return -(-phys // unit) * unit
+
+
+def compaction_unit_costs(columns: int, num_bins: int, rows: int,
+                          packed4: bool) -> dict:
+    """The trigger's two unit costs at this shape, in ns a row: a full
+    accumulating pass (the padded-lane MXU model the kernel is measured
+    against: every feature tile's columns x bins x 128 lanes of
+    multiply-adds a row) and one ``compact_state`` on the ``path`` it
+    takes for this width."""
+    tile = feature_tile(columns, num_bins)
+    pass_ns = (-(-columns // tile) * tile * num_bins * 128
+               / _MXU_MACS_PER_NS / _PASS_MXU_SHARE)
+    bin_rows = table_bin_rows(columns, num_bins, packed4)
+    operands = _sort_operands(bin_rows)
+    sort_ns = _SORT_NS_ROW_OPERAND * (math.log2(max(rows, 2)) / 25.0) ** 2
+    if operands <= _MAX_SORT_OPERANDS:
+        path, compact_ns = "sort", sort_ns * operands
+    else:
+        # the permutation's two-operand sort, then every byte of the row
+        # (bins, six bf16 channels, order) gathered once
+        path = "gather"
+        compact_ns = (2 * sort_ns + _GATHER_NS_ROW
+                      + _GATHER_NS_BYTE * (bin_rows + 12 + 4))
+    return {"path": path, "pass_ns_per_row": pass_ns,
+            "compaction_ns_per_row": compact_ns}
+
+
+def compaction_budget_blocks(columns: int, num_bins: int, rows: int,
+                             block_rows: int, packed4: bool) -> int:
+    """Blocks the kernels may accumulate over between two compactions
+    (a Python int, baked into the epoch loops' predicates at trace time):
+    the more passes a compaction costs, the more tables of scanning go
+    before one, between ``_BUDGET_MIN_N`` and ``_BUDGET_MAX_N``."""
+    cost = compaction_unit_costs(columns, num_bins, rows, packed4)
+    c = cost["compaction_ns_per_row"] / cost["pass_ns_per_row"]
+    budget_n = min(max(_BUDGET_BASE_N + _BUDGET_N_PER_COST * c,
+                       _BUDGET_MIN_N), _BUDGET_MAX_N)
+    # compared against an i32 counter
+    return min(max(1, int(budget_n * (rows // block_rows))), 2**31 - 1)
 
 
 class SegStats(NamedTuple):
@@ -88,6 +182,7 @@ class SegStats(NamedTuple):
     compactions: object
     grid_steps: object          # kernel grid steps of accumulating passes
     max_blocks: object          # blocks of the whole table
+    compact_budget: object      # blocks accumulated between compactions
     batch_k: object             # leaves split a round (1: strict)
     splits: object
     lookahead_hits: object      # splits served by a lookahead histogram
@@ -386,24 +481,13 @@ def _unpermute(order, leaf_id):
     return lax.sort((order, leaf_id), num_keys=1)[1]
 
 
-# above this many sort operands, compact via argsort + matrix gathers:
-# XLA's variadic TPU sort compile time explodes with operand count
-# (measured on v5e 2026-08-01: 12 operands at 56k rows = 94 s compile;
-# the 39-operand sort a 136-feature dataset produces never finished
-# inside a 70-minute budget and took the whole lambdarank-suite tier
-# with it).  The gather path runs slower per sort (round-3 micro) but
-# compiles in seconds and compaction is ~1 sort/tree at the default
-# waste budget.
-_MAX_SORT_OPERANDS = 16
-
-
 def compact_state(st: _SegState, L: int, rb: int) -> _SegState:
     """Stable-sort the whole layout by leaf_id; leaves become contiguous
     segments and confinement intervals reset to them.  Shared by the
     strict and frontier growers (identical _SegState layout)."""
     W = st.binsT.shape[0] // 4
     wrows = 3       # the six live channels as halfword pairs
-    if W + 2 + wrows <= _MAX_SORT_OPERANDS:
+    if _sort_operands(st.binsT.shape[0]) <= _MAX_SORT_OPERANDS:
         operands = ((st.leaf_id,)
                     + tuple(_pack_bins_words(st.binsT))
                     + tuple(_pack_w8_words(st.w8))
@@ -973,8 +1057,7 @@ def make_grow_tree_segment(num_bins: int, params: GrowerParams,
         # keeps its own layout: four copies of 392 MB a split at 2000
         # columns x 64 bins, 1.77 s of a 5.35 s iteration (ledger, PR 30;
         # tests/test_tpu_compile.py holds the loop to none).
-        limit_blocks = min(max(1, int(COMPACT_WASTE * max_blocks)),
-                           2**31 - 1)   # compared against an i32 counter
+        limit_blocks = compaction_budget_blocks(G_cols, B, n, rb, p.packed4)
 
         def can_grow(st: _SegState):
             return (st.num_leaves < L) & (jnp.max(st.best_f32[:, 0]) > 0.0)
@@ -1015,7 +1098,8 @@ def make_grow_tree_segment(num_bins: int, params: GrowerParams,
         # LIGHTGBM_TPU_SEG_STATS at the call sites
         stats = seg_stats_vector(
             scanned_blocks=st.scanned_total, compactions=st.num_sorts,
-            grid_steps=st.grid_total, max_blocks=max_blocks, batch_k=1,
+            grid_steps=st.grid_total, max_blocks=max_blocks,
+            compact_budget=limit_blocks, batch_k=1,
             splits=st.num_splits, lookahead_hits=st.look_hits,
             lookahead_filled=st.look_filled,
             route_only_blocks=st.route_only, feature_tiles=n_tiles,

@@ -129,7 +129,7 @@ def test_env_knobs_are_the_documented_ones():
     docs = set().union(*(described_in(p) for p in glob.glob(
         os.path.join(root, "docs", "*.md"))))
     readme = described_in(os.path.join(root, "README.md"))
-    assert len(library) == 20, sorted(library)
+    assert len(library) == 19, sorted(library)
     assert not library - docs, f"read, documented nowhere: {library - docs}"
     assert not (docs | readme) - repo, (
         f"documented, read nowhere: {(docs | readme) - repo}")

@@ -262,7 +262,7 @@ def test_segment_epoch_edges(rng, monkeypatch):
     y = (X[:, 0] - 0.5 * X[:, 1] > 0).astype(np.float64)
 
     with monkeypatch.context() as mp:
-        mp.setattr(gs, "COMPACT_WASTE", 0.01)
+        mp.setattr(gs, "compaction_budget_blocks", lambda *a: 1)
         fused, seg = _train_pair(X, y, rng, n_iters=2, objective="binary",
                                  num_leaves=15, max_bin=31,
                                  min_data_in_leaf=5)
@@ -418,3 +418,87 @@ def test_grid_steps_are_scanned_blocks_times_tiles(shape, rng, monkeypatch,
         assert st.batch_k == (4 if shape == "frontier_k4" else 1)
         assert st.scanned_blocks > st.max_blocks > 1
         assert st.grid_steps == st.scanned_blocks * max(tiles, 1)
+
+
+# ---------------------------------------------- the compaction trigger's
+# budget (grower_seg.compaction_budget_blocks)
+
+def test_budget_at_the_two_cells():
+    """``higgs63-train``'s shape keeps 9 N to the block (the program of
+    before); ``epsilon63-train``'s, where a compaction costs a third of
+    a pass, lands in the flat optimum of the replay."""
+    from lightgbm_tpu.models import grower_seg as gs
+    assert gs.compaction_budget_blocks(28, 64, 36_765_696, 32_768,
+                                       False) == 10_098
+    eps = gs.compaction_budget_blocks(2000, 64, 1_105_920, 8_192, False)
+    assert 2.5 * 135 <= eps <= 5 * 135
+    higgs = gs.compaction_unit_costs(28, 64, 36_765_696, False)
+    wide = gs.compaction_unit_costs(2000, 64, 1_105_920, False)
+    assert (higgs["path"], wide["path"]) == ("sort", "gather")
+    # the chip's own readings (PERF.md sections 5 and 6): 2.82 and 185.5
+    # ns a row of a pass, 20.7 and 64.5 of a compaction
+    assert higgs["pass_ns_per_row"] == pytest.approx(2.82, rel=0.1)
+    assert wide["pass_ns_per_row"] == pytest.approx(185.5, rel=0.1)
+    assert higgs["compaction_ns_per_row"] == pytest.approx(20.7, rel=0.05)
+    assert wide["compaction_ns_per_row"] == pytest.approx(64.5, rel=0.05)
+    # and alone at the two sides of the switch, 10.5M rows: 22.8 and 49.6
+    assert gs.compaction_unit_costs(44, 64, 10_502_144, False)[
+        "compaction_ns_per_row"] == pytest.approx(22.8, rel=0.1)
+    assert gs.compaction_unit_costs(48, 64, 10_502_144, False)[
+        "compaction_ns_per_row"] == pytest.approx(49.6, rel=0.1)
+
+
+def test_budget_is_monotone_in_the_cost_ratio(monkeypatch):
+    """More passes a compaction, more scanning before one; never under
+    2 N (the first epoch would re-sort a table of one or two leaves) nor
+    over 9 N."""
+    from lightgbm_tpu.models import grower_seg as gs
+    nb, rb = 640, 16_384
+    budgets = []
+    for c in (0.0, 0.1, 0.35, 1.0, 2.0, 4.0, 7.6, 30.0):
+        monkeypatch.setattr(
+            gs, "compaction_unit_costs", lambda *a, c=c: {
+                "path": "sort", "pass_ns_per_row": 1.0,
+                "compaction_ns_per_row": c})
+        budgets.append(gs.compaction_budget_blocks(44, 64, nb * rb, rb,
+                                                   False))
+    assert budgets == sorted(budgets)
+    assert budgets[0] >= 2 * nb and budgets[-1] == 9 * nb
+    assert budgets[0] < budgets[-1]
+    assert all(isinstance(b, int) for b in budgets)
+
+
+@pytest.mark.parametrize("columns,bins,packed4", [
+    (44, 64, False), (48, 64, False), (28, 64, False), (2000, 64, False),
+    (88, 16, True), (90, 16, True)],
+    ids=["44_sorts", "48_gathers", "higgs", "epsilon", "packed_88_sorts",
+         "packed_90_gathers"])
+def test_budget_assumes_the_path_compact_state_takes(columns, bins, packed4):
+    """On both sides of ``_MAX_SORT_OPERANDS`` the cost is reckoned for
+    the path the compaction takes for the table as ``grow`` pads it:
+    a variadic sort of the packed words, or a two-operand sort and
+    gathers."""
+    import jax
+    from lightgbm_tpu.models import grower_seg as gs
+    from lightgbm_tpu.ops.pallas_histogram import feature_tile
+    rb, n, L = 8, 64, 4
+    # grow()'s padding of the physical bin rows
+    n_phys = (columns + 1) // 2 if packed4 else columns
+    tile = feature_tile(columns, bins)
+    tiles = -(-columns // tile)
+    n_phys += (-n_phys) % ((tile // 2 if packed4 else tile)
+                           if tiles > 1 else 4)
+    assert n_phys == gs.table_bin_rows(columns, bins, packed4)
+    st = gs.fresh_state(jnp.zeros((n_phys, n), jnp.uint8),
+                        jnp.zeros((8, n), jnp.bfloat16), n, L, 4, 16, 4,
+                        n // rb, 0.0, 1.0, 1.0, None,
+                        gs.GrowerParams(num_leaves=L))
+    jaxpr = jax.make_jaxpr(lambda s: gs.compact_state(s, L, rb))(st)
+    widest = max(len(e.invars) for e in jaxpr.jaxpr.eqns
+                 if e.primitive.name == "sort")
+    took = "gather" if widest == 2 else "sort"
+    assert widest in (2, gs._sort_operands(n_phys))
+    assert took == gs.compaction_unit_costs(columns, bins, n * 1000,
+                                            packed4)["path"]
+    assert (took == "sort") == (gs._sort_operands(n_phys)
+                                <= gs._MAX_SORT_OPERANDS)

@@ -66,6 +66,14 @@ def _data(shape, rng):
         y = effect[Xc] + Xn[:, 0] + 0.1 * rng.normal(size=n)
         return X, y, dict(objective="regression", max_bin=63,
                           min_data_per_group=20, cat_smooth=1.0), [0]
+    if shape == "wide":
+        # three feature tiles a pass and a compaction that gathers: it
+        # costs about one pass, so the derived budget re-sorts often
+        n = 3000
+        X = rng.normal(size=(n, 300))
+        y = (X[:, 0] + 0.5 * X[:, 1] - 0.3 * X[:, 2] ** 2
+             + 0.1 * rng.normal(size=n) > 0).astype(np.float64)
+        return X, y, dict(objective="binary", max_bin=63), []
     if shape == "chain":
         # one dominant feature: the child that keeps its parent's id is
         # split again and again, the case a stale entry would corrupt
@@ -82,7 +90,7 @@ class _Case:
     seeded (grad, hess, member) triple for it."""
 
     def __init__(self, shape, leaves=40):
-        rng = np.random.RandomState(SHAPES.index(shape))
+        rng = np.random.RandomState((SHAPES + SHAPES_MORE).index(shape))
         X, y, params, cats = _data("binary" if shape == "bagging"
                                    else shape, rng)
         cfg = Config(verbosity=-1, tpu_histogram_backend="pallas",
@@ -123,12 +131,15 @@ class _Case:
         """A grower of this case's shape, traced under the patches:
         ``look`` the program as built, ``nofill`` the same program whose
         slots are never filled, ``k1`` one lane set (the program before
-        lookahead).  ``waste`` replaces COMPACT_WASTE (1e6: never);
+        lookahead).  ``waste`` tables of scanning replace the budget
+        ``compaction_budget_blocks`` derives from the shape (1e6: never);
         ``plain_reads`` takes ``leaf_hist[leaf]`` and ``look_hist[leaf]``
         by plain indexing where they are used, unpinned (the program
         before ``_pinned_row``)."""
         if waste is not None:
-            mp.setattr(gs, "COMPACT_WASTE", waste)
+            mp.setattr(gs, "compaction_budget_blocks",
+                       lambda columns, bins, rows, rb, packed4: max(
+                           1, int(waste * (rows // rb))))
         if plain_reads:
             mp.setattr(gs, "_pinned_row", lambda table, i: (table[i], table))
         if variant == "nofill":
@@ -167,6 +178,8 @@ def _assert_same_tree(a, b, rtol=2e-4):
 
 SHAPES = ["binary", "packed4", "missing_nan", "categorical", "bagging",
           "chain"]
+# not in the bit-identity matrix: built for the derived budget's case
+SHAPES_MORE = ["wide"]
 
 
 @pytest.fixture(scope="module")
@@ -176,7 +189,8 @@ def cases():
 
 def _case(cases, shape):
     if shape not in cases:
-        cases[shape] = _Case(shape)
+        # the wide table: leaves enough for several compactions a tree
+        cases[shape] = _Case(shape, leaves=96 if shape == "wide" else 40)
     return cases[shape]
 
 
@@ -222,15 +236,24 @@ def test_no_compaction_bit_identical_to_unfilled(cases, shape, against,
     assert total_hits > 0, "no split was served by a lookahead histogram"
 
 
-@pytest.mark.parametrize("waste", [9.0, 0.5, 0.01],
-                         ids=["default", "often", "every_split"])
-@pytest.mark.parametrize("shape", ["binary", "categorical", "bagging"])
+@pytest.mark.parametrize(
+    "shape,waste",
+    [(s, w) for s in ("binary", "categorical", "bagging")
+     for w in (9.0, 0.5, 0.01)] + [("wide", None)],
+    ids=[f"{w}-{s}" for s in ("binary", "categorical", "bagging")
+         for w in ("default", "often", "every_split")] + ["derived-wide"])
 def test_compaction_same_tree(cases, shape, waste, monkeypatch):
     """(b): with compaction on (down to one after nearly every split, the
     epoch edge), a lookahead histogram filled before a sort and used after
     it gives the splits and partition of the unfilled program and of the
-    one-lane-set program; only the order of the sums differs."""
+    one-lane-set program; only the order of the sums differs.
+    ``derived``: the budget as the function gives it for a wide table,
+    where a compaction is cheap and a tree takes several."""
     case = _case(cases, shape)
+    if waste is None:
+        budget = gs.compaction_budget_blocks(300, case.bst.num_bins,
+                                             case.npad, RB, False)
+        assert budget < 6 * (case.npad // RB)      # 9 N at 28 columns
     with monkeypatch.context() as mp:
         look = case.grower(mp, "look", waste=waste)
     with monkeypatch.context() as mp:
@@ -238,7 +261,8 @@ def test_compaction_same_tree(cases, shape, waste, monkeypatch):
     with monkeypatch.context() as mp:
         k1 = case.grower(mp, "k1", waste=waste)
     sorts = hits = 0
-    for seed in range(4):
+    seeds = range(2 if waste is None else 4)
+    for seed in seeds:
         a = case.grow(look, seed)
         _assert_same_tree(a, case.grow(ref, seed))
         _assert_same_tree(a, case.grow(k1, seed))
@@ -246,7 +270,9 @@ def test_compaction_same_tree(cases, shape, waste, monkeypatch):
         hits += a[2][HITS]
         assert a[2][FILLED] >= a[2][HITS]
     assert hits > 0
-    if waste < 9.0:
+    if waste is None:
+        assert sorts >= 2 * len(seeds), "several compactions a tree"
+    elif waste < 9.0:
         assert sorts > 0, "no compaction fell between fill and use"
 
 

@@ -561,6 +561,11 @@ def test_chunked_seg_counters_match_per_iteration_path(monkeypatch):
     assert c["transfer/fetch_calls"] == 2        # two chunks, trees only
     rb = bst.gbdt.grower_params.row_chunk
     assert stats["gauges"]["seg/block_rows"] == rb
+    # the budget the run compacted under, as the grower derived it
+    from lightgbm_tpu.models.grower_seg import compaction_budget_blocks
+    assert stats["gauges"]["seg/compact_budget_blocks"] == \
+        compaction_budget_blocks(8, bst.gbdt.num_bins,
+                                 -(-2500 // rb) * rb, rb, False)
     # the root alone scans every block of every tree
     assert c["seg/scanned_blocks"] >= 8 * -(-2500 // rb)
     chunked = {k: c[k] for k in SEG_KEYS}

@@ -11,7 +11,11 @@ and how exact its sums are.
     lane set sums before the first compaction) and for the same number of
     rows packed into a tight interval (what the scan it replaces sums
     after one): the routed kernel's single f32 total against the lookahead
-    kernel's (hi, lo) pair across blocks.
+    kernel's (hi, lo) pair across blocks;
+  * ``--unit-costs``: the two unit costs of the grower's compaction
+    trigger alone, a full accumulating pass and one ``compact_state`` of
+    the same table on the path its width takes, beside what
+    ``grower_seg.compaction_budget_blocks`` reckons for them.
 
     python tools/seg_pass_bench.py [--rows 36750000] [--bins 64]
 """
@@ -26,6 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 
 sys.path.insert(0, __file__.rsplit("/", 2)[0])
+from lightgbm_tpu.models import grower_seg as gs  # noqa: E402
 from lightgbm_tpu.ops import pallas_histogram as ph  # noqa: E402
 
 
@@ -48,6 +53,42 @@ def _gaps(got, want):
     return {"max": float(g.max()), "p50": float(np.median(g))}
 
 
+def _timed(fn, reps):
+    """Median seconds of ``reps`` calls after one that warms up."""
+    jax.block_until_ready(fn())
+    ts = []
+    for _ in range(reps):
+        t0 = time.perf_counter()
+        jax.block_until_ready(fn())
+        ts.append(time.perf_counter() - t0)
+    return float(np.median(ts))
+
+
+def _compaction_costs(a, binsT, w8, n, nblk, rb):
+    """One ``compact_state`` of the table alone (leaf ids of 64 leaves
+    spread over every block, as before a tree's first compaction), and
+    the trigger's own reckoning for this shape."""
+    L = 255
+    st = gs.fresh_state(binsT, w8, n, L, 4, 16, 4, nblk, 0.0, 1.0, 1.0, None,
+                        gs.GrowerParams(num_leaves=L))
+    st = st._replace(leaf_id=jax.random.randint(
+        jax.random.PRNGKey(3), (n,), 0, 64, jnp.int32))
+    compact = jax.jit(lambda s: gs.compact_state(s, L, rb))
+    t0 = time.perf_counter()
+    jax.block_until_ready(compact(st))      # the build, for the record
+    first = time.perf_counter() - t0
+    t = _timed(lambda: compact(st), a.reps)
+    model = gs.compaction_unit_costs(a.features, a.bins, n, False)
+    return {"compaction_path": model["path"],
+            "compaction_s": t, "compaction_ns_per_row": t / n * 1e9,
+            "compaction_first_call_s": first,
+            "model_pass_ns_per_row": model["pass_ns_per_row"],
+            "model_compaction_ns_per_row": model["compaction_ns_per_row"],
+            "budget_blocks": gs.compaction_budget_blocks(
+                a.features, a.bins, n, rb, False),
+            "max_blocks": nblk}
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--rows", type=int, default=36_750_000)
@@ -62,6 +103,8 @@ def main():
     ap.add_argument("--unroll", type=int, default=0,
                     help="chunks a loop body of the lookahead kernel "
                          "(0: as built)")
+    ap.add_argument("--unit-costs", action="store_true",
+                    help="time a full pass and a compaction, nothing else")
     ap.add_argument("--rehearse", action="store_true",
                     help="walk the code on any backend; its times are not "
                          "device times")
@@ -76,7 +119,14 @@ def main():
     nblk = n // rb
     meta = _Meta(F, B)
     kb, kg, kh, kl, km = jax.random.split(jax.random.PRNGKey(0), 5)
-    binsT = jax.random.randint(kb, (F, n), 0, B, jnp.int32).astype(jnp.uint8)
+    # the table as the grower holds it (bin rows padded to whole sort
+    # words or feature tiles), drawn 128 rows at a time (4 bytes a bin)
+    F_rows = gs.table_bin_rows(F, B, False)
+    binsT = jnp.concatenate([
+        jax.random.randint(jax.random.fold_in(kb, r), (min(128, F - r), n),
+                           0, B, jnp.int32).astype(jnp.uint8)
+        for r in range(0, F, 128)]
+        + [jnp.zeros((F_rows - F, n), jnp.uint8)] * (F_rows > F))
     # the second tree of a binary run: |g| near 0.5, h near 0.25
     p = jax.nn.sigmoid(0.13 * jnp.sign(jax.random.normal(kg, (n,)))
                        + 0.01 * jax.random.normal(kh, (n,)))
@@ -104,27 +154,32 @@ def main():
            "unroll": ph._LOOKAHEAD_UNROLL}
 
     def timed(fn):
-        jax.block_until_ready(fn())
-        ts = []
-        for _ in range(a.reps):
-            t0 = time.perf_counter()
-            jax.block_until_ready(fn())
-            ts.append(time.perf_counter() - t0)
-        return float(np.median(ts))
+        return _timed(fn, a.reps)
+
+    def note(key, value):
+        out[key] = value
+        print(f"{key} = {value}", file=sys.stderr, flush=True)
 
     s0, nb, tgt = jnp.int32(0), jnp.int32(nblk), jnp.int32(24)
-    t = timed(lambda: ph.histogram_segment_routed(
-        binsT, w8, lid, s0, nb, tgt, route, B, rb))
-    out["ns_per_row_routed"] = t / n * 1e9
+    if not a.unit_costs:    # one kernel build less where only the pass counts
+        t = timed(lambda: ph.histogram_segment_routed(
+            binsT, w8, lid, s0, nb, tgt, route, B, rb))
+        note("ns_per_row_routed", t / n * 1e9)
     for name, live in (("full", K - 1), ("empty", 0)):
         sl = slots_for(live)
         t = timed(lambda: ph.histogram_segment_lookahead(
             binsT, w8, lid, s0, nb, tgt, route, sl, nb, B, rb))
-        out[f"ns_per_row_lookahead_{name}"] = t / n * 1e9
+        note(f"ns_per_row_lookahead_{name}", t / n * 1e9)
     sl = slots_for(K - 1)
     t = timed(lambda: ph.histogram_segment_lookahead(
         binsT, w8, lid, s0, nb, tgt, route, sl, jnp.int32(0), B, rb))
-    out["route_only_us_per_block"] = t / nblk * 1e6
+    note("route_only_us_per_block", t / nblk * 1e6)
+    if a.unit_costs:
+        for key, value in _compaction_costs(a, binsT, w8, n, nblk,
+                                            rb).items():
+            note(key, value)
+        print(json.dumps(out))
+        return
 
     # every lane set against lane set 0 of a pass over its rows
     m = jnp.int32(min(nblk, 24))
