@@ -243,7 +243,7 @@ def train(params: Dict[str, Any], train_set: Dataset,
                 it = i + step - 1
 
                 if use_inscan:
-                    with GLOBAL_TIMER.phase("callbacks"):
+                    with GLOBAL_TIMER.phase("eval_replay"):
                         stopped_early = replay_inscan(base_iter)
                     if stopped_early or should_stop:
                         break

@@ -355,10 +355,12 @@ def build_device_eval(gbdt, include_train: bool):
     vbins = tuple(vset.device_binned() for _, vset in gbdt.valid_sets)
 
     def eval_fn(train_score, vscores, arrs_tuple):
-        outs = []
-        for (src, set_fn), A in zip(progs, arrs_tuple):
-            s = train_score if src < 0 else vscores[src]
-            outs.append(set_fn(s, A))
-        return jnp.concatenate(outs)
+        # the scope is the metrics' name in any device trace
+        with jax.named_scope("eval_metric"):
+            outs = []
+            for (src, set_fn), A in zip(progs, arrs_tuple):
+                s = train_score if src < 0 else vscores[src]
+                outs.append(set_fn(s, A))
+            return jnp.concatenate(outs)
 
     return DeviceEval(tuple(columns), eval_fn, tuple(arrays), vbins), None

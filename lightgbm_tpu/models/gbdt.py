@@ -327,12 +327,27 @@ def _route_tree_rows(arrays, vbins, fmeta, depth_bound: int):
     # Stop at the tree's own depth: with max_depth unbounded depth_bound is
     # num_leaves, and a step past the deepest leaf moves no row yet still
     # pays its dozen [Nv] gathers (at 255 leaves and 500k held-out rows the
-    # 255 fixed steps were ~10 s per tree on a v5e, PERF.md PR 21).
-    _, node = jax.lax.while_loop(
+    # 255 fixed steps were ~10 s per tree on a v5e; the walk to the tree's
+    # depth is in PERF.md section 5, `higgs63-train-eval`).
+    levels, node = jax.lax.while_loop(
         lambda c: (c[0] < depth_bound) & jnp.any(c[1] >= 0),
         lambda c: (c[0] + 1, step(c[0], c[1])),
         (jnp.int32(0), start))
-    return arrays.leaf_value[jnp.maximum(~node, 0)]
+    return arrays.leaf_value[jnp.maximum(~node, 0)], levels
+
+
+def _eval_walk(vscores, vbins, arrays, fmeta, k, shrinkage,
+               depth_bound: int):
+    """One freshly grown class-``k`` tree folded into every valid set's
+    [C, Nv] score carry: (the new carries, the levels walked over all the
+    sets).  The scope is the walk's name in any device trace."""
+    with jax.named_scope("eval_walk"):
+        out, levels = [], jnp.int32(0)
+        for vs, vb in zip(vscores, vbins):
+            vals, walked = _route_tree_rows(arrays, vb, fmeta, depth_bound)
+            out.append(vs.at[k].add(shrinkage * vals))
+            levels = levels + walked
+        return out, levels
 
 
 def _is_oom_error(e: BaseException) -> bool:
@@ -1337,9 +1352,9 @@ class GBDT:
         With ``with_eval`` the scan additionally threads the valid-set
         score vectors through the carry, routes every freshly grown tree
         over each valid set's binned matrix, and runs the attached
-        DeviceEval program per iteration — stacking a [T, n_cols] metric
-        matrix onto the chunk outputs so eval cadence costs zero extra
-        dispatches."""
+        DeviceEval program per iteration — stacking a [T, n_cols + 1]
+        matrix (the metrics, then the levels the walk took) onto the chunk
+        outputs so eval cadence costs zero extra dispatches."""
         cache_key = (T, "eval") if with_eval else T
         fn = self._chunk_fns.get(cache_key)
         if fn is not None:
@@ -1368,6 +1383,7 @@ class GBDT:
                     roots = (roots_core(grads, hesss, member, bins)
                              if roots_core is not None else None)
                     ints_l, floats_l, stats_l = [], [], []
+                    levels = jnp.int32(0)
                     for k in range(C):
                         key, sub = jax.random.split(key)
                         (score, ints_d, floats_d, stats,
@@ -1377,11 +1393,15 @@ class GBDT:
                         ints_l.append(ints_d)
                         floats_l.append(floats_d)
                         stats_l.append(stats)
-                        vscores = [
-                            vs.at[k].add(shrinkage * _route_tree_rows(
-                                arrays, vb, fmeta, depth_bound))
-                            for vs, vb in zip(vscores, vbins)]
-                    mvals = inscan.eval_fn(score, vscores, earrs)
+                        vscores, walked = _eval_walk(
+                            vscores, vbins, arrays, fmeta, k, shrinkage,
+                            depth_bound)
+                        levels = levels + walked
+                    # the walk's levels ride out as one more column of
+                    # the metric row: the same buffer, the same fetch
+                    mvals = jnp.concatenate([
+                        inscan.eval_fn(score, vscores, earrs),
+                        levels.astype(jnp.float32)[None]])
                     return ((score, key, vscores),
                             (jnp.stack(ints_l), jnp.stack(floats_l),
                              gstats, mvals, _stack_seg_stats(stats_l)))
@@ -1474,6 +1494,10 @@ class GBDT:
                 TELEMETRY.counter_add("transfer/eval_fetch_calls")
                 TELEMETRY.counter_add("transfer/eval_fetch_bytes",
                                       int(mv.nbytes))
+                # its last column is the valid-set walk's levels
+                TELEMETRY.counter_add("eval/walk_levels",
+                                      int(mv[:, -1].sum()))
+                mv = mv[:, :-1]
             if payload.seg_stats is not None and TELEMETRY.level >= 1:
                 # 9 int32 a tree that rode out with the tree buffers: no
                 # sync of its own, and not a tree fetch (fetch_calls and
@@ -1574,6 +1598,7 @@ class GBDT:
             # stop are discarded with their trees
             if mrow is not None:
                 self._inscan_evals.append((iter_idx, mrow))
+                TELEMETRY.counter_add("eval/points", len(mrow))
         return False
 
     def _note_trees(self, trees) -> None:
@@ -2451,6 +2476,10 @@ class GBDT:
         if prog is None:
             return blocker
         self._inscan = prog
+        # the rows every grown tree is walked over: with eval/walk_levels,
+        # the walk's work
+        TELEMETRY.gauge_set("eval/valid_rows",
+                            sum(int(vb.shape[0]) for vb in prog.vbins))
         return None
 
     def inscan_result_list(self, vals) -> List[Tuple]:

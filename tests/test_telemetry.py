@@ -604,6 +604,52 @@ def test_chunked_seg_counters_off_at_level0():
     assert lowered(off.gbdt) == lowered(on.gbdt)
 
 
+def _eval_run(**kw):
+    X, y = _seg_data(1200)
+    evals = {}
+    ds = lgb.Dataset(X[:800], y[:800])
+    bst = lgb.train(_params(metric="auc", tpu_boost_chunk=4, **kw), ds,
+                    num_boost_round=8, verbose_eval=False,
+                    valid_sets=[ds.create_valid(X[800:], y[800:])],
+                    evals_result=evals)
+    return bst, evals
+
+
+@pytest.mark.parametrize("level", [1, 2])
+def test_inscan_eval_counters_gauge_and_phase(level):
+    """What the in-scan evaluation records: the walk's levels (out of the
+    scan in the metric matrix's own fetch), the values delivered, the rows
+    walked and the host phase of the replay."""
+    bst, evals = _eval_run(telemetry_level=level)
+    stats = bst.get_stats()
+    c = stats["counters"]
+    assert c["eval/points"] == 8 == len(evals["valid_0"]["auc"])
+    assert stats["gauges"]["eval/valid_rows"] == 400
+    # a level a tree at the least, never past the leaves; whole numbers
+    assert 8 <= c["eval/walk_levels"] <= 8 * 7
+    assert c["eval/walk_levels"] == int(c["eval/walk_levels"])
+    # no transfer of its own: one eval fetch a chunk, 4 bytes an iteration
+    # for the levels beside the one metric column's 4
+    assert c["transfer/eval_fetch_calls"] == 2
+    assert c["transfer/eval_fetch_bytes"] == 8 * (4 + 4)
+    assert stats["phases"]["eval_replay"]["count"] == 2
+    assert "callbacks" not in stats["phases"]
+
+
+def test_inscan_eval_names_cost_nothing_at_level0():
+    """telemetry_level=0: none of the names recorded, the same model bytes
+    and the same metric values."""
+    off, evals_off = _eval_run(telemetry_level=0)
+    stats = off.get_stats()
+    assert not [k for k in list(stats["counters"]) + list(stats["gauges"])
+                if k.startswith(("eval/", "transfer/"))]
+    assert stats["timeline"] == []
+    on, evals_on = _eval_run()
+    assert on.get_stats()["counters"]["eval/points"] == 8
+    assert _trees_of(off) == _trees_of(on)
+    assert evals_off == evals_on
+
+
 def test_timeline_entries_carry_phases():
     """Each chunk's timeline entry holds the per-phase seconds and counts
     since the last mark; the compile is a phase of the first entry

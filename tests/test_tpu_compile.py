@@ -320,3 +320,59 @@ def test_split_loop_copies_no_histogram_table(one_chip, monkeypatch, F, rows):
     # leaf_hist and look_hist, at the parameter and at the root
     assert len(layouts) == 2 and len(layouts[0]) == 2, layouts
     assert layouts[0] == layouts[1], layouts
+
+
+def test_chunk_program_with_evaluation_has_scopes_and_no_carry_copy(
+        one_chip, monkeypatch):
+    """The chunk program with in-scan evaluation (`boost/chunk_eval[16]`) at
+    28 columns x 64 bins, 255 leaves, with a 500,000-row valid set and
+    `metric: auc` (the `higgs63-train-eval` cell's; training rows cut, the
+    evaluation does not depend on them): its device operations carry the
+    `eval_walk` and `eval_metric` scopes, so a trace attributes their time,
+    and the optimized module holds no copy or transpose of the `[C, Nv]`
+    valid-score carry, which the scan threads and the program donates.  The
+    compaction is steered onto its gather path as above (the 12-operand
+    sort takes minutes to compile and is not what is looked at)."""
+    import numpy as np
+
+    import lightgbm_tpu as lgb
+    from lightgbm_tpu.models import grower_seg
+    rows, valid_rows, T = 65_536, 500_000, 16
+    rng = np.random.default_rng(34)
+    X = rng.standard_normal((rows, 28)).astype(np.float32)
+    Xv = rng.standard_normal((valid_rows, 28)).astype(np.float32)
+    params = {"objective": "binary", "metric": "auc", "max_bin": 63,
+              "num_leaves": 255, "min_sum_hessian_in_leaf": 100,
+              "verbose": -1, "tpu_histogram_backend": "pallas",
+              "tpu_tree_impl": "segment", "tpu_boost_chunk": T}
+    ds = lgb.Dataset(X, (X[:, 0] > 0).astype(np.float64),
+                     params=dict(params))
+    bst = lgb.Booster(params=params, train_set=ds)
+    bst.add_valid(ds.create_valid(Xv, (Xv[:, 0] > 0).astype(np.float64)),
+                  "valid_0")
+    g = bst.gbdt
+    assert g._use_segment and g.grower_params.hist_backend == "pallas"
+    assert g.setup_inscan_eval(False) is None
+    g._boost_from_average()
+    g._build_fused_step()       # the kernel gates decide in interpret mode
+    monkeypatch.setattr(ph, "_interpret_default", lambda: False)
+    monkeypatch.setenv("LIGHTGBM_TPU_FUSED_ROUTE", "1")
+    monkeypatch.setattr(grower_seg, "_MAX_SORT_OPERANDS", 0)
+    carry = [jnp.asarray(np.asarray(v, np.float32)) for v in g.valid_scores]
+    assert [c.shape for c in carry] == [(1, valid_rows)]
+    args = (g.train_score, g._key, carry, g.bag_weight, g._device_bins(),
+            g.fmeta, g._full_fmask, jnp.float32(g.shrinkage_rate),
+            g._obj_arrs, g._inscan.vbins, g._inscan.arrays)
+    described = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        args)
+    text = g._get_chunk_fn(T, with_eval=True).lower(
+        *described).compile().as_text()
+    scoped = re.findall(r'op_name="jit\(chunk_run_eval\)/while/body/'
+                        r'[^"]*?/(eval_walk|eval_metric)/', text)
+    assert scoped.count("eval_walk") and scoped.count("eval_metric")
+    copies = [line.strip()[:160] for line in text.splitlines()
+              if (m := _INSTR.match(line))
+              and m.group(3) in ("copy", "transpose")
+              and f"f32[1,{valid_rows}]" in m.group(2)]
+    assert not copies, "\n".join(copies)
