@@ -336,15 +336,163 @@ def _route_tree_rows(arrays, vbins, fmeta, depth_bound: int):
     return arrays.leaf_value[jnp.maximum(~node, 0)], levels
 
 
+# The dense walk's room: a block of at most _WALK_BLOCK_ROWS rows, whose
+# [rows, L] tables (each node's bin and the row's signs in bfloat16, the
+# path agreements in float32: 8 bytes a cell) and the tree's [L - 1, L]
+# path-sign matrix (its float32 build and bfloat16 copy: 6 bytes a cell)
+# stay under _WALK_TEMP_BYTES.  Past that the gather walk takes the tree.
+_WALK_BLOCK_ROWS = 1 << 17
+_WALK_TEMP_BYTES = 1 << 29
+
+
+def _dense_walk_plan(vbins, num_leaves: int) -> Optional[Tuple[int, ...]]:
+    """Rows a block of the dense walk (`_dense_tree_rows`) holds over each
+    valid set, or None where the gather walk (`_route_tree_rows`) takes
+    ``num_leaves``-leaf trees: bins past 256 values are not all bfloat16
+    integers, and past the room above the tables are too large.  Static:
+    shapes and dtypes alone."""
+    L = num_leaves
+    plan = []
+    for vb in vbins:
+        if np.iinfo(vb.dtype).max > 256:
+            return None
+        n = max(int(vb.shape[0]), 1)
+        blocks = -(-n // _WALK_BLOCK_ROWS)
+        rows = -(-n // blocks)
+        rows = -(-rows // 256) * 256
+        if rows * L * 8 + (L - 1) * L * 6 > _WALK_TEMP_BYTES:
+            return None
+        plan.append(rows)
+    return tuple(plan)
+
+
+def _dense_tree_rows(arrays, vbins, fmeta, depth_bound: int, rows: int):
+    """`_route_tree_rows` without levels: the same (per-row leaf values,
+    levels), bit for bit, from every node's decision for every row at once.
+
+    1. ``V[row, node]``, the row's bin in the node's column, is one MXU
+       contraction of the bins against a one-hot [G, nodes] selection
+       (bins are integers <= 255, bfloat16 integers, one product a cell:
+       exact), and the node's decision follows `_route_tree_rows`
+       elementwise: ``S`` = +1 left, -1 right, 0 for the slots past
+       ``num_leaves - 1``.
+    2. ``P[node, leaf]`` = +1 where the leaf lies under the node's left
+       child, -1 under its right one, 0 elsewhere, walked up from every
+       leaf's parent link.  ``S @ P`` (sums of +-1 products, exact in
+       float32) reaches a leaf's path length exactly where the row agrees
+       with every decision on its path: at one leaf, since any other
+       leaf disagrees where the two paths part.  Leaves past
+       ``num_leaves`` (no path, length 0) are masked; a single-leaf tree
+       is leaf 0, as the gather walk's ``start = -1``.
+    3. The levels are the deepest reached path, the gather walk's trip
+       count.
+
+    Rows go in blocks of ``rows`` (`_dense_walk_plan`), so the [rows, L]
+    tables stay inside the walk's room."""
+    L = arrays.leaf_value.shape[0]
+    inner = L - 1
+    nl = arrays.num_leaves
+    node = jnp.arange(inner, dtype=jnp.int32)
+    leaf = jnp.arange(L, dtype=jnp.int32)
+    live = node < nl - 1
+    sf = arrays.split_feature
+    col = sf if fmeta.feat_group is None else fmeta.feat_group[sf]
+
+    # every node's and leaf's parent and side (internal node i at slot i,
+    # leaf l at slot inner + l), scattered from the children links
+    kids = jnp.concatenate([arrays.left_child, arrays.right_child])
+    slot = jnp.where(kids >= 0, kids, inner + ~kids)
+    slot = jnp.where(jnp.concatenate([live, live]), slot, inner + L)
+    up = jnp.full(inner + L, -1, jnp.int32).at[slot].set(
+        jnp.concatenate([node, node]), mode="drop")
+    side = jnp.zeros(inner + L, jnp.float32).at[slot].set(
+        jnp.repeat(jnp.array([1.0, -1.0], jnp.float32), inner),
+        mode="drop")
+
+    def climb(c):
+        d, cur, sgn, signs, length = c
+        on = cur >= 0
+        signs = signs.at[jnp.where(on, cur, inner), leaf].set(
+            sgn, mode="drop")
+        at = jnp.maximum(cur, 0)
+        return (d + 1, jnp.where(on, up[at], -1), side[at], signs,
+                length + on.astype(jnp.int32))
+
+    _, _, _, signs, length = jax.lax.while_loop(
+        lambda c: (c[0] < depth_bound) & jnp.any(c[1] >= 0), climb,
+        (jnp.int32(0), up[inner + leaf], side[inner + leaf],
+         jnp.zeros((inner, L), jnp.float32), jnp.zeros(L, jnp.int32)))
+    signs = signs.astype(jnp.bfloat16)
+    # a masked leaf's target is -1, which no agreement with a zero column
+    # reaches
+    target = jnp.where(leaf < nl, length, -1)
+
+    nb = fmeta.num_bin[sf]
+    db = fmeta.default_bin[sf]
+    mt = fmeta.missing_type[sf]
+    off = None if fmeta.feat_group is None else fmeta.feat_offset[sf]
+    thr, dleft = arrays.threshold_bin, arrays.default_left
+    words = arrays.cat_bitset.T
+    lv = arrays.leaf_value
+    n, G = vbins.shape
+    sel = (jnp.arange(G, dtype=jnp.int32)[:, None]
+           == col[None, :]).astype(jnp.bfloat16)
+
+    def block(vb, with_cat):
+        fv = jnp.dot(vb.astype(jnp.bfloat16), sel,
+                     preferred_element_type=jnp.bfloat16).astype(jnp.int32)
+        if off is not None:
+            in_range = (fv >= off) & (fv < off + nb)
+            fv = jnp.where(in_range, fv - off, db)
+        is_missing = (((mt == MISSING_ZERO) & (fv == db))
+                      | ((mt == MISSING_NAN) & (fv == nb - 1)))
+        go_left = jnp.where(is_missing, dleft, fv <= thr)
+        if with_cat:
+            w = jnp.clip(fv >> 5, 0, 7)
+            word = words[0]
+            for j in range(1, 8):
+                word = jnp.where(w == j, words[j], word)
+            cat_left = ((word >> (fv & 31).astype(jnp.uint32)) & 1) > 0
+            go_left = jnp.where(arrays.is_cat, cat_left, go_left)
+        s = jnp.where(live, jnp.where(go_left, 1.0, -1.0), 0.0)
+        agree = jnp.dot(s.astype(jnp.bfloat16), signs,
+                        preferred_element_type=jnp.float32)
+        hit = agree == target.astype(jnp.float32)
+        # one hit a row: a sum from -0.0 is that leaf's value to the bit
+        val = jax.lax.reduce(jnp.where(hit, lv, -0.0), np.float32(-0.0),
+                             jax.lax.add, (1,))
+        return val, jnp.max(jnp.where(hit, target, 0), axis=1)
+
+    blocks = -(-n // rows)
+    padded = jnp.pad(vbins, ((0, blocks * rows - n), (0, 0)))
+    padded = padded.reshape(blocks, rows, G)
+
+    def walk(with_cat):
+        vals, depth = jax.lax.map(lambda vb: block(vb, with_cat), padded)
+        return (vals.reshape(-1)[:n],
+                jnp.max(depth.reshape(-1)[:n], initial=0))
+
+    has_cat = jnp.any(arrays.is_cat & live)
+    return jax.lax.cond(has_cat, lambda: walk(True), lambda: walk(False))
+
+
 def _eval_walk(vscores, vbins, arrays, fmeta, k, shrinkage,
                depth_bound: int):
     """One freshly grown class-``k`` tree folded into every valid set's
     [C, Nv] score carry: (the new carries, the levels walked over all the
-    sets).  The scope is the walk's name in any device trace."""
+    sets).  The dense walk takes the tree where `_dense_walk_plan` admits
+    it, else the gather walk; the scope is the walk's name in any device
+    trace."""
     with jax.named_scope("eval_walk"):
+        rows = _dense_walk_plan(vbins, arrays.leaf_value.shape[0])
         out, levels = [], jnp.int32(0)
-        for vs, vb in zip(vscores, vbins):
-            vals, walked = _route_tree_rows(arrays, vb, fmeta, depth_bound)
+        for i, (vs, vb) in enumerate(zip(vscores, vbins)):
+            if rows is None:
+                vals, walked = _route_tree_rows(arrays, vb, fmeta,
+                                                depth_bound)
+            else:
+                vals, walked = _dense_tree_rows(arrays, vb, fmeta,
+                                                depth_bound, rows[i])
             out.append(vs.at[k].add(shrinkage * vals))
             levels = levels + walked
         return out, levels
@@ -1497,6 +1645,12 @@ class GBDT:
                 # its last column is the valid-set walk's levels
                 TELEMETRY.counter_add("eval/walk_levels",
                                       int(mv[:, -1].sum()))
+                # the walk's form is chosen from static shapes, so the
+                # trees the dense form folded in are counted here
+                dense = _dense_walk_plan(self._inscan.vbins, L) is not None
+                TELEMETRY.counter_add("eval/dense_walk_trees",
+                                      len(chunk) * len(chunk[0])
+                                      if dense else 0)
                 mv = mv[:, :-1]
             if payload.seg_stats is not None and TELEMETRY.level >= 1:
                 # 9 int32 a tree that rode out with the tree buffers: no

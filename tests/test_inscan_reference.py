@@ -21,6 +21,7 @@ import numpy as np
 import pytest
 
 import lightgbm_tpu as lgb
+from lightgbm_tpu.models import gbdt as gm
 from lightgbm_tpu.utils.phase import GLOBAL_TIMER
 from lightgbm_tpu.utils.telemetry import TELEMETRY
 
@@ -71,6 +72,8 @@ def _trained(chunk, pattern):
     stats = bst.get_stats()
     assert not [g for g in stats["gauges"] if g.startswith("boost/inscan_")]
     assert stats["counters"]["transfer/eval_fetch_calls"] == ROUNDS // chunk
+    # every tree went through the dense walk (`gbdt._dense_tree_rows`)
+    assert stats["counters"]["eval/dense_walk_trees"] == ROUNDS
     return eval_readings.gaps(bst.gbdt, bst.model_to_string(), evals, rec,
                               Xv, yv)
 
@@ -106,6 +109,30 @@ def test_inscan_auc_against_the_plain_reference(chunk, pattern, fault):
     else:
         # a fault is caught at every iteration from the planted change on
         assert got[fault]["least"] > TOLERANCE, got
+
+
+@pytest.mark.parametrize("chunk", (4, 1))
+def test_inscan_auc_is_the_gather_walks_to_the_bit(chunk, monkeypatch):
+    """The same run forced through the gather walk (`_route_tree_rows`,
+    the walk before the dense one) gives every in-scan AUC to the bit."""
+    def aucs():
+        rng = np.random.default_rng(20261005)
+        X, y = _rows(rng, 6000)
+        Xv, yv = _held_out(rng, "planted_ties")
+        params = {"objective": "binary", "metric": "auc", "max_bin": 63,
+                  "num_leaves": 15, "min_data_in_leaf": 20, "verbose": -1,
+                  "tpu_boost_chunk": chunk}
+        ds = lgb.Dataset(X, y, params=dict(params))
+        evals = {}
+        lgb.train(params, ds, num_boost_round=ROUNDS, verbose_eval=False,
+                  valid_sets=[ds.create_valid(Xv, yv)], evals_result=evals)
+        return [float(v).hex() for v in evals["valid_0"]["auc"]]
+
+    dense = aucs()
+    monkeypatch.setattr(gm, "_dense_walk_plan", lambda vbins, leaves: None)
+    assert aucs() == dense
+    assert dense == [float(v).hex()
+                     for v in _trained(chunk, "planted_ties")["auc_program"]]
 
 
 def test_reference_refuses_what_it_does_not_walk():

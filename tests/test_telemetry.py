@@ -636,6 +636,23 @@ def test_inscan_eval_counters_gauge_and_phase(level):
     assert "callbacks" not in stats["phases"]
 
 
+def test_dense_walk_counter_counts_every_tree(monkeypatch):
+    """`eval/dense_walk_trees`: every tree of the run where the dense walk
+    takes the shape, none where the gather walk does; the levels and the
+    metric values the same either way."""
+    from lightgbm_tpu.models import gbdt as gm
+    dense, evals = _eval_run()
+    c = dense.get_stats()["counters"]
+    assert c["eval/dense_walk_trees"] == 8 == dense.num_trees()
+    TELEMETRY.reset()
+    monkeypatch.setattr(gm, "_dense_walk_plan", lambda vbins, leaves: None)
+    gather, evals_gather = _eval_run()
+    g = gather.get_stats()["counters"]
+    assert g["eval/dense_walk_trees"] == 0
+    assert g["eval/walk_levels"] == c["eval/walk_levels"]
+    assert evals_gather == evals
+
+
 def test_inscan_eval_names_cost_nothing_at_level0():
     """telemetry_level=0: none of the names recorded, the same model bytes
     and the same metric values."""
