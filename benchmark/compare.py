@@ -32,6 +32,8 @@ class TreeFacts:
 
 
 def facts_of_tree(t: RefTree, init_score: float) -> TreeFacts:
+    """`init_score`: the objective's (`Follower.init_score`), which the
+    first tree carries in its leaves; 0 for every later tree."""
     return TreeFacts(leaf_value=t.leaf_value - init_score,
                      leaf_weight=t.leaf_weight, leaf_count=t.leaf_count,
                      node_weight=t.internal_weight,

@@ -5,6 +5,9 @@ standard-normal float32 features and a label from a fixed non-linear rule
 with noise, drawn in bulk.  The same seed gives the same rows.  Any other
 name is a file, `generators/<name>.py`, whose `make(rng, rows, **data)`
 returns float32 rows and the labels: a later PR's data shape is a new file.
+A generator may return a third item, `extras`, a dict of what the rows come
+with: its `"group"` holds the query sizes in row order, which sum to the
+rows asked for (a rehearsal's rows end with a whole query).
 """
 
 from __future__ import annotations
@@ -48,8 +51,34 @@ def generator(name: str, here: str = HERE) -> Callable:
 
 
 def make(data: Dict, rows: int, rng: np.random.Generator, here: str = HERE):
-    """`data` is the configuration's `data` group: the generator's name and
-    its parameters.  `rows` is passed apart: a rehearsal cuts it."""
+    """(X, y, extras).  `data` is the configuration's `data` group: the
+    generator's name and its parameters.  `rows` is passed apart: a
+    rehearsal cuts it.  `extras` is {} where the generator returns none."""
     kwargs = {k: v for k, v in data.items()
               if k not in ("generator", "rows")}
-    return generator(data["generator"], here)(rng, int(rows), **kwargs)
+    X, y, *rest = generator(data["generator"], here)(rng, int(rows), **kwargs)
+    extras = dict(rest[0]) if rest else {}
+    if "group" in extras:
+        group = np.asarray(extras["group"])
+        if (group.ndim != 1 or group.dtype != np.int64 or np.any(group < 1)
+                or int(group.sum()) != len(y)):
+            raise ValueError(f"{data['generator']}: extras['group'] must be "
+                             f"int64 query sizes >= 1 summing to the "
+                             f"{len(y)} rows")
+    return X, y, extras
+
+
+def query_of_rows(group: np.ndarray) -> np.ndarray:
+    """The query each row belongs to, numbered in row order."""
+    return np.repeat(np.arange(len(group)), group)
+
+
+def keep_rows(extras: Dict, keep: np.ndarray) -> Dict:
+    """`extras` for the rows `keep` (a boolean mask or indices in row order)
+    of the rows it came with: each query keeps the rows it had among them,
+    and a query left with none is gone."""
+    if "group" not in extras:
+        return dict(extras)
+    kept = query_of_rows(extras["group"])[keep]
+    sizes = np.bincount(kept, minlength=len(extras["group"]))
+    return dict(extras, group=sizes[sizes > 0].astype(np.int64))

@@ -4,6 +4,12 @@ Nothing here names a configuration, a cell, a traffic mix or a metric: a
 later PR adds `configs/<config>.json`, `traffic/<traffic>.json`,
 `workloads/<cell>.json` and `metrics/<metric>.py` with their entries in
 `BENCHMARK.json`, and edits no file that is there.
+
+A manifest of another name (`Manifest(doc=...)`: a test's, one set aside
+for sizing a cell before it is entered) has the same keys; its cells' traffic
+mixes and their files are in `traffic/` and `workloads/` beside it, and its
+configurations' `file`, its metrics' readers and the peaks are found as
+`BENCHMARK.json`'s are.
 """
 
 from __future__ import annotations
@@ -33,9 +39,12 @@ def load_module(path: str, tag: str):
 
 
 class Manifest:
-    def __init__(self, root: str = ROOT, here: str = HERE):
+    def __init__(self, root: str = ROOT, here: str = HERE,
+                 doc: str | None = None):
         self.root, self.here = root, here
-        self.doc = _load_json(os.path.join(root, "BENCHMARK.json"))
+        self.doc = _load_json(doc or os.path.join(root, "BENCHMARK.json"))
+        # where the cells' traffic mixes and own numbers are
+        self.cells_dir = os.path.dirname(os.path.abspath(doc)) if doc else here
 
     def cell(self, name: str) -> Dict:
         for w in self.doc["workloads"]:
@@ -52,12 +61,14 @@ class Manifest:
         raise SystemExit(f"no configuration {name!r} in BENCHMARK.json")
 
     def traffic(self, name: str) -> Dict:
-        return _load_json(os.path.join(self.here, "traffic", f"{name}.json"))
+        return _load_json(os.path.join(self.cells_dir, "traffic",
+                                       f"{name}.json"))
 
     def workload(self, name: str) -> Dict:
         """The cell's own numbers: seconds a chunk takes (which sets how many
         fit a window) and the limit of each number compared."""
-        return _load_json(os.path.join(self.here, "workloads", f"{name}.json"))
+        return _load_json(os.path.join(self.cells_dir, "workloads",
+                                       f"{name}.json"))
 
     def metrics(self, group: str, cell: str) -> List[Dict]:
         """The cell's metrics of `end_to_end` or `per_layer`: those with no

@@ -1,4 +1,5 @@
-"""The plain reference: binary-logloss gradient boosting in jax.numpy.
+"""The plain reference: gradient boosting in jax.numpy, under the objective
+the configuration names (`params["objective"]`).
 
 It imports nothing of the program and takes nothing the program made except
 the answer under test: the model as the program serialised it
@@ -6,7 +7,7 @@ the answer under test: the model as the program serialised it
 float32 rows, the labels and the configuration's parameters it follows the
 first steps of training on its own score vector:
 
-    gradients of the logistic loss at its own scores
+    gradients of the objective at its own scores
     -> every row routed through the program's tree by the serialised
        real-valued thresholds (x <= threshold goes left)
     -> per-leaf sums of gradient, hessian and rows, and for every leaf a
@@ -32,6 +33,17 @@ sit on the device once, raw float32, in the layout the passes read
 a few splits at a time: the cost follows the rows and the candidates, not
 the columns a tree does not use.
 
+Binary logloss is this file's own: its gradients are taken inside the
+jitted pass over the blocks, from the labels and the scores there.  Any
+other objective is a file, `objectives/<name>.py`, found by the name as a
+generator is, which gives `init_score(y, params, extras)`,
+`gradients(y, score, params, extras) -> (g, h)` (float32 a row) and
+`loss(y, score, params, extras)`; the follower takes the scores to the
+host, uploads g and h in its blocks' layout, and runs the same sums,
+candidate gains and update on them.  `extras` is what the rows came with
+(`datagen.make`): a query's rows, for one.  An objective with neither is
+refused before anything is trained.
+
 Rows that outgrow one chip are sharded (`Follower(devices=...)`): each
 device holds a contiguous run of whole blocks and runs the same passes on
 them, and the host adds what the shards return: the per-block sums in
@@ -42,13 +54,17 @@ With one device every pass and every number is the unsharded one.
 
 from __future__ import annotations
 
+import functools
 import math
+import os
+import re
 from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
 from typing import Dict, List
 
 import numpy as np
 
+HERE = os.path.dirname(os.path.abspath(__file__))
 K_EPS = 1e-15       # LightGBM's kEpsilon, added to every hessian sum
 
 MAX_BLOCK = 16384           # rows a block, where the width allows it
@@ -91,6 +107,22 @@ def block_rows(n_feat: int, n_cand: int, n_leaf: int) -> int:
     while rows > MIN_BLOCK and rows * per_row > TEMP_BYTES:
         rows //= 2
     return rows
+
+
+def objective(params: Dict, here: str = HERE):
+    """None for binary logloss, this file's own branch; for any other name
+    the module `objectives/<name>.py`.  A name with neither is refused."""
+    name = params.get("objective")
+    if name == "binary":
+        return None
+    path = os.path.join(here, "objectives", f"{name}.py")
+    if not (isinstance(name, str) and re.fullmatch(r"[A-Za-z0-9_]+", name)
+            and os.path.exists(path)):
+        raise SystemExit(f"the reference knows no objective {name!r}: it "
+                         f"follows binary logloss, or an objective with a "
+                         f"file {path}")
+    from manifest import load_module
+    return load_module(path, f"objective_{name}")
 
 
 # ---------------------------------------------------------------- the model
@@ -249,8 +281,9 @@ def route(X, feat, thr, path, depth):
 
 
 def _build_passes(sigmoid: float, low: bool, with_hist: bool):
-    """The two jitted passes over all blocks: sums under one tree, and the
-    score update with the loss."""
+    """The jitted passes over all blocks: sums under one tree with binary
+    logloss's gradients taken inside, the same sums under given gradients,
+    the score update with binary logloss, and the sum of a tree's values."""
     import jax
     import jax.numpy as jnp
     f32, bf16 = jnp.float32, jnp.bfloat16
@@ -289,18 +322,26 @@ def _build_passes(sigmoid: float, low: bool, with_hist: bool):
         t = total + y
         return t, (t - total) - y
 
-    @jax.jit
-    def sums_pass(Xb, yb, sb, feat, thr, path, depth, cand):
+    def binary(ysign, score):
+        """g, h and the rows that count (0: padding) of a block."""
+        g, h = gradients(ysign, score)
+        return g, h, ysign
+
+    def given(rows, g, h):
+        return g, h, rows
+
+    def sums_pass(weights, Xb, cols, feat, thr, path, depth, cand):
+        """`cols`: the per-block vectors `weights` takes."""
         L = path.shape[1]
 
         def body(carry, blk):
-            Xt, ysign, score = blk
+            Xt, *vecs = blk
             X = Xt.T
-            g, h = gradients(ysign, score)
+            g, h, rows = weights(*vecs)
             onehot = route(X, feat, thr, path, depth)
-            valid = (ysign != 0)
+            valid = (rows != 0)
             w = jnp.stack([g.astype(f32), h.astype(f32),
-                           jnp.ones_like(ysign)], axis=1) * valid[:, None]
+                           jnp.ones_like(rows)], axis=1) * valid[:, None]
             if low:
                 w = w.astype(bf16).astype(f32)
             oh = onehot.astype(f32)
@@ -323,7 +364,7 @@ def _build_passes(sigmoid: float, low: bool, with_hist: bool):
         else:
             carry0 = ()
         carry, (leaf_sums, leaf_id, bad) = jax.lax.scan(
-            body, carry0, (Xb, yb, sb))
+            body, carry0, (Xb, *cols))
         hist = carry[0] if with_hist else None
         return leaf_sums, leaf_id, bad, hist
 
@@ -353,7 +394,9 @@ def _build_passes(sigmoid: float, low: bool, with_hist: bool):
             return (), score + add
         return jax.lax.scan(body, (), (Xb, sb))[1]
 
-    return sums_pass, update_pass, apply_pass
+    return (jax.jit(functools.partial(sums_pass, binary)),
+            jax.jit(functools.partial(sums_pass, given)),
+            update_pass, apply_pass)
 
 
 # ------------------------------------------------------------ the follower
@@ -371,7 +414,8 @@ class StepReading:
     gain: np.ndarray            # [S] gain of the split the program made
     best_gain: np.ndarray | None    # [S] best over all candidates
     other_gain: np.ndarray | None   # [S] best over the other features'
-    loss: float                 # mean logloss after this step, own scores
+    loss: float                 # the objective's loss after this step (a
+                                # mean over rows, or as its file says)
     unrouted: int               # rows not in exactly one leaf
     tested_loss: float | None = None    # the same under the tested values
 
@@ -437,13 +481,16 @@ class Follower:
     """Holds the rows on the device in blocks and follows training step by
     step under the trees it is given.  The block is `block_rows` of the shape:
     nothing is set for a width.  With `devices` the blocks are sharded over
-    them, an equal number of blocks each, the last shard padded."""
+    them, an equal number of blocks each, the last shard padded.  `extras`:
+    what the rows came with, for an objective's file."""
 
     def __init__(self, X: np.ndarray, y: np.ndarray, params: Dict,
                  cand: np.ndarray, n_leaf: int, *,
                  precision: str = "float32", with_hist: bool = True,
-                 devices=None):
+                 devices=None, extras: Dict | None = None):
         import jax.numpy as jnp
+        self.objective = objective(params)
+        self.y, self.params, self.extras = y, params, extras or {}
         self.n, self.F = X.shape
         self.lr = float(params["learning_rate"])
         self.sigmoid = float(params.get("sigmoid", 1.0))
@@ -460,15 +507,22 @@ class Follower:
         devices = list(devices) if devices and len(devices) > 1 else [None]
         D = len(devices)
         blocks = -(-self.n // R)
-        per = -(-blocks // D)                   # blocks a shard: all hold
-        nb = per * D                            # as many, the last padded
+        per = self.per = -(-blocks // D)        # blocks a shard: all hold
+        nb = self.nb = per * D                  # as many, the last padded
         pad = nb * R - self.n
-        pos = y > 0
-        ysign = np.where(pos, 1.0, -1.0).astype(np.float32)
-        yb = np.concatenate([ysign, np.zeros(pad, np.float32)]).reshape(nb, R)
-        # BoostFromScore: log-odds of the positive rate over sigmoid
-        pavg = min(max(float(pos.sum()) / self.n, 1e-10), 1.0 - 1e-10)
-        self.init_score = math.log(pavg / (1.0 - pavg)) / self.sigmoid
+        if self.objective is None:
+            pos = y > 0
+            ysign = np.where(pos, 1.0, -1.0).astype(np.float32)
+            yb = np.concatenate([ysign, np.zeros(pad, np.float32)]).reshape(
+                nb, R)
+            # BoostFromScore: log-odds of the positive rate over sigmoid
+            pavg = min(max(float(pos.sum()) / self.n, 1e-10), 1.0 - 1e-10)
+            self.init_score = math.log(pavg / (1.0 - pavg)) / self.sigmoid
+        else:
+            # 1 a row, 0 for padding: the rows that count
+            yb = self._blocks(np.ones(self.n, np.float32))
+            self.init_score = float(self.objective.init_score(
+                y, params, self.extras))
 
         def shard(d):
             at = slice(d * per * R, (d + 1) * per * R)
@@ -485,8 +539,34 @@ class Follower:
         with ThreadPoolExecutor(D) as pool:     # the uploads side by side
             self.shards = list(pool.map(shard, range(D)))
         self.cand_np = np.asarray(cand, np.float64)
-        self._sums, self._update, self._apply = _build_passes(
-            self.sigmoid, self.low, with_hist)
+        self._sums, self._sums_given, self._update, self._apply = \
+            _build_passes(self.sigmoid, self.low, with_hist)
+
+    def _blocks(self, v: np.ndarray) -> np.ndarray:
+        """[n] per row -> [nb, R] float32 in the blocks' layout, zero past
+        the last row."""
+        out = np.zeros(self.nb * self.block_rows, np.float32)
+        out[:self.n] = v
+        return out.reshape(self.nb, self.block_rows)
+
+    def scores(self, name: str = "sb") -> np.ndarray:
+        """The reference's own scores (`sb`) or those moved by the values
+        under test (`sp`), [n] float32 on the host."""
+        return np.concatenate([np.asarray(getattr(sh, name)).reshape(-1)
+                               for sh in self.shards])[:self.n]
+
+    def _sums_of_objective(self, feat, thr, path, depth):
+        """The objective file's g and h at the reference's scores, uploaded
+        in the blocks' layout, and the sums pass on them."""
+        g, h = self.objective.gradients(self.y, self.scores(), self.params,
+                                        self.extras)
+        gb, hb = self._blocks(g), self._blocks(h)
+        at = [slice(d * self.per, (d + 1) * self.per)
+              for d in range(len(self.shards))]
+        return [self._sums_given(sh.Xb, (sh.yb, sh.put(gb[a]), sh.put(hb[a])),
+                                 sh.put(feat), sh.put(thr), sh.put(path),
+                                 sh.put(depth), sh.cand)
+                for sh, a in zip(self.shards, at)]
 
     def step(self, tree: RefTree, tested_values=None) -> StepReading:
         """Follow one step under `tree`.  `tested_values` are the leaf values
@@ -497,9 +577,13 @@ class Follower:
                              f"configuration allows {self.n_leaf}")
         feat, thr, path, depth = tree_tables(tree, self.n_split, self.n_leaf)
         # every shard's pass is dispatched before any result is read
-        outs = [self._sums(sh.Xb, sh.yb, sh.sb, sh.put(feat), sh.put(thr),
-                           sh.put(path), sh.put(depth), sh.cand)
-                for sh in self.shards]
+        if self.objective is None:
+            outs = [self._sums(sh.Xb, (sh.yb, sh.sb), sh.put(feat),
+                               sh.put(thr), sh.put(path), sh.put(depth),
+                               sh.cand)
+                    for sh in self.shards]
+        else:
+            outs = self._sums_of_objective(feat, thr, path, depth)
         L, S = tree.num_leaves, tree.num_leaves - 1
         sums = self._gather([o[0] for o in outs]).sum(axis=0).reshape(
             self.n_leaf, 3)[:L]
@@ -531,7 +615,7 @@ class Follower:
         for sh, o in zip(self.shards, outs):
             sh.sb, loss = self._update(sh.yb, sh.sb, o[1], sh.put(vals))
             losses.append(loss)
-        loss = self._gather(losses)
+        loss = self._loss(losses, "sb")
         tested_loss = None
         if tested_values is not None:
             tv = np.zeros(self.n_leaf, np.float32)
@@ -540,15 +624,22 @@ class Follower:
             for sh, o in zip(self.shards, outs):
                 sh.sp, tl = self._update(sh.yb, sh.sp, o[1], sh.put(tv))
                 tls.append(tl)
-            tested_loss = float(self._gather(tls).sum() / self.n)
+            tested_loss = self._loss(tls, "sp")
         return StepReading(
             tested_loss=tested_loss,
             leaf_g=leaf_g, leaf_h=leaf_h, leaf_c=leaf_c, leaf_value=value,
             node_g=node[:, 0], node_h=node[:, 1],
             node_c=np.rint(node[:, 2]).astype(np.int64), gain=gain,
-            best_gain=best, other_gain=other,
-            loss=float(loss.sum() / self.n),
+            best_gain=best, other_gain=other, loss=loss,
             unrouted=int(sum(np.asarray(o[2]).sum() for o in outs)))
+
+    def _loss(self, logloss_parts, name: str) -> float:
+        """The loss at the scores `name`: binary logloss's mean over the
+        rows from the update pass's per-block sums, or the objective's."""
+        if self.objective is None:
+            return float(self._gather(logloss_parts).sum() / self.n)
+        return float(self.objective.loss(self.y, self.scores(name),
+                                         self.params, self.extras))
 
     @staticmethod
     def _gather(parts) -> np.ndarray:

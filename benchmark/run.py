@@ -10,6 +10,10 @@ tree in host memory.  Afterwards the model as serialised is held against
 the plain reference (`reference.py`, `compare.py`), and with `--trace 1` a
 slice of the profiler's trace is reduced to the per-layer metrics.
 
+Rows may come with query groups (`datagen.make`'s `extras`): the program's
+`lgb.Dataset` gets them, a held-out set its own, and the reference follows
+the configuration's objective over them (`reference.objective`).
+
 A cell on several chips trains on the first `chips` devices with the
 configuration's `tree_learner` (`num_machines` = chips), and the reference
 holds its rows sharded over the same devices.  A traffic mix whose
@@ -237,24 +241,27 @@ def check_path(bst, chunk: int, auto_chunk: int, on_tpu: bool,
 
 
 def follow(X, y, params, trees, cand, *, tested=True, precision="float32",
-           with_hist=True, sum_all=None, devices=None):
+           with_hist=True, sum_all=None, devices=None, extras=None):
     """The reference's readings under `trees`, one step each, and the facts
-    of each tree as the program serialised it (the first carries the init
-    score in its leaves).  `tested` also moves a second score vector by the
-    serialised leaf values, whose loss the comparison reads.  With `sum_all`
-    (every tree of the run) also every row's raw score under all of them,
-    and the init score.  `devices`: the rows sharded over them (one by
-    default)."""
+    of each tree as the program serialised it (the first carries the
+    objective's init score in its leaves).  `tested` also moves a second
+    score vector by the serialised leaf values, whose loss the comparison
+    reads.  With `sum_all` (every tree of the run) also every row's raw
+    score under all of them, and the init score.  `devices`: the rows
+    sharded over them (one by default).  `extras`: what the rows came with
+    (a query's rows, for one)."""
     import compare
     import reference
     fol = reference.Follower(X, y, params, cand, int(params["num_leaves"]),
                              precision=precision, with_hist=with_hist,
-                             devices=devices)
+                             devices=devices, extras=extras)
     try:
         facts, readings = [], []
         for i, t in enumerate(trees):
             f = compare.facts_of_tree(t, fol.init_score if i == 0 else 0.0)
+            t0 = time.perf_counter()
             readings.append(fol.step(t, f.leaf_value if tested else None))
+            say(f"reference: step {i + 1} in {time.perf_counter() - t0:.2f}s")
             facts.append(f)
         summed = None if sum_all is None else fol.sum_forest(sum_all)
     finally:
@@ -263,7 +270,7 @@ def follow(X, y, params, trees, cand, *, tested=True, precision="float32",
 
 
 def follow_and_compare(X, y, params, trees, final_score, expected_trees,
-                       devices=None):
+                       devices=None, extras=None):
     """The numbers compared, and what `tools/readings.py` needs to read the
     control and the faults against the same reference: the first steps under
     the program's trees, the scores the program ended with against the sum
@@ -275,7 +282,7 @@ def follow_and_compare(X, y, params, trees, final_score, expected_trees,
     cand = reference.candidate_thresholds(trees, X.shape[1], n_bins)
     facts, readings, summed, init_score = follow(
         X, y, params, trees[:FOLLOWED_STEPS], cand, sum_all=trees,
-        devices=devices)
+        devices=devices, extras=extras)
     numbers = compare.first_steps(facts, readings)
     numbers["score_gap"] = compare.score_gap(final_score, summed, init_score)
     numbers.update(compare.forest_faults(
@@ -288,7 +295,7 @@ def follow_and_compare(X, y, params, trees, final_score, expected_trees,
     context = {"X": X, "y": y, "params": params, "trees": trees,
                "cand": cand, "facts": facts, "readings": readings,
                "summed": summed, "init_score": init_score,
-               "devices": devices}
+               "devices": devices, "extras": extras}
     return numbers, steps, context
 
 
@@ -335,9 +342,13 @@ def run_cell(man: Manifest, name: str, seed: int, seconds: float, trace: int,
     params.setdefault("verbose", -1)
     if chips > 1:
         params["num_machines"] = chips
+    import reference
+    reference.objective(params)     # refused here, before anything is drawn
     rows = int(config["data"]["rows"])
     valid_rows = int(traffic.get("valid_rows", 0))
     if rehearse:
+        # a generator's query groups sum to the rows it is asked for, so the
+        # rehearsal's rows end with a whole query
         rows = REHEARSAL["rows"]
         valid_rows = min(valid_rows, rows // 10)
         params.update(num_leaves=REHEARSAL["num_leaves"],
@@ -348,16 +359,18 @@ def run_cell(man: Manifest, name: str, seed: int, seconds: float, trace: int,
     import datagen
     rng = np.random.default_rng(seed)
     with spans.span("gen_s"):
-        X, y = datagen.make(config["data"], rows, rng)
+        X, y, row_extras = datagen.make(config["data"], rows, rng)
         Xv = yv = None
         if valid_rows:
-            Xv, yv = datagen.make(config["data"], valid_rows, rng)
+            Xv, yv, valid_extras = datagen.make(config["data"], valid_rows,
+                                                rng)
     with spans.span("bin_s"):
-        ds = lgb.Dataset(X, y, params=dict(params))
+        ds = lgb.Dataset(X, y, params=dict(params),
+                         group=row_extras.get("group"))
         ds.construct()
         valid = None
         if valid_rows:
-            valid = ds.create_valid(Xv, yv)
+            valid = ds.create_valid(Xv, yv, group=valid_extras.get("group"))
             valid.construct()
     say(f"data: {rows} x {X.shape[1]} rows from seed {seed} in "
         f"{spans.seconds['gen_s']:.1f}s, binned in "
@@ -429,12 +442,11 @@ def run_cell(man: Manifest, name: str, seed: int, seconds: float, trace: int,
     gc.collect()
 
     import compare
-    import reference
     t_ref = time.perf_counter()
     trees = reference.parse_model(model_text)
     numbers, steps, context = follow_and_compare(
         X, y, params, trees, final_score, expected_trees=rounds,
-        devices=devices if chips > 1 else None)
+        devices=devices if chips > 1 else None, extras=row_extras)
     extras = more_readings(context) if more_readings else {}
     correct, compared = compare.verdict(numbers, workload["limits"])
     correct = correct and failed == 0
@@ -503,9 +515,15 @@ def main(argv=None) -> int:
     ap.add_argument("--rehearse", action="store_true",
                     help="tiny size on any backend; prints no metric and "
                          "never exits 0")
+    ap.add_argument("--manifest", help="a manifest other than BENCHMARK.json "
+                                       "(a test's), for a rehearsal")
     args = ap.parse_args(argv)
-    code, result, _ = run_cell(Manifest(), args.workload, args.seed,
-                               args.seconds, args.trace, args.rehearse)
+    if args.manifest and not args.rehearse:
+        ap.error("--manifest is for a rehearsal: a benchmark run reads "
+                 "BENCHMARK.json")
+    code, result, _ = run_cell(Manifest(doc=args.manifest), args.workload,
+                               args.seed, args.seconds, args.trace,
+                               args.rehearse)
     if result is None:
         return code
     if args.rehearse:

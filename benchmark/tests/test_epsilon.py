@@ -77,11 +77,13 @@ def test_benchmark_json_only_gained_entries():
 def test_generator_is_deterministic_and_of_the_epsilon_shape():
     data = {"generator": "epsilon_proxy", "features": 300, "bins": 64,
             "noise": 0.25}
-    X, y = datagen.make(data, 70_000, np.random.default_rng(2 ** 31 + 11))
-    X2, y2 = datagen.make(data, 70_000, np.random.default_rng(2 ** 31 + 11))
+    X, y, extras = datagen.make(data, 70_000,
+                                np.random.default_rng(2 ** 31 + 11))
+    X2, y2, _ = datagen.make(data, 70_000, np.random.default_rng(2 ** 31 + 11))
+    assert extras == {}                         # no query groups
     assert X.dtype == np.float32 and X.shape == (70_000, 300)
     assert np.array_equal(X, X2) and np.array_equal(y, y2)
-    X3, _ = datagen.make(data, 1000, np.random.default_rng(5))
+    X3, _, _ = datagen.make(data, 1000, np.random.default_rng(5))
     assert not np.array_equal(X[:1000], X3)
     assert np.allclose((X.astype(np.float64) ** 2).sum(axis=1), 1.0,
                        atol=1e-5)                       # unit rows
@@ -108,9 +110,9 @@ def test_generator_asks_the_program_first(monkeypatch):
 @pytest.fixture(scope="module")
 def trained():
     import lightgbm_tpu as lgb
-    X, y = datagen.make({"generator": "epsilon_proxy", "features": 300,
-                         "bins": 64, "noise": 0.25}, 6000,
-                        np.random.default_rng(30))
+    X, y, _ = datagen.make({"generator": "epsilon_proxy", "features": 300,
+                            "bins": 64, "noise": 0.25}, 6000,
+                           np.random.default_rng(30))
     params = {"objective": "binary", "max_bin": 63, "num_leaves": 15,
               "learning_rate": 0.1, "min_sum_hessian_in_leaf": 5.0,
               "verbose": -1}

@@ -133,8 +133,10 @@ def test_new_files_are_found_by_name(tmp_path):
     data = man.config(cell["config"])["data"]
 
     def rows_of(seed):
-        return datagen.make(data, 50, np.random.default_rng(seed),
-                            here=man.here)
+        X, y, extras = datagen.make(data, 50, np.random.default_rng(seed),
+                                    here=man.here)
+        assert extras == {}         # a generator of two items: no groups
+        return X, y
 
     X, y = rows_of(2 ** 31 + 11)
     assert X.shape == (50, 12) and X.dtype == np.float32 and len(y) == 50

@@ -109,8 +109,8 @@ def wide():
     the program trains on them, on whatever backend the test runs."""
     import lightgbm_tpu as lgb
     rows, n_feat = 6000, 300
-    X, y = datagen.make({"generator": "higgs_proxy", "features": n_feat,
-                         "noise": 0.5}, rows, np.random.default_rng(29))
+    X, y, _ = datagen.make({"generator": "higgs_proxy", "features": n_feat,
+                            "noise": 0.5}, rows, np.random.default_rng(29))
     params = {"objective": "binary", "max_bin": 63, "num_leaves": 15,
               "learning_rate": 0.1, "min_sum_hessian_in_leaf": 5.0,
               "feature_fraction_bynode": 0.5, "verbose": -1}

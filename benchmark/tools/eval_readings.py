@@ -205,8 +205,8 @@ def one_seed(man, name, seed, rehearse):
                           "min_sum_hessian_in_leaf"],
                       tpu_histogram_backend="pallas")
     rng = np.random.default_rng(seed)
-    X, y = datagen.make(config["data"], rows, rng)
-    Xv, yv = datagen.make(config["data"], valid_rows, rng)
+    X, y, _ = datagen.make(config["data"], rows, rng)
+    Xv, yv, _ = datagen.make(config["data"], valid_rows, rng)
     ds = lgb.Dataset(X, y, params=dict(params))
     ds.construct()
     valid = ds.create_valid(Xv, yv)

@@ -34,32 +34,37 @@ def control_and_faults(ctx, with_witness=False):
     import numpy as np
 
     import compare
+    from datagen import keep_rows
     X, y, params, cand = ctx["X"], ctx["y"], ctx["params"], ctx["cand"]
     trees, readings = ctx["trees"][:run.FOLLOWED_STEPS], ctx["readings"]
     devices = ctx.get("devices")
+    extras = ctx.get("extras") or {}
     out = {}
     low = run.follow(X, y, params, trees, cand, tested=False,
                      precision="bfloat16", with_hist=False,
-                     devices=devices)[1]
+                     devices=devices, extras=extras)[1]
     out["control_bfloat16"] = compare.control_readings(readings, low)
     import jax.numpy as jnp
     rounded = ctx["summed"].astype(jnp.bfloat16).astype(np.float32)
     out["control_bfloat16"]["score_gap"] = compare.score_gap(
         rounded, ctx["summed"], ctx["init_score"])
+    # a query keeps its rows among those left
     half = run.follow(X[::2], y[::2], params, trees, cand, tested=False,
-                      with_hist=False, devices=devices)[1]
+                      with_hist=False, devices=devices,
+                      extras=keep_rows(extras, np.s_[::2]))[1]
     out["fault_half_rows"] = compare.control_readings(readings, half)
     if devices and len(devices) > 1:
         # the program's shards are contiguous runs of rows, n / D each
         keep = len(y) * (len(devices) - 1) // len(devices)
         lost = run.follow(X[:keep], y[:keep], params, trees, cand,
-                          tested=False, with_hist=False, devices=devices)[1]
+                          tested=False, with_hist=False, devices=devices,
+                          extras=keep_rows(extras, np.s_[:keep]))[1]
         out["fault_one_shard_left_out"] = compare.control_readings(
             readings, lost)
     out["fault_skipped_feature"] = compare.skipped_feature_shortfall(readings)
     again = [trees[0]] * len(trees)
     facts, under = run.follow(X, y, params, again, cand, with_hist=False,
-                              devices=devices)[:2]
+                              devices=devices, extras=extras)[:2]
     # every step's tree carries the init score, as the first does
     out["fault_state_unchanged"] = compare.first_steps(
         [facts[0]] * len(trees), under)
@@ -91,11 +96,13 @@ def main() -> int:
                     help="train the measured chunks too (a full run)")
     ap.add_argument("--seconds", type=float, default=10.0)
     ap.add_argument("--rehearse", action="store_true")
+    ap.add_argument("--manifest", help="a manifest other than BENCHMARK.json "
+                                       "(a test's, or a cell's being sized)")
     ap.add_argument("--witness", action="store_true",
                     help="also follow the steps in float64 numpy on the "
                          "host, and keep every per-leaf array in an .npz")
     args = ap.parse_args()
-    man = run.Manifest()
+    man = run.Manifest(doc=args.manifest)
     out_dir = os.path.join(run.ROOT, "chiprun_out")
     os.makedirs(out_dir, exist_ok=True)
     path = os.path.join(out_dir, f"readings_{args.workload}.jsonl")

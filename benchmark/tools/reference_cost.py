@@ -78,9 +78,9 @@ def main() -> int:
         peak_after[part] = int(stats.get("peak_bytes_in_use", 0))
 
     t = time.perf_counter()
-    X, y = datagen.make({"generator": "higgs_proxy",
-                         "features": args.features, "noise": 0.5},
-                        args.rows, np.random.default_rng(args.seed))
+    X, y, _ = datagen.make({"generator": "higgs_proxy",
+                            "features": args.features, "noise": 0.5},
+                           args.rows, np.random.default_rng(args.seed))
     seconds["rows"] = time.perf_counter() - t
     rs = np.random.default_rng(args.seed + 1)
     quantiles = np.linspace(-2.2, 2.2, args.max_bin)

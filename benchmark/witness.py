@@ -18,6 +18,8 @@ import numpy as np
 
 class HostFollower:
     def __init__(self, X: np.ndarray, y: np.ndarray, params: Dict):
+        if params.get("objective") != "binary":
+            raise ValueError("the witness follows binary logloss only")
         self.X = X
         self.ysign = np.where(y > 0, 1.0, -1.0)
         self.lr = float(params["learning_rate"])
